@@ -1,14 +1,20 @@
 """ctypes bindings for the native host-runtime kernels (ingest.cpp).
 
-Loads (building on first use if the toolchain is available)
-libgsnative.so; every entry point has a numpy/python fallback so the
-framework works without a compiler. `available()` reports which path is
-active.
+Loads libgsnative-<hash>.so, building it on first use from the
+committed ingest.cpp when the toolchain is available. The file name
+carries a hash of the source, so an edited ingest.cpp is rebuilt and a
+library built from another revision is never loaded. The Makefile
+builds for the generic target of the host architecture (no
+-march=native): the library must run on whichever machine loads the
+checkout. Every entry point has a numpy/python fallback so the
+framework works without a compiler. `available()` reports which path
+is active.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional, Tuple
@@ -18,7 +24,15 @@ import numpy as np
 from ..utils import telemetry
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_DIR, "libgsnative.so")
+
+
+def _lib_path() -> str:
+    """The library built from the ingest.cpp on disk now."""
+    with open(os.path.join(_DIR, "ingest.cpp"), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, "libgsnative-%s.so" % digest)
+
+
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
@@ -28,16 +42,22 @@ def _load() -> Optional[ctypes.CDLL]:
     if _lib is not None or _tried:
         return _lib
     _tried = True
-    if not os.path.exists(_LIB_PATH):
+    path = _lib_path()
+    if not os.path.exists(path):
+        # build under a per-process name, then rename into place:
+        # concurrent first loads never see a half-written library
+        tmp = "%s.tmp%d" % (path, os.getpid())
         try:
-            subprocess.run(["make", "-C", _DIR, "-s"], check=True,
-                           capture_output=True, timeout=120)
+            subprocess.run(["make", "-C", _DIR, "-s",
+                            "LIB=" + os.path.basename(tmp)], check=True,
+                           capture_output=True, timeout=300)
+            os.replace(tmp, path)
         except Exception as e:
             telemetry.event("native.build_failed", durable=True,
                             error="%s: %s" % (type(e).__name__, e))
             return None
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(path)
     except OSError:
         return None
     lib.gs_parse_edges.restype = ctypes.c_int64
@@ -65,64 +85,53 @@ def _load() -> Optional[ctypes.CDLL]:
         ctypes.c_void_p, ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
         ctypes.POINTER(ctypes.c_int64),
     ]
-    try:
-        lib.gs_triangle_count_stream.restype = ctypes.c_int64
-        lib.gs_triangle_count_stream.argtypes = [
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int64, ctypes.c_int64, ctypes.POINTER(ctypes.c_int64),
-        ]
-        lib.gs_windowed_reduce.restype = ctypes.c_int64
-        lib.gs_windowed_reduce.argtypes = [
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_int32, ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64),
-        ]
-        lib.gs_windowed_reduce_i32.restype = ctypes.c_int64
-        lib.gs_windowed_reduce_i32.argtypes = [
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_int32, ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int64),
-        ]
-    except AttributeError:
-        # a stale libgsnative.so missing newer symbols: everything else
-        # still works; the affected helpers report unavailable
-        pass
-    try:
-        lib.gs_windowed_reduce_i32o.restype = ctypes.c_int64
-        lib.gs_windowed_reduce_i32o.argtypes = [
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_int32, ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-        ]
-        lib.gs_windowed_reduce_i64i32o.restype = ctypes.c_int64
-        lib.gs_windowed_reduce_i64i32o.argtypes = [
-            ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_int32, ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-        ]
-    except AttributeError:
-        pass
-    try:
-        lib.gs_snapshot_windows.restype = ctypes.c_int64
-        lib.gs_snapshot_windows.argtypes = [
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
-        ]
-    except AttributeError:
-        pass
+    lib.gs_triangle_count_stream.restype = ctypes.c_int64
+    lib.gs_triangle_count_stream.argtypes = [
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64, ctypes.c_int64, ctypes.POINTER(ctypes.c_int64),
+    ]
+    lib.gs_windowed_reduce.restype = ctypes.c_int64
+    lib.gs_windowed_reduce.argtypes = [
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
+        ctypes.c_int32, ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64),
+    ]
+    lib.gs_windowed_reduce_i32.restype = ctypes.c_int64
+    lib.gs_windowed_reduce_i32.argtypes = [
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
+        ctypes.c_int32, ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64),
+    ]
+    lib.gs_windowed_reduce_i32o.restype = ctypes.c_int64
+    lib.gs_windowed_reduce_i32o.argtypes = [
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
+        ctypes.c_int32, ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+    ]
+    lib.gs_windowed_reduce_i64i32o.restype = ctypes.c_int64
+    lib.gs_windowed_reduce_i64i32o.argtypes = [
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
+        ctypes.c_int32, ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+    ]
+    lib.gs_snapshot_windows.restype = ctypes.c_int64
+    lib.gs_snapshot_windows.argtypes = [
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32),
+    ]
     _lib = lib
     return _lib
 

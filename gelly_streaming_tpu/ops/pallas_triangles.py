@@ -37,9 +37,10 @@ def _need_interpret() -> bool:
 def _tri_kernel(a_ik, a_kj, a_ij, out, acc):
     """Grid (i, j, k), k innermost. acc: VMEM (TILE, TILE) scratch.
 
-    The output is the per-column sum of the masked tile (TILE values per
-    (i,j) tile), not a single scalar: each column sum is ≤ TILE·V ≤ 2¹⁹
-    so it stays exact in f32; the global reduction finishes in int64 on
+    The output is the masked tile folded to one (8, TILE) block (sums of
+    TILE/8 rows each), not a single scalar: an (8, 128) out block is
+    what the TPU's tiling accepts, and each entry is ≤ TILE·V ≤ 2¹⁹ so
+    it stays exact in f32; the global reduction finishes in int64 on
     the host."""
     k = pl.program_id(2)
 
@@ -51,7 +52,8 @@ def _tri_kernel(a_ik, a_kj, a_ij, out, acc):
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _():
-        out[0, :] = jnp.sum(acc[:] * a_ij[:], axis=0)
+        out[:] = jnp.sum((acc[:] * a_ij[:]).reshape(TILE // 8, 8, TILE),
+                         axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -69,9 +71,9 @@ def _six_t_partials(a: jax.Array, interpret: bool) -> jax.Array:
             pl.BlockSpec((TILE, TILE), lambda i, j, k: (i, j),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, TILE), lambda i, j, k: (i, j),
+        out_specs=pl.BlockSpec((8, TILE), lambda i, j, k: (i, j),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((g, g * TILE), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((8 * g, g * TILE), jnp.float32),
         scratch_shapes=[pltpu.VMEM((TILE, TILE), jnp.float32)],
         interpret=interpret,
     )(a, a, a)

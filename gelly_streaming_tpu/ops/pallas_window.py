@@ -46,11 +46,18 @@ Selection is the repo's measured-adoption gate (`resolve_*` family):
 `GS_PALLAS_WINDOW` pins on/off; unset/`auto` adopts ONLY on committed
 backend-matched `pallas_ab` rows (tools/pallas_ab.py) that all show
 exact parity and ≥1.05×, so the XLA fused scan stands — and CPU
-digests stay bit-identical — until a chip row lands. A pallas_call
-that raises at build/trace time (Pallas API drift, a Mosaic lowering
-gap for the in-kernel sort/scatter) degrades to the XLA body with a
-durable `selection.fallback` event instead of taking the stream down
-— the same honest fallback every other selection plays.
+digests stay bit-identical — until a chip row lands. Selection probes
+the built kernel: a trace in interpret mode, a lowering and compile for
+the chip otherwise. A kernel pinned `on` that the chip's compiler
+refuses raises PallasUnavailable with the compiler's reason; one that
+was adopted without a pin, or runs in interpret mode, degrades to the
+XLA body with a durable `selection.fallback` event.
+
+On a v5e this megakernel, the triangle-only counter, the tenant-axis
+cohort kernel and the GNN kernel are all refused today: Mosaic has no
+lowering for their in-kernel scatter-add ("Unimplemented primitive in
+Pallas TPU lowering: scatter-add"; ROADMAP queue 1). They run in
+interpret mode only, as parity oracles.
 
 Off-TPU the kernel runs in INTERPRET mode (the seeds' convention):
 bit-identical to the XLA scan and the host twins by construction —
@@ -232,6 +239,38 @@ def _on_tpu() -> bool:
         return jax.default_backend() == "tpu"
     except Exception:  # gslint: disable=except-hygiene (availability probe: selects the interpret form, never correctness)
         return False
+
+
+class PallasUnavailable(RuntimeError):
+    """A Pallas kernel pinned `on` cannot be built for the chip this
+    process runs on: the pin raises instead of silently running XLA."""
+
+
+def _refuse(component: str, pin: str, fallback: str, error: str):
+    """A selected kernel that cannot run. Pinned `on` while building
+    for the chip (not interpret), raise PallasUnavailable; otherwise
+    record a durable `selection.fallback` event and return None (the
+    caller builds `fallback`)."""
+    if knobs.get_str(pin) == "on" and not _need_interpret():
+        raise PallasUnavailable("%s=on, but the %s kernel cannot run "
+                                "here: %s" % (pin, component, error))
+    telemetry.event("selection.fallback", durable=True,
+                    component=component, fallback=fallback, error=error)
+    return None
+
+
+def _probe(fn, *shapes) -> None:
+    """Check that `fn` builds at `shapes`: a trace in interpret mode;
+    a lowering and compile for the chip otherwise, so a Mosaic refusal
+    (e.g. an in-kernel scatter) surfaces at selection time."""
+    if _need_interpret():
+        jax.eval_shape(fn, *shapes)
+    else:
+        jax.jit(fn).lower(*shapes).compile()
+
+
+def _why(e: Exception) -> str:
+    return "%s: %s" % (type(e).__name__, str(e)[:200])
 
 
 def default_tile(eb: int) -> int:
@@ -662,10 +701,10 @@ def _cohort_call(eb: int, vb: int, kb: int, nb: int, tile_e: int,
     vb1 = vb + 1
 
     def _row(ref, n):
-        return pl.load(ref, (pl.dslice(n, 1), slice(None)))[0]
+        return ref[pl.ds(n, 1), :][0]
 
     def _set_row(ref, n, val):
-        pl.store(ref, (pl.dslice(n, 1), slice(None)), val[None])
+        ref[pl.ds(n, 1), :] = val[None]
 
     def kernel(s_ref, d_ref, v_ref, deg0, lab0, cov0,
                deg_ref, lab_ref, cov_ref, sums_ref, slab_s, slab_d):
@@ -839,25 +878,19 @@ def maybe_window_body(eb: int, vb: int, kb: int,
                       compact: bool = False):
     """The gated, PROBED entry the engines build through: None (use
     the XLA body) unless the selection gate is on, the shape fits the
-    chip budget, AND a trace probe of the built body succeeds. A
-    pallas_call that raises at trace time — Pallas API drift, a
-    lowering gap — degrades here with a durable `selection.fallback`
-    event instead of wedging engine construction (the chaos-leg
-    contract). On success the analytic cost entry registers with the
+    chip budget, AND the probe (_probe) of the built body succeeds. A
+    refusal raises when GS_PALLAS_WINDOW pins `on` for the chip, and
+    otherwise degrades here with a durable `selection.fallback` event
+    (_refuse). On success the analytic cost entry registers with the
     observatory."""
     if not resolve_pallas_window():
         return None
     tile_e, ck = resolve_tiles(eb, kb, vb)
     if not supports(eb, vb, kb, tile_e, ck, compact):
-        telemetry.event("selection.fallback", durable=True,
-                        component="pallas_window",
-                        fallback="xla_scan",
-                        error="vmem budget: %d > %d at eb=%d vb=%d "
-                              "kb=%d" % (
-                                  vmem_window_bytes(eb, vb, kb,
-                                                    tile_e, ck),
-                                  VMEM_BUDGET, eb, vb, kb))
-        return None
+        return _refuse("pallas_window", "GS_PALLAS_WINDOW", "xla_scan",
+                       "vmem budget: %d > %d at eb=%d vb=%d kb=%d" % (
+                           vmem_window_bytes(eb, vb, kb, tile_e, ck),
+                           VMEM_BUDGET, eb, vb, kb))
     try:
         body = build_window_body(eb, vb, kb, tile_e, ck, compact)
         carry = (jax.ShapeDtypeStruct((vb + 1,), jnp.int32),
@@ -871,14 +904,10 @@ def maybe_window_body(eb: int, vb: int, kb: int,
             xs = (jax.ShapeDtypeStruct((eb,), jnp.int32),
                   jax.ShapeDtypeStruct((eb,), jnp.int32),
                   jax.ShapeDtypeStruct((eb,), jnp.bool_))
-        jax.eval_shape(body, carry, xs)
-    except Exception as e:
-        telemetry.event("selection.fallback", durable=True,
-                        component="pallas_window",
-                        fallback="xla_scan",
-                        error="%s: %s" % (type(e).__name__,
-                                          str(e)[:200]))
-        return None
+        _probe(body, carry, xs)
+    except Exception as e:  # gslint: disable=except-hygiene (_refuse raises or records a durable selection.fallback)
+        return _refuse("pallas_window", "GS_PALLAS_WINDOW", "xla_scan",
+                       _why(e))
     register_cost_model(eb, vb, kb, compact)
     return body
 
@@ -919,24 +948,21 @@ def build_cohort_window_body(eb: int, vb: int, kb: int, nb: int,
 def maybe_cohort_body(eb: int, vb: int, kb: int, nb: int):
     """The gated, PROBED entry build_cohort_scan builds through: None
     (vmap the XLA body over tenants) unless resolve_cohort_pallas()
-    is on, the N-row shape fits the chip budget, AND a trace probe of
-    the built body succeeds — the same durable `selection.fallback`
-    contract as maybe_window_body, under component `cohort_pallas`.
+    is on, the N-row shape fits the chip budget, AND the probe of the
+    built body succeeds — the same raise-or-fallback contract as
+    maybe_window_body, under GS_COHORT_PALLAS / `cohort_pallas`.
     On success the cohort analytic cost entry registers with the
     observatory."""
     if not resolve_cohort_pallas():
         return None
     tile_e, ck = resolve_tiles(eb, kb, vb)
     if not supports_cohort(eb, vb, kb, nb, tile_e, ck):
-        telemetry.event("selection.fallback", durable=True,
-                        component="cohort_pallas",
-                        fallback="xla_cohort_scan",
-                        error="vmem budget: %d > %d at eb=%d vb=%d "
-                              "kb=%d nb=%d" % (
-                                  cohort_vmem_window_bytes(
-                                      eb, vb, kb, nb, tile_e, ck),
-                                  VMEM_BUDGET, eb, vb, kb, nb))
-        return None
+        return _refuse("cohort_pallas", "GS_COHORT_PALLAS",
+                       "xla_cohort_scan",
+                       "vmem budget: %d > %d at eb=%d vb=%d kb=%d "
+                       "nb=%d" % (cohort_vmem_window_bytes(
+                           eb, vb, kb, nb, tile_e, ck),
+                           VMEM_BUDGET, eb, vb, kb, nb))
     try:
         body = build_cohort_window_body(eb, vb, kb, nb, tile_e, ck)
         vb1 = vb + 1
@@ -946,14 +972,10 @@ def maybe_cohort_body(eb: int, vb: int, kb: int, nb: int):
         xs = (jax.ShapeDtypeStruct((nb, eb), jnp.int32),
               jax.ShapeDtypeStruct((nb, eb), jnp.int32),
               jax.ShapeDtypeStruct((nb, eb), jnp.bool_))
-        jax.eval_shape(body, carry, xs)
-    except Exception as e:
-        telemetry.event("selection.fallback", durable=True,
-                        component="cohort_pallas",
-                        fallback="xla_cohort_scan",
-                        error="%s: %s" % (type(e).__name__,
-                                          str(e)[:200]))
-        return None
+        _probe(body, carry, xs)
+    except Exception as e:  # gslint: disable=except-hygiene (_refuse raises or records a durable selection.fallback)
+        return _refuse("cohort_pallas", "GS_COHORT_PALLAS",
+                       "xla_cohort_scan", _why(e))
     costmodel.record_analytic(
         "cohort_pallas", "eb=%d,vb=%d,kb=%d,nb=%d" % (eb, vb, kb, nb),
         flops=nb * window_flops(eb, vb, kb),
@@ -1220,23 +1242,18 @@ def maybe_gnn_body(eb: int, vb: int, F: int, act: str):
     """The gated, PROBED entry GnnSummaryEngine builds through: None
     (use the XLA gather/segment-sum round) unless
     resolve_gnn_pallas() is on, the [vb+1, F] slab fits the chip
-    budget, AND a trace probe of the built body succeeds — the same
-    durable `selection.fallback` contract as maybe_window_body, under
-    component `gnn_pallas`. On success the GNN analytic cost entries
+    budget, AND the probe of the built body succeeds — the same
+    raise-or-fallback contract as maybe_window_body, under
+    GS_GNN_PALLAS / `gnn_pallas`. On success the GNN analytic cost entries
     register with the observatory."""
     if not resolve_gnn_pallas():
         return None
     tile_e = default_tile(eb)
     if not supports_gnn(eb, vb, F, tile_e):
-        telemetry.event("selection.fallback", durable=True,
-                        component="gnn_pallas",
-                        fallback="xla_gnn_scan",
-                        error="vmem budget: %d > %d at eb=%d vb=%d "
-                              "F=%d" % (
-                                  gnn_vmem_window_bytes(eb, vb, F,
-                                                        tile_e),
-                                  VMEM_BUDGET, eb, vb, F))
-        return None
+        return _refuse("gnn_pallas", "GS_GNN_PALLAS", "xla_gnn_scan",
+                       "vmem budget: %d > %d at eb=%d vb=%d F=%d" % (
+                           gnn_vmem_window_bytes(eb, vb, F, tile_e),
+                           VMEM_BUDGET, eb, vb, F))
     try:
         body = build_gnn_window_body(eb, vb, F, act, tile_e)
         h = jax.ShapeDtypeStruct((vb + 1, F), jnp.float32)
@@ -1245,14 +1262,10 @@ def maybe_gnn_body(eb: int, vb: int, F: int, act: str):
         xs = (jax.ShapeDtypeStruct((eb,), jnp.int32),
               jax.ShapeDtypeStruct((eb,), jnp.int32),
               jax.ShapeDtypeStruct((eb,), jnp.bool_))
-        jax.eval_shape(body, h, W, b, xs)
-    except Exception as e:
-        telemetry.event("selection.fallback", durable=True,
-                        component="gnn_pallas",
-                        fallback="xla_gnn_scan",
-                        error="%s: %s" % (type(e).__name__,
-                                          str(e)[:200]))
-        return None
+        _probe(body, h, W, b, xs)
+    except Exception as e:  # gslint: disable=except-hygiene (_refuse raises or records a durable selection.fallback)
+        return _refuse("gnn_pallas", "GS_GNN_PALLAS", "xla_gnn_scan",
+                       _why(e))
     register_gnn_cost_model(eb, vb, F)
     return body
 
@@ -1263,7 +1276,7 @@ def maybe_counter(vb: int, kb: int, classic_run):
     triangle-only megakernel where the (trace-static) edge bucket
     fits the budget and the probed kernel built, else `classic_run`.
     The probe runs ONCE per (vb, kb) at a nominal bucket — the same
-    durable-fallback contract as maybe_window_body."""
+    raise-or-fallback contract as maybe_window_body."""
     if not resolve_pallas_window():
         return None
     pkey = (vb, kb, "counter")
@@ -1276,16 +1289,12 @@ def maybe_counter(vb: int, kb: int, classic_run):
                                  _need_interpret())
             g = probe_eb // tile_e
             sds = jax.ShapeDtypeStruct((g, tile_e), jnp.int32)
-            jax.eval_shape(call, sds, sds,
-                           jax.ShapeDtypeStruct((g, tile_e),
-                                                jnp.bool_))
+            _probe(call, sds, sds,
+                   jax.ShapeDtypeStruct((g, tile_e), jnp.bool_))
             verdict = True
-        except Exception as e:
-            telemetry.event("selection.fallback", durable=True,
-                            component="pallas_window",
-                            fallback="xla_counter",
-                            error="%s: %s" % (type(e).__name__,
-                                              str(e)[:200]))
+        except Exception as e:  # gslint: disable=except-hygiene (_refuse raises or records a durable selection.fallback)
+            _refuse("pallas_window", "GS_PALLAS_WINDOW", "xla_counter",
+                    _why(e))
             verdict = False
         _PROBES[pkey] = verdict
     if not verdict:

@@ -10,7 +10,6 @@ the available chips; exchanges become XLA collectives (`psum`, `pmin`,
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Optional
 
 import jax
@@ -20,27 +19,8 @@ from jax.sharding import PartitionSpec as P
 
 SHARD_AXIS = "shard"
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed across jax releases
-# (check_rep through 0.5.x, check_vma from 0.6); resolve whichever this
-# install accepts once at import.
-_NOCHECK_KW = None
-for _kw in ("check_rep", "check_vma"):
-    try:
-        if _kw in inspect.signature(_shard_map).parameters:
-            _NOCHECK_KW = _kw
-            break
-    except (ValueError, TypeError):  # C-level signature: try the old name
-        _NOCHECK_KW = "check_rep"
-        break
-
-
 def shard_map_norep(mesh: Mesh, in_specs, out_specs):
-    """`shard_map` partial with the replication/vma check DISABLED —
+    """`jax.shard_map` partial with the vma check DISABLED —
     for bodies that trace a `lax.while_loop` (ops/unionfind.cc_fixpoint's
     fixpoint iteration; arbitrary user associative fns), which jax's
     checker has no replication rule for ("No replication rule for
@@ -48,9 +28,8 @@ def shard_map_norep(mesh: Mesh, in_specs, out_specs):
     EXPLICITLY with collectives (psum/pmin/pmax/all_gather), so the
     check is redundant there — disabling it changes what is verified,
     never what is computed."""
-    kw = {_NOCHECK_KW: False} if _NOCHECK_KW else {}
-    return functools.partial(_shard_map, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
+    return functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(n_devices: Optional[int] = None,

@@ -49,10 +49,13 @@ entry point is a guarded no-op, no tags are bound, and the hot path
 is bit-identical — asserted by tests/test_costmodel.py digest parity
 on the 524K/32768 CPU row.
 
-Knobs (utils/knobs.py):
-    GS_COSTMODEL              0 (default) = disarmed no-ops; 1 = capture
-    GS_COSTMODEL_PEAK_GFLOPS  compute roofline peak (GFLOP/s)
-    GS_COSTMODEL_PEAK_GBPS    memory-bandwidth roofline peak (GB/s)
+Knob (utils/knobs.py): GS_COSTMODEL — 0 (default) = disarmed no-ops;
+1 = capture.
+
+Peaks come from one table keyed by JAX's `device_kind` (PEAKS). The
+verdicts are computed when rows are reported, for the device the
+process runs on unless a caller names one; a device missing from the
+table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -70,11 +73,27 @@ def enabled() -> bool:
     return knobs.get_bool("GS_COSTMODEL")
 
 
-def peaks() -> Tuple[float, float]:
-    """(peak FLOP/s, peak bytes/s) of the roofline the verdicts are
-    computed against (GS_COSTMODEL_PEAK_GFLOPS/_GBPS)."""
-    return (knobs.get_float("GS_COSTMODEL_PEAK_GFLOPS") * 1e9,
-            knobs.get_float("GS_COSTMODEL_PEAK_GBPS") * 1e9)
+# Published per-chip peaks, keyed by `jax.devices()[0].device_kind`:
+# (FLOP/s, HBM bytes/s). Source: Google Cloud documentation, "TPU v5e"
+# (197 TFLOP/s bf16, 819 GB/s HBM per chip).
+PEAKS = {"TPU v5 lite": (197e12, 819e9)}
+
+
+def device_kind() -> str:
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
+def peaks(kind: Optional[str] = None) -> Tuple[float, float]:
+    """(peak FLOP/s, peak bytes/s) of `kind` (default: this process's
+    device) from PEAKS; ValueError for a device the table lacks."""
+    kind = kind or device_kind()
+    if kind not in PEAKS:
+        raise ValueError("no roofline peaks for device kind %r "
+                         "(costmodel.PEAKS has %s)"
+                         % (kind, sorted(PEAKS)))
+    return PEAKS[kind]
 
 
 # ----------------------------------------------------------------------
@@ -208,28 +227,33 @@ def _extract(compiled) -> dict:
     return out
 
 
-def classify(entry: dict) -> dict:
-    """Attach the roofline verdict to one cost entry IN PLACE:
-    arithmetic intensity, the machine balance it is judged against,
-    the bytes/FLOPs `bound` verdict, and the roofline-implied minimum
-    seconds per dispatch. Entries without both FLOPs and bytes get
-    verdict `unknown` — with the flops/bytes keys still PRESENT
-    (null), so every row classify() touches satisfies the committed
-    cost_model schema's required keys (error-path and
-    armed-mid-stream rows included: "not reported" must stay
-    distinguishable from "silently dropped")."""
+def classify(entry: dict, kind: Optional[str] = None) -> dict:
+    """Attach the roofline verdict to one cost entry IN PLACE against
+    the peaks of device `kind` (default: this process's device; an
+    unknown device raises): arithmetic intensity, the machine balance
+    it is judged against, the bytes/FLOPs `bound` verdict, and the
+    roofline-implied minimum seconds per dispatch. Entries without
+    both FLOPs and bytes get verdict `unknown` and need no peaks —
+    with the flops/bytes keys still PRESENT (null), so every row
+    classify() touches satisfies the committed cost_model schema's
+    required keys (error-path and armed-mid-stream rows included:
+    "not reported" must stay distinguishable from "silently
+    dropped")."""
     entry.setdefault("flops", None)
     entry.setdefault("bytes_accessed", None)
     flops, bts = entry.get("flops"), entry.get("bytes_accessed")
-    peak_f, peak_b = peaks()
-    entry["machine_balance_flops_per_byte"] = round(peak_f / peak_b, 3)
     if flops and bts:
+        peak_f, peak_b = peaks(kind)
+        entry["device_kind"] = kind or device_kind()
+        entry["machine_balance_flops_per_byte"] = round(
+            peak_f / peak_b, 3)
         intensity = flops / bts
         entry["arith_intensity_flops_per_byte"] = round(intensity, 4)
         entry["bound"] = ("bytes" if intensity < peak_f / peak_b
                           else "flops")
         entry["roofline_s"] = max(flops / peak_f, bts / peak_b)
     else:
+        entry["machine_balance_flops_per_byte"] = None
         entry["arith_intensity_flops_per_byte"] = None
         entry["bound"] = "unknown"
         entry["roofline_s"] = None
@@ -255,13 +279,11 @@ def record_compiled(program: str, compiled, sig: tuple) -> None:
         return
     entry = _extract(compiled)
     entry.update(program=program, sig=key[1])
-    classify(entry)
     with reg.lock:
         reg.programs[key] = entry
     telemetry.event("costmodel.capture", program=program, sig=key[1],
                     flops=entry.get("flops"),
-                    bytes_accessed=entry.get("bytes_accessed"),
-                    bound=entry.get("bound"))
+                    bytes_accessed=entry.get("bytes_accessed"))
 
 
 def record_analytic(program: str, sig_text: str, flops,
@@ -296,7 +318,6 @@ def record_analytic(program: str, sig_text: str, flops,
              "bytes_accessed": (None if bytes_accessed is None
                                 else int(bytes_accessed))}
     entry.update(extra)
-    classify(entry)
     with reg.lock:
         reg.programs[key] = entry
         reg.analytic[program] = {
@@ -304,7 +325,7 @@ def record_analytic(program: str, sig_text: str, flops,
     telemetry.event("costmodel.capture", program=program, sig=key[1],
                     flops=entry.get("flops"),
                     bytes_accessed=entry.get("bytes_accessed"),
-                    bound=entry.get("bound"), model="analytic")
+                    model="analytic")
 
 
 def _instantiate_analytic(reg, key: Tuple[str, str]) -> bool:
@@ -348,7 +369,6 @@ def on_call(program: str, fn, sig: tuple, args, kwargs) -> None:
     if lower is None:
         entry = {"program": program, "sig": key[1],
                  "error": "not AOT-lowerable (no .lower)"}
-        classify(entry)
         with reg.lock:
             reg.programs[key] = entry
         return
@@ -360,14 +380,12 @@ def on_call(program: str, fn, sig: tuple, args, kwargs) -> None:
         telemetry.event("costmodel.capture_failed", program=program,
                         sig=key[1], error=entry["error"])
     entry.update(program=program, sig=key[1])
-    classify(entry)
     with reg.lock:
         reg.programs[key] = entry
     if "error" not in entry:
         telemetry.event("costmodel.capture", program=program,
                         sig=key[1], flops=entry.get("flops"),
-                        bytes_accessed=entry.get("bytes_accessed"),
-                        bound=entry.get("bound"))
+                        bytes_accessed=entry.get("bytes_accessed"))
 
 
 def wrap_exec(program: str, ex, sig: tuple):
@@ -404,7 +422,7 @@ def _sink(rec: dict) -> None:
     with reg.lock:
         d = reg.dispatches.setdefault(key, {"count": 0, "total_s": 0.0})
         d["count"] += 1
-        d["total_s"] += float(rec.get("dur", 0.0))
+        d["total_s"] += float(rec.get("dur", 0.0))  # gslint: disable=host-sync (a telemetry record's host float)
 
 
 telemetry.register_sink(_sink, enabled)
@@ -441,11 +459,13 @@ def join_measure(entry: dict, count: int, total_s: float) -> dict:
     return entry
 
 
-def report() -> List[dict]:
-    """Joined per-program-per-shape rows: the captured cost model plus
-    whatever measured dispatch seconds the sink has accumulated,
-    sorted by measured time then program name — the `programs` rows
-    the profiler commits to PERF.json's `cost_model` section."""
+def report(kind: Optional[str] = None) -> List[dict]:
+    """Joined per-program-per-shape rows: the captured cost model,
+    classified against the peaks of device `kind` (default: this
+    process's device; an unknown device raises), plus whatever
+    measured dispatch seconds the sink has accumulated, sorted by
+    measured time then program name — the `programs` rows the
+    profiler commits to PERF.json's `cost_model` section."""
     reg = _reg()
     with reg.lock:
         progs = {k: dict(v) for k, v in reg.programs.items()}
@@ -453,10 +473,9 @@ def report() -> List[dict]:
     rows = []
     for key, entry in progs.items():
         entry.pop("pending", None)
-        if "bound" not in entry:
-            # a capture still in flight on another thread: serve the
-            # row classified (null cost fields) rather than bare
-            classify(entry)
+        # a capture still in flight on another thread is served
+        # classified (null cost fields) rather than bare
+        classify(entry, kind)
         d = disp.pop(key, None)
         if d:
             join_measure(entry, d["count"], d["total_s"])
@@ -468,7 +487,7 @@ def report() -> List[dict]:
     # mid-stream after the compile): still reported, cost-less
     for key, d in disp.items():
         rows.append(join_measure(
-            classify({"program": key[0], "sig": key[1]}),
+            classify({"program": key[0], "sig": key[1]}, kind),
             d["count"], d["total_s"]))
     rows.sort(key=lambda r: (-r.get("measured_total_s", 0.0),
                              r.get("program") or "", r.get("sig") or ""))

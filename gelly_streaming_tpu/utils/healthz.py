@@ -13,10 +13,9 @@ An opt-in stdlib `http.server` daemon thread bound to 127.0.0.1
 
 plus the **staleness watchdog**: a daemon thread calling
 `metrics.check_staleness()` every quarter of `GS_HEALTH_STALE_S`, so
-a wedged tunnel (no window finalizing) flips `/healthz` to `degraded`
+a hung stream (no window finalizing) flips `/healthz` to `degraded`
 and stamps a durable `health_degraded` event within one watchdog
-interval — the round-5 dead-queue-hour failure shape becomes a live
-signal instead of a post-mortem. Recovery is the next finalize
+interval — a live signal instead of a post-mortem. Recovery is the next finalize
 (metrics.mark_window flips back and stamps `health_recovered`).
 
 The server is brought up lazily by the instrumented layers (driver /

@@ -351,7 +351,7 @@ register("GS_HEALTH_STALE_S", "float", 30.0, lo=0.0,
          help="staleness watchdog deadline: with the metrics plane "
               "armed, no window finalizing for this many seconds "
               "flips `/healthz` to `degraded` and writes a durable "
-              "`health_degraded` event (the wedged-tunnel detector); "
+              "`health_degraded` event (the hung-stream detector); "
               "0 disables the watchdog",
          default_text="30")
 
@@ -567,18 +567,6 @@ register("GS_COSTMODEL", "bool", False,
               "bit-identical (armed, a jit-path program pays ONE "
               "extra AOT compile per new signature)",
          default_text="0 (off)")
-register("GS_COSTMODEL_PEAK_GFLOPS", "float", 197000.0, lo=1.0,
-         help="compute roofline peak (GFLOP/s) the boundedness "
-              "verdict and achieved fractions are computed against; "
-              "default is the public TPU v5e bf16 peak — on a CPU "
-              "backend the fractions are structure checks, not chip "
-              "numbers",
-         default_text="197000 (v5e bf16)")
-register("GS_COSTMODEL_PEAK_GBPS", "float", 819.0, lo=0.001,
-         help="memory-bandwidth roofline peak (GB/s) for the "
-              "bytes-vs-FLOPs boundedness verdict; default is the "
-              "public TPU v5e HBM peak",
-         default_text="819 (v5e HBM)")
 
 # windowed GNN workload (ops/gnn_window.py)
 register("GS_GNN_F", "int", 16, lo=1, hi=256,

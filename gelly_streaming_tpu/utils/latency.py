@@ -78,7 +78,7 @@ from . import telemetry
 
 clock = telemetry.clock  # ONE clock family with the span ledger
 
-# canonical stage taxonomy, in pipeline order; boundary stamps carry
+# canonical stage map, in pipeline order; boundary stamps carry
 # the name of the stage they CLOSE (see stamp()/on_window)
 STAGES = ("admission", "queue_wait", "prep", "h2d", "dispatch",
           "finalize", "deliver")

@@ -397,7 +397,7 @@ telemetry.register_sink(_sink, enabled)
 
 
 # ----------------------------------------------------------------------
-# window-finalize marks + health state (the wedged-tunnel detector)
+# window-finalize marks + health state (the hung-stream detector)
 # ----------------------------------------------------------------------
 def on_stream_start(engine: str = "driver",
                     tenant: Optional[str] = None) -> None:
@@ -504,6 +504,19 @@ def mark_window(windows: int, edges: int, engine: str = "driver",
     _maybe_serve()
 
 
+def _residue(total: float, shares: list) -> float:
+    """The last share x with sum(shares + [x]) == total exactly (the
+    built-in float sum, as a consumer rolls rows up): total - sum can
+    miss by an ulp, so step x until the sum lands."""
+    x = total - sum(shares)
+    for _ in range(64):
+        got = sum([*shares, x])
+        if got == total:
+            break
+        x = math.nextafter(x, math.inf if got < total else -math.inf)
+    return x
+
+
 def attribute_dispatch(seconds: float, rows,
                        program: Optional[str] = None,
                        sig: Optional[str] = None):
@@ -549,21 +562,17 @@ def attribute_dispatch(seconds: float, rows,
     nz = [i for i, (_t, n) in enumerate(rows) if n > 0]
     last = nz[-1]
     out = []
-    acc_s = 0.0
-    acc_b = 0.0
     for i, (t, n) in enumerate(rows):
         if n == 0:
             out.append((t, 0.0, 0.0))
-            continue
-        if i == last:
-            s = seconds - acc_s
-            b = (bytes_total - acc_b) if bytes_total else 0.0
+        elif i == last:
+            out.append((t, _residue(seconds, [r[1] for r in out]),
+                        _residue(bytes_total, [r[2] for r in out])
+                        if bytes_total else 0.0))
         else:
-            s = seconds * (n / total)
-            acc_s += s
-            b = bytes_total * (n / total) if bytes_total else 0.0
-            acc_b += b
-        out.append((t, s, b))
+            out.append((t, seconds * (n / total),
+                        bytes_total * (n / total) if bytes_total
+                        else 0.0))
     reg = _reg()
     with reg.lock:
         for t, s, b in out:

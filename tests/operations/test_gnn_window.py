@@ -392,7 +392,7 @@ def test_gnn_cost_model_intensity(monkeypatch):
     costmodel.reset()
     try:
         pw.register_gnn_cost_model(32768, 65536, 16)
-        rows = {r["program"]: r for r in costmodel.report()
+        rows = {r["program"]: r for r in costmodel.report("TPU v5 lite")
                 if r.get("program", "").startswith("gnn")}
         assert set(rows) >= {"gnn_scan", "gnn_resident",
                              "gnn_pallas"}

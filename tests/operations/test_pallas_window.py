@@ -293,6 +293,30 @@ def test_trace_failure_falls_back_with_durable_event(monkeypatch):
         telemetry.reset()
 
 
+@pytest.mark.parametrize("pin, build", [
+    ("GS_PALLAS_WINDOW", lambda: pw.maybe_window_body(256, 256, 16)),
+    ("GS_PALLAS_WINDOW",
+     lambda: pw.maybe_counter(256, 16, lambda *a: None)),
+    ("GS_COHORT_PALLAS", lambda: pw.maybe_cohort_body(256, 256, 16, 4)),
+    ("GS_GNN_PALLAS", lambda: pw.maybe_gnn_body(256, 256, 16, "relu")),
+], ids=["window", "counter", "cohort", "gnn"])
+def test_pinned_kernel_that_cannot_build_raises(monkeypatch, pin, build):
+    """Pinned `on` and built for the chip (interpret off), a kernel the
+    compiler refuses raises PallasUnavailable — it never quietly runs
+    the XLA body instead. Here the refusal is the CPU backend's own:
+    it lowers Pallas in interpret mode only."""
+    monkeypatch.setenv(pin, "on")
+    monkeypatch.setattr(pw, "_need_interpret", lambda: False)
+    pw._reset_pallas_window()
+    pw._CALLS.clear()
+    try:
+        with pytest.raises(pw.PallasUnavailable, match=pin + "=on"):
+            build()
+    finally:
+        pw._CALLS.clear()
+        pw._reset_pallas_window()
+
+
 def test_vmem_budget_gate(monkeypatch):
     """supports() enforces the chip VMEM budget on TPU backends only:
     interpret (no VMEM) always passes, a pretend-chip refuses shapes
@@ -392,14 +416,14 @@ def test_cost_model_registers_single_slab_read(monkeypatch,
     try:
         eng = StreamSummaryEngine(edge_bucket=256, vertex_bucket=256)
         assert eng._pallas
-        rows = [r for r in costmodel.report()
+        rows = [r for r in costmodel.report("TPU v5 lite")
                 if r["program"] == "pallas_window"
                 and r.get("model") == "analytic"]
         assert rows, "analytic megakernel entry not registered"
         # a dispatch must join the STATED model at its own span sig —
         # never a capture of the interpret lowering (review fix)
         eng.process(*_stream(256, 200, seed=1))
-        sig_rows = [r for r in costmodel.report()
+        sig_rows = [r for r in costmodel.report("TPU v5 lite")
                     if r["program"] == "pallas_window"
                     and not r["sig"].startswith("eb=")]
         assert sig_rows, \

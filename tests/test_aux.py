@@ -1,6 +1,8 @@
 """Auxiliary subsystems: tracing, checkpoint/resume (SURVEY.md §5 build
 items — all absent from the reference)."""
 
+import os
+
 import numpy as np
 
 from gelly_streaming_tpu import SimpleEdgeStream
@@ -153,3 +155,25 @@ def test_ingress_ab_parity_failure_is_evidence_not_a_crash(monkeypatch):
     assert row["parity"] is False
     assert "speedup" not in row
     assert not tri.rows_clear_bar([row], "speedup", lambda r: 1.0)
+
+
+def test_compile_cache_dir_honours_env(monkeypatch):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR when
+    set, which the helper leaves to JAX alone; else one fixed path in
+    the checkout (the path is part of the cache key)."""
+    import jax
+
+    from gelly_streaming_tpu.core import platform
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert platform.CACHE_DIR == os.path.join(repo, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert platform.enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert platform.enable_compile_cache() == platform.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == platform.CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

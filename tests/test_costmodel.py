@@ -82,15 +82,16 @@ def test_sig_key_renders_abstract_sigs():
 # roofline classification
 # ----------------------------------------------------------------------
 def test_classify_bytes_vs_flops_bound(monkeypatch):
-    monkeypatch.setenv("GS_COSTMODEL_PEAK_GFLOPS", "100")
-    monkeypatch.setenv("GS_COSTMODEL_PEAK_GBPS", "10")
+    monkeypatch.setitem(costmodel.PEAKS, "test chip", (100e9, 10e9))
     # machine balance = 10 FLOPs/byte
-    low = costmodel.classify({"flops": 10, "bytes_accessed": 100})
+    low = costmodel.classify({"flops": 10, "bytes_accessed": 100},
+                             "test chip")
     assert low["bound"] == "bytes"
     assert low["arith_intensity_flops_per_byte"] == 0.1
     # bytes-bound: roofline time is the bandwidth term
     assert low["roofline_s"] == pytest.approx(100 / 10e9)
-    high = costmodel.classify({"flops": 10000, "bytes_accessed": 100})
+    high = costmodel.classify({"flops": 10000, "bytes_accessed": 100},
+                              "test chip")
     assert high["bound"] == "flops"
     assert high["roofline_s"] == pytest.approx(10000 / 100e9)
     assert high["machine_balance_flops_per_byte"] == 10.0
@@ -104,9 +105,24 @@ def test_classify_unknown_without_both_inputs():
         assert out["roofline_s"] is None
 
 
+def test_peaks_table_keyed_by_device_kind():
+    """The v5e row is the published one; any other device — the CPU
+    this suite runs on included — is an error wherever a roofline
+    share is computed, never a silent default."""
+    assert costmodel.peaks("TPU v5 lite") == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        costmodel.peaks("cpu")
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        costmodel.classify({"flops": 1, "bytes_accessed": 1}, "TPU v9")
+    # the default is the device this process runs on: the CPU here
+    with pytest.raises(ValueError, match="'cpu'"):
+        costmodel.classify({"flops": 1, "bytes_accessed": 1})
+
+
 def test_join_measure_math():
     entry = costmodel.classify(
-        {"flops": 2_000_000_000, "bytes_accessed": 4_000_000_000})
+        {"flops": 2_000_000_000, "bytes_accessed": 4_000_000_000},
+        "TPU v5 lite")
     costmodel.join_measure(entry, count=4, total_s=8.0)
     assert entry["dispatches"] == 4
     assert entry["measured_mean_s"] == 2.0
@@ -152,7 +168,8 @@ def test_wrap_exec_captures_and_tags(armed):
     assert entry["flops"] > 0
     assert entry["bytes_accessed"] > 0
     assert entry["argument_bytes"] == 512      # 2 × 64 × f32
-    assert entry["bound"] in ("bytes", "flops")
+    assert costmodel.classify(dict(entry), "TPU v5 lite")["bound"] \
+        in ("bytes", "flops")
     # the dispatch bound its program/sig tags for the span record site
     assert telemetry.pop_dispatch_tags() \
         == {"program": "toy_exec", "sig": "f32[64],f32[64]"}
@@ -199,14 +216,14 @@ def test_on_call_unlowerable_records_error_entry(armed):
     costmodel.on_call("plain_fn", lambda x: x, ("sig",), (1,), {})
     entry = costmodel.programs()[("plain_fn", "sig")]
     assert "not AOT-lowerable" in entry["error"]
-    assert entry["bound"] == "unknown"
+    # the error entry still reports (cost-less) instead of vanishing;
     # error rows still carry the schema-required cost keys (null), so
     # a partially-captured run commits a valid cost_model section
-    assert entry["flops"] is None
-    assert entry["bytes_accessed"] is None
-    # the error entry still reports (cost-less) instead of vanishing
-    rows = costmodel.report()
-    assert any(r.get("program") == "plain_fn" for r in rows)
+    row = next(r for r in costmodel.report()
+               if r.get("program") == "plain_fn")
+    assert row["bound"] == "unknown"
+    assert row["flops"] is None
+    assert row["bytes_accessed"] is None
     telemetry.pop_dispatch_tags()
 
 
@@ -223,7 +240,7 @@ def test_sink_joins_tagged_spans_into_report(armed, monkeypatch):
     # untagged spans never reach the registry
     with telemetry.span("ingress.prep"):
         pass
-    rows = {r["program"]: r for r in costmodel.report()}
+    rows = {r["program"]: r for r in costmodel.report("TPU v5 lite")}
     assert rows["joined"]["dispatches"] == 3
     assert rows["joined"]["measured_total_s"] >= 0
     assert "roofline_frac" in rows["joined"] \
@@ -233,7 +250,7 @@ def test_sink_joins_tagged_spans_into_report(armed, monkeypatch):
     with telemetry.span("ingress.dispatch", program="ghost",
                         sig="i32[4]"):
         pass
-    rows = {r["program"]: r for r in costmodel.report()}
+    rows = {r["program"]: r for r in costmodel.report("TPU v5 lite")}
     assert rows["ghost"]["dispatches"] == 1
     assert rows["ghost"]["bound"] == "unknown"
     # cost-less rows still carry the schema-required keys as null
@@ -242,13 +259,12 @@ def test_sink_joins_tagged_spans_into_report(armed, monkeypatch):
 
 
 def test_report_sorted_by_measured_time(armed):
+    # stated durations, not sleeps: under a loaded host one slept
+    # millisecond can outlast four (the order must not hang on it)
     for name, n in (("cold", 1), ("hot", 4)):
         for _ in range(n):
-            with telemetry.span("ingress.dispatch", program=name,
-                                sig="s"):
-                import time
-
-                time.sleep(0.001)
+            telemetry.record_span("ingress.dispatch", 0.0, 0.001,
+                                  program=name, sig="s")
     order = [r["program"] for r in costmodel.report()]
     assert order.index("hot") < order.index("cold")
 
@@ -275,7 +291,7 @@ def test_engine_dispatch_spans_carry_program_tags(
     assert "i32[" in sig                  # the COO slab is in the key
     assert ("fused_scan", sig) in costmodel.programs()
     # the live join serves the same rows explain_perf computes offline
-    row = next(r for r in costmodel.report()
+    row = next(r for r in costmodel.report("TPU v5 lite")
                if r["program"] == "fused_scan")
     assert row["dispatches"] == len(tagged)
     assert row["flops"] is not None

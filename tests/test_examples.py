@@ -16,7 +16,7 @@ EDGES = "1 2 100\n1 3 150\n3 2 200\n2 4 250\n3 4 300\n4 5 400\n"
 
 
 def _run(args, timeout=240):
-    env = dict(os.environ, PYTHONPATH="", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run([sys.executable] + args, cwd=REPO, env=env,
                           capture_output=True, text=True,
                           timeout=timeout)

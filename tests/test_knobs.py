@@ -37,8 +37,7 @@ ALL = ("GS_PIPELINE_WORKERS GS_PIPELINE_INFLIGHT GS_STREAM_PREFETCH "
        "GS_PUMP GS_SLIDE GS_OOO_BOUND GS_SUB_QUEUE "
        "GS_GNN_F GS_GNN_ACT GS_GNN_PALLAS "
        "GS_PROVENANCE GS_PROVENANCE_DIR GS_PROVENANCE_RETAIN "
-       "GS_COSTMODEL GS_COSTMODEL_PEAK_GFLOPS "
-       "GS_COSTMODEL_PEAK_GBPS").split()
+       "GS_COSTMODEL").split()
 
 _GETTERS = {"int": knobs.get_int, "float": knobs.get_float,
             "bool": knobs.get_bool, "str": knobs.get_str,

@@ -1,6 +1,8 @@
 """Native host-runtime kernels: parser / window assigner / interner,
 cross-checked against the Python fallbacks."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -298,3 +300,28 @@ def test_snapshot_tier_delta_parity():
                 if dx is not None:
                     np.testing.assert_array_equal(dx[0], dy[0])
                     np.testing.assert_array_equal(dx[1], dy[1])
+
+
+def test_library_name_keyed_on_source(tmp_path, monkeypatch):
+    """The loader's library carries a hash of ingest.cpp: an edited
+    source maps to a new library (rebuilt on first load), never to one
+    built from another revision."""
+    import shutil
+
+    for name in ("ingest.cpp", "Makefile"):
+        shutil.copy(os.path.join(native._DIR, name), tmp_path)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    first = native._lib_path()
+    assert os.path.dirname(first) == str(tmp_path)
+    assert os.path.basename(first).startswith("libgsnative-")
+    assert native._lib_path() == first
+    with open(tmp_path / "ingest.cpp", "a") as f:
+        f.write("\n// edited\n")
+    assert native._lib_path() != first
+
+
+def test_makefile_builds_for_any_host():
+    """The checkout's library must load on whichever machine runs it:
+    no -march=native."""
+    with open(os.path.join(native._DIR, "Makefile")) as f:
+        assert "march=native" not in f.read()

@@ -30,13 +30,8 @@ bench_compare = _load_tool("bench_compare")
 # ----------------------------------------------------------------------
 # schema: the committed files must stay valid
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("fname", [
-    "PERF.json", "PERF_cpu.json", "PERF_tpu.json"])
-def test_committed_perf_files_validate(fname):
-    path = os.path.join(REPO, fname)
-    if not os.path.exists(path):
-        pytest.skip("%s not committed" % fname)
-    with open(path) as f:
+def test_committed_perf_file_validates():
+    with open(os.path.join(REPO, "PERF_cpu.json")) as f:
         perf = json.load(f)
     assert perf_schema.validate(perf) == []
 
@@ -303,15 +298,6 @@ def test_bench_compare_unchanged_run_exits_zero(tmp_path, capsys):
     assert report["rows_compared"] == 2
 
 
-def test_bench_compare_committed_baseline_self_compare():
-    """The acceptance pin: `--baseline BENCH_r05.json` (no --current)
-    exits 0 on the unchanged run."""
-    path = os.path.join(REPO, "BENCH_r05.json")
-    if not os.path.exists(path):
-        pytest.skip("BENCH_r05.json not committed")
-    assert bench_compare.main(["--baseline", path]) == 0
-
-
 def test_bench_compare_slowed_row_exits_nonzero(tmp_path, capsys):
     base = str(tmp_path / "base.jsonl")
     cur = str(tmp_path / "cur.jsonl")
@@ -484,21 +470,6 @@ def test_schema_validates_bench_capture_shape():
     joined = "\n".join(errors)
     assert "'tail'" in joined and "'rc'" in joined \
         and "'parsed'" in joined
-
-
-@pytest.mark.parametrize("fname", [
-    "BENCH_r01.json", "BENCH_r02.json", "BENCH_r03.json",
-    "BENCH_r04.json", "BENCH_r05.json"])
-def test_committed_bench_captures_validate(fname):
-    """tools/ci_check.sh runs perf_schema over every committed
-    evidence file — the captures must stay valid too."""
-    path = os.path.join(REPO, fname)
-    if not os.path.exists(path):
-        pytest.skip("%s not committed" % fname)
-    with open(path) as f:
-        doc = json.load(f)
-    assert perf_schema.is_capture(doc)
-    assert perf_schema.validate_capture(doc) == []
 
 
 # ----------------------------------------------------------------------
@@ -716,7 +687,7 @@ def test_explain_perf_suspect_heuristics():
 
 def test_explain_perf_unmapped_spans_fail_conservation(tmp_path,
                                                        capsys):
-    """The taxonomy polices itself: leaf time under a span name the
+    """The stage map polices itself: leaf time under a span name the
     stage map doesn't know (beyond --tolerance of the total) exits
     non-zero and names the unmapped spans."""
     ledger = tmp_path / "l.jsonl"

@@ -31,7 +31,7 @@ report must fail the sentry, not the consumer. Exit status: 0 clean,
 1 regressions found, 2 usage/IO errors.
 
 Usage:
-  python tools/bench_compare.py --baseline BENCH_r05.json \
+  python tools/bench_compare.py --baseline BASELINE_RUN.jsonl \
          [--current RUN.jsonl] [--tolerance 0.2] [--out REPORT.json]
 
 With no --current the baseline is compared against itself — a wiring

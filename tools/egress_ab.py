@@ -21,8 +21,7 @@ probes so the egress lever is measured in isolation.
 
 The committed `egress_ab` rows are what ops/delta_egress.
 resolve_egress gates on: parity true AND >=5% on EVERY row, or
-full-vector stands. Run after the evidence queue (tools/tpu_queue.sh);
-commit policy identical to tools/ingress_ab.py (PERF.json only when
+full-vector stands. Run alone on the chip's host; commit policy identical to tools/ingress_ab.py (PERF.json only when
 backend-matched, PERF_<backend>.json always).
 """
 

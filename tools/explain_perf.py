@@ -12,7 +12,7 @@ attribution verdict:
     its children's time, whatever it is named — with the known
     envelope names as a fallback for ledgers without parent links.
     The conservation check is on the mapped fraction: leaf time the
-    stage taxonomy could NOT name (`other`) beyond `--tolerance`
+    stage map could NOT name (`other`) beyond `--tolerance`
     (default 5%) of the ledger's leaf-span total exits non-zero,
     naming the unmapped spans — a new span name can't silently
     vanish from the attribution;
@@ -106,7 +106,7 @@ def stage_attribution(records):
     conservation numbers: the attributed total, the independent
     leaf-span total (via trace_report's own accounting), and the
     `other` rows' unmapped span names — main() fails the run when
-    the taxonomy couldn't name more than --tolerance of the time."""
+    the stage map couldn't name more than --tolerance of the time."""
     totals = {s: {"stage": s, "count": 0, "total_s": 0.0}
               for s in STAGE_ORDER}
     unmapped = {}
@@ -182,7 +182,7 @@ def tenant_attribution(records):
     driver's steps), plus one `<cohort>` row aggregating the vmapped
     `cohort.dispatch` spans — whose time is SHARED by all tenants in
     the slab, so it is reported with its mean tenants-per-dispatch
-    instead of being split by guesswork. Unlike the stage taxonomy,
+    instead of being split by guesswork. Unlike the stage map,
     this table reads ALL spans (not just leaves): a tenant-labeled
     span legitimately envelopes its engine's internal chunk spans —
     its duration IS the tenant's wall time, and the table is rendered
@@ -478,7 +478,7 @@ def main(argv=None) -> int:
     else:
         print(render(report, args.top))
     if mapped_frac < 1.0 - args.tolerance:
-        print("explain_perf: the stage taxonomy could not name "
+        print("explain_perf: the stage map could not name "
               "%.1f%% of the ledger's leaf-span time (> %.1f%% "
               "tolerance) — unmapped spans: %s; add them to STAGE_OF "
               "(or CONTAINERS if they envelope other spans)"

@@ -2,8 +2,8 @@
 
 Every perf and robustness PR in this repo depends on hand-enforced
 invariants: no host↔device sync outside the sanctioned egress sites
-(the dispatch wall is the ROADMAP's top item — BENCH_r05 shows the
-round-trip, not compute, is the bottleneck), no impure reads inside
+(the dispatch wall: in earlier rounds' ladders the round-trip, not
+compute, was the bottleneck), no impure reads inside
 traced code (an `os.environ` read under `jax.jit` silently freezes at
 compile time), every `GS_*` knob through the typed registry
 (utils/knobs.py), every failure recorded durably, shared state

@@ -47,8 +47,9 @@ def _imports_jax(tree: ast.AST) -> bool:
 # R1 — host-sync discipline
 # ======================================================================
 class HostSyncRule(Rule):
-    """Host↔device synchronization is the dispatch wall (BENCH_r05:
-    the round-trip, not compute, bounds the device path). Every d2h
+    """Host↔device synchronization is the dispatch wall (in earlier
+    rounds' ladders the round-trip, not compute, bounded the device
+    path). Every d2h
     materialization must happen at a sanctioned egress/finalize/
     mirror-sync site — the driver's delivery boundary, the delta
     egress decode, the host-twin mirror sync — where it is batched,
@@ -142,7 +143,7 @@ class JitPurityRule(Rule):
         "lax.fori_loop": (2,), "jax.lax.fori_loop": (2,),
         "lax.cond": (1, 2), "jax.lax.cond": (1, 2),
         "shard_map": (0,), "shard_map_norep": (0,),
-        "jax.experimental.shard_map.shard_map": (0,),
+        "jax.shard_map": (0,),
         # Pallas kernel bodies trace like any jit root (and freeze
         # even harder: the kernel compiles once per shape into a
         # Mosaic binary) — ops/pallas_window.py and the seed kernels
