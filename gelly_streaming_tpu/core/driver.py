@@ -100,6 +100,10 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
     economics). Cover layout matches the driver's
     host state: (+) = v, (−) = vb + v, sentinel slot 2vb.
 
+    The CC and double-cover fixpoints also emit their round counts,
+    `cc_rounds` and `cover_rounds` ([W] int32), in every egress and
+    donate variant: the read-back's fixpoint counters (finalize).
+
     With `deltas`, each analytic also emits a per-window changed-slot
     bool mask over [:vb] (new state vs the scan carry — computed
     on-device, so a consumer of the reference's improving streams
@@ -152,7 +156,8 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
                 outs["deg"] = new_deg
             deg = new_deg
         if want_cc:
-            new_labels = uf.cc_fixpoint(labels, s, d)
+            new_labels, outs["cc_rounds"] = uf.cc_fixpoint(
+                labels, s, d, rounds=True)
             chg = (new_labels[:vb] != labels[:vb]) \
                 if (deltas or delta_out) else None
             if delta_out:
@@ -172,7 +177,8 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
             d2 = jnp.concatenate([
                 jnp.where(valid, d + vb, sent2),
                 jnp.where(valid, d, sent2)])
-            new_cover = uf.cc_fixpoint(cover, s2, d2)
+            new_cover, outs["cover_rounds"] = uf.cc_fixpoint(
+                cover, s2, d2, rounds=True)
             if deltas or delta_out:
                 # the consumer-visible value is the odd flag, so the
                 # mask (and the delta wire) tracks IT, not raw labels
@@ -210,6 +216,21 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
         return jax.lax.scan(body, carry, (s_w, d_w, valid_w))
 
     return run
+
+
+def _readback_counters(outs: dict, windows: int) -> None:
+    """The read-back's counters, from a chunk's materialized scan
+    outputs (no further sync): bytes brought back, and the rounds the
+    CC and double-cover fixpoints took over the chunk's `windows` real
+    windows (rows past them are the W-bucket's sentinel windows)."""
+    telemetry.counter("driver.readback_bytes",
+                      sum(v.nbytes for v in outs.values()),
+                      windows=windows)
+    for key in ("cc_rounds", "cover_rounds"):
+        if key in outs:
+            telemetry.counter("driver." + key,
+                              int(outs[key][:windows].sum()),
+                              windows=windows)
 
 
 _SNAPSHOT_TIER = None  # resolved once per process (reset below)
@@ -1510,11 +1531,14 @@ class StreamingAnalyticsDriver:
 
                 f_outs = resilience.call_guarded(
                     "finalize", f_at, _mat, retries=0)
+            _readback_counters(f_outs, len(f_chunk))
             # the dispatch boundary of this chunk's waterfall closes
             # with the materialize (device execute + d2h, observed)
             disp_t[0] = (latency.clock() if latency.enabled()
                          else None)
-            _finalize_chunk(f_at, f_chunk, f_outs)
+            with self._step("snapshot_extract",
+                            sum(len(s) for _w, s, _d, _n in f_chunk)):
+                _finalize_chunk(f_at, f_chunk, f_outs)
             if f_sw is not None:
                 # the resident super-batch span closes at its DRAIN —
                 # dispatch through materialize + extraction (the
@@ -1549,6 +1573,13 @@ class StreamingAnalyticsDriver:
                     jnp.asarray(valid))
 
         build_job = _build_dev if resident else _build_stack
+
+        def _build_inline(item):
+            # a stack the ring built is spanned by the pool's own prep
+            chunk, _wb = item
+            with telemetry.span("ingress.prep", window=chunk[0][0]):
+                return build_job(item)
+
         from ..ops import resident_engine
 
         ring = resident_engine.IngestRing(
@@ -1658,9 +1689,9 @@ class StreamingAnalyticsDriver:
                             raise  # inert knobs keep legacy fail-fast
                         wb, s_w, d_w, valid = resilience.call_guarded(
                             "prep", at,
-                            lambda: build_job(item))
+                            lambda: _build_inline(item))
                 else:
-                    wb, s_w, d_w, valid = build_job(
+                    wb, s_w, d_w, valid = _build_inline(
                         (chunk, self._scan_wb(len(chunk))))
                 if next_submit <= at:
                     next_submit = at + take

@@ -43,7 +43,9 @@ a chip run can decompose the chip-side wall without new
 instrumentation. With the flight recorder armed (utils/telemetry,
 GS_TELEMETRY=1) each chunk additionally records a correlated span
 tree — an `ingress.chunk` span with prep/h2d/dispatch/finalize child
-spans, worker-side stages included — into the run ledger.
+spans, worker-side stages included — into the run ledger. Inside a
+jax.profiler capture each stage also runs under a live scope of the
+same name, on the thread that does it (`telemetry.trace_scope`).
 
 Env knobs:
   GS_STREAM_PREFETCH=0  — force the fully synchronous single-threaded
@@ -270,10 +272,12 @@ def _timed_prep(prep: Callable, item, timers: Optional[StageTimers],
     """Worker-side prep wrapper: times the call and converts a failure
     into a PrepError carrying the formatted worker traceback."""
     _mark(cell, "prep")
+    par, ck = _span_cell(cell, item)
     t0 = time.perf_counter()
     try:
-        faults.fire("prep")
-        out = prep(item)
+        with telemetry.trace_scope("ingress.prep", chunk=ck):
+            faults.fire("prep")
+            out = prep(item)
     except Exception as e:
         # Exception only: KeyboardInterrupt/SystemExit must abort the
         # run unwrapped (a broad caller-side `except RuntimeError`
@@ -285,7 +289,6 @@ def _timed_prep(prep: Callable, item, timers: Optional[StageTimers],
     dt = time.perf_counter() - t0
     if timers is not None:
         timers.add("prep", dt)
-    par, ck = _span_cell(cell, item)
     telemetry.record_span("ingress.prep", t0, dt, parent=par,
                           chunk=ck)
     return out
@@ -298,10 +301,12 @@ def _prep_then_h2d(prep: Callable, h2d: Callable, item,
     separately; h2d failures carry the worker traceback too."""
     payload = _timed_prep(prep, item, timers, cell)
     _mark(cell, "h2d")
+    par, ck = _span_cell(cell, item)
     t0 = time.perf_counter()
     try:
-        faults.fire("h2d")
-        dev = h2d(payload)
+        with telemetry.trace_scope("ingress.h2d", chunk=ck):
+            faults.fire("h2d")
+            dev = h2d(payload)
     except Exception as e:  # see _timed_prep: interrupts pass through
         raise PrepError(
             "ingress h2d stage failed for chunk %r:\n%s"
@@ -309,7 +314,6 @@ def _prep_then_h2d(prep: Callable, h2d: Callable, item,
     dt = time.perf_counter() - t0
     if timers is not None:
         timers.add("h2d", dt)
-    par, ck = _span_cell(cell, item)
     telemetry.record_span("ingress.h2d", t0, dt, parent=par, chunk=ck)
     _mark(cell, "done")
     return dev
@@ -507,25 +511,28 @@ def run_pipeline(items: Iterable, prep: Callable, h2d: Callable,
         return telemetry.chunk_ctx(telemetry.chunk_key(it))
 
     def _finalize(item, raw, tctx=None):
+        par, ck = _span_cell({"tctx": tctx}, item)
         t0 = time.perf_counter()
 
         def _call():
             faults.fire("finalize")
             finalize(raw)
 
-        if guard:
-            # deadline only, NEVER retried: finalize mutates consumer
-            # state (appends results, advances carried mirrors), so a
-            # re-run would double-apply; a hang still surfaces as a
-            # typed StageTimeout instead of stalling the stream
-            resilience.call_guarded("finalize", item, _call, retries=0)
-        else:
-            _call()
+        with telemetry.trace_scope("ingress.finalize", chunk=ck):
+            if guard:
+                # deadline only, NEVER retried: finalize mutates
+                # consumer state (appends results, advances carried
+                # mirrors), so a re-run would double-apply; a hang
+                # still surfaces as a typed StageTimeout instead of
+                # stalling the stream
+                resilience.call_guarded("finalize", item, _call,
+                                        retries=0)
+            else:
+                _call()
         dt = time.perf_counter() - t0
         if timers is not None:
             timers.add("compute", dt)
             timers.chunks += 1
-        par, ck = _span_cell({"tctx": tctx}, item)
         telemetry.record_span("ingress.finalize", t0, dt, parent=par,
                               chunk=ck)
         telemetry.close_chunk(tctx)
@@ -547,15 +554,16 @@ def run_pipeline(items: Iterable, prep: Callable, h2d: Callable,
             disp_tags.update(telemetry.pop_dispatch_tags())
             return out
 
+        par, ck = _span_cell({"tctx": tctx}, item)
         t0 = time.perf_counter()
         telemetry.pop_dispatch_tags()  # drop any stale pre-dispatch tag
         # dispatch is retries=0 too: engines fold the chunk into a
         # device-resident carry inside it, so re-running would
         # double-fold the chunk
-        raw = (resilience.call_guarded("dispatch", item, _call,
-                                       retries=0)
-               if guard else _call())
-        par, ck = _span_cell({"tctx": tctx}, item)
+        with telemetry.trace_scope("ingress.dispatch", chunk=ck):
+            raw = (resilience.call_guarded("dispatch", item, _call,
+                                           retries=0)
+                   if guard else _call())
         telemetry.record_span("ingress.dispatch", t0,
                               time.perf_counter() - t0, parent=par,
                               chunk=ck, **disp_tags)

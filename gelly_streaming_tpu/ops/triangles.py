@@ -776,6 +776,14 @@ def _tuned_chunk(eb: int) -> int:
     return _TUNED_CHUNK[eb]
 
 
+def _readback_counter(*outs) -> None:
+    """The stream program's d2h in the driver's read-back counter.
+    `windows=0`: the snapshot scan's counter already counts the
+    driver's windows, so each is counted once."""
+    telemetry.counter("driver.readback_bytes",
+                      sum(o.nbytes for o in outs), windows=0)
+
+
 class TriangleWindowKernel:
     """One compiled program for an unbounded stream of windows.
 
@@ -993,6 +1001,7 @@ class TriangleWindowKernel:
             at, n, c_dev, o_dev = raw
             # np.array (not asarray): device outputs can be read-only
             c, o = np.array(c_dev)[:n], np.array(o_dev)[:n]  # gslint: disable=host-sync (sanctioned finalize boundary: the chunk's ONE batched [W]-scalar d2h, pipelined one chunk behind dispatch)
+            _readback_counter(c_dev, o_dev)
             for w in np.nonzero(o)[0]:  # rare hub overflow: exact redo
                 c[w] = recount(at + int(w))
             counts.extend(int(x) for x in c)
@@ -1166,6 +1175,7 @@ class TriangleWindowKernel:
         def finalize(raw):
             at, m, c_dev, o_dev = raw
             c, o = np.array(c_dev)[:m], np.array(o_dev)[:m]  # gslint: disable=host-sync (sanctioned finalize boundary: the tuned round's ONE batched [W]-scalar d2h)
+            _readback_counter(c_dev, o_dev)
             for w in np.nonzero(o)[0]:  # rare hub overflow: exact redo
                 c[w] = recount(at + int(w), kb)
             counts.extend(int(x) for x in c)

@@ -45,10 +45,12 @@ def cc_round(labels: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
 
 
 def cc_fixpoint(labels0: jax.Array, src: jax.Array, dst: jax.Array,
-                exchange=None, carried: bool = True) -> jax.Array:
+                exchange=None, carried: bool = True, rounds: bool = False):
     """Run cc_round + pointer jumping to the fixpoint inside a
     while_loop; `exchange` (e.g. a pmin over the mesh axis) merges
-    labels across shards each round.
+    labels across shards each round. The loop carries an int32 count
+    of its rounds, the sweeps it took (1 when the edges change
+    nothing); with `rounds` it returns (labels, rounds).
 
     With `carried` (labels0 is a prior forest, not a fresh arange), the
     forest's parent links (v, labels0[v]) participate as edges in every
@@ -68,20 +70,21 @@ def cc_fixpoint(labels0: jax.Array, src: jax.Array, dst: jax.Array,
         dst = jnp.concatenate([dst.astype(jnp.int32), fdst])
 
     def cond(state):
-        _, changed = state
+        _, changed, _ = state
         return changed
 
     def body(state):
-        labels, _ = state
+        labels, _, n = state
         new = cc_round(labels, src, dst)
         if exchange is not None:
             new = exchange(new)
         # pointer jumping: jump each label to its label's label
         new = new[new]
-        return new, jnp.any(new != labels)
+        return new, jnp.any(new != labels), n + 1
 
-    labels, _ = jax.lax.while_loop(cond, body, (labels0, jnp.array(True)))
-    return labels
+    labels, _, n = jax.lax.while_loop(
+        cond, body, (labels0, jnp.array(True), jnp.int32(0)))
+    return (labels, n) if rounds else labels
 
 
 @functools.partial(jax.jit, static_argnames=("num_vertices",))

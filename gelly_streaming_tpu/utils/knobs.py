@@ -301,9 +301,10 @@ register("GS_TELEMETRY", "bool", False,
          help="arm the flight recorder (`utils/telemetry.py`): "
               "unified spans/counters/gauges with per-run trace IDs "
               "and per-chunk correlation across every layer; off, "
-              "every hook is a guarded no-op and the hot path is "
-              "bit-identical (bench A/B sections run disarmed by "
-              "default)",
+              "nothing reaches the ring or the ledger and the hot "
+              "path is bit-identical (bench A/B sections run disarmed "
+              "by default). Armed or not, spans and counters also "
+              "land in a live `jax.profiler` capture",
          default_text="0 (off)")
 register("GS_TRACE_DIR", "path", None,
          help="directory of the crash-safe JSONL run ledger "

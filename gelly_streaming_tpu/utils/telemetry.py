@@ -31,11 +31,23 @@ This module is the ONE recorder they all feed:
   (tools/trace_report.py) skip a torn final line, the same
   damage-tolerant discipline as utils/checkpoint.
 
-Zero-overhead contract: with `GS_TELEMETRY=0` (the default) every
-recording call is a guarded no-op and `span()` degrades to a bare
-perf_counter stopwatch — exactly the measurement the migrated call
-sites performed before — so the hot path is bit-identical armed or
-not (asserted by tests/test_telemetry.py digest parity).
+- The **live profiler session** is the second sink: while a
+  `jax.profiler` capture records (`jax.profiler.trace`, the
+  benchmark's `--trace 1`), every span also opens a
+  `TraceAnnotation` carrying its attributes, and every counter emits
+  a zero-length event carrying its value, so the program's own spans
+  land in the capture on the device planes' clock. This sink is
+  independent of `GS_TELEMETRY`; stopwatches and after-the-fact
+  `record_span` intervals stay off it (`trace_scope` gives such a
+  site a live scope).
+
+Zero-overhead contract: with `GS_TELEMETRY=0` (the default) and no
+profiler session, every recording call is a guarded no-op and
+`span()` degrades to a bare perf_counter stopwatch — exactly the
+measurement the migrated call sites performed before — so the hot
+path is bit-identical armed or not (asserted by
+tests/test_telemetry.py digest parity). The profiler guard is one
+`TraceMe.is_enabled()` call per span or counter.
 
 Knobs:
     GS_TELEMETRY      0 (default) = disarmed no-ops; 1 = record
@@ -67,8 +79,9 @@ clock = time.perf_counter  # the one monotonic clock every record uses
 # tools flip them mid-process)
 # ----------------------------------------------------------------------
 def enabled() -> bool:
-    """GS_TELEMETRY arms the recorder; off (the default) every hook is
-    a guarded no-op and span() is a bare stopwatch."""
+    """GS_TELEMETRY arms the recorder; off (the default) nothing
+    reaches the ring or the ledger and span() is a bare stopwatch (a
+    live profiler session still sees spans and counters)."""
     return knobs.get_bool("GS_TELEMETRY")
 
 
@@ -403,15 +416,44 @@ def _record(kind: str, name: str, durable: bool = False,
 
 
 # ----------------------------------------------------------------------
+# the live profiler session (the second sink)
+# ----------------------------------------------------------------------
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, bound on first use
+_UNSET = object()
+
+
+def _profiling():
+    """The `TraceAnnotation` class while a jax.profiler session
+    records, else None: one `is_enabled()` call off the capture."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION if _ANNOTATION.is_enabled() else None
+
+
+def trace_scope(name: str, **attrs):
+    """A profiler-only scope (nothing reaches the ring or the ledger):
+    for sites that time themselves and report through `record_span`
+    after the work, so the capture still sees the work as it runs."""
+    ann = _profiling()
+    return ann(name, **attrs) if ann is not None \
+        else contextlib.nullcontext()
+
+
+# ----------------------------------------------------------------------
 # spans
 # ----------------------------------------------------------------------
 class _Span:
     """Context manager AND stopwatch. Always measures (callers like
     the autotune round loops need `.elapsed` whether or not telemetry
-    is armed); records only when armed at __exit__ time. Nesting is
-    tracked per thread via the span-id stack."""
+    is armed); records only when armed at __exit__ time, and opens a
+    profiler annotation while a session records. Nesting is tracked
+    per thread via the span-id stack."""
 
-    __slots__ = ("name", "attrs", "t0", "elapsed", "sid", "_pushed")
+    __slots__ = ("name", "attrs", "t0", "elapsed", "sid", "_pushed",
+                 "_ann", "_ann_attrs")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -420,8 +462,15 @@ class _Span:
         self.elapsed = 0.0
         self.sid = None
         self._pushed = False
+        self._ann = None
+        self._ann_attrs = None
 
     def __enter__(self):
+        ann = _profiling()
+        if ann is not None:
+            self._ann_attrs = dict(self.attrs)
+            self._ann = ann(self.name, **self._ann_attrs)
+            self._ann.__enter__()
         self.t0 = clock()
         if enabled():
             self.sid = _rec().sid()
@@ -437,6 +486,8 @@ class _Span:
         if self._pushed:
             _TLS.stack.pop()
             self._pushed = False
+        if self._ann is not None:
+            self._close_annotation(exc_type, exc, tb)
         if _active():
             par = _parent_sid()
             a = dict(self.attrs) if self.attrs else {}
@@ -445,6 +496,22 @@ class _Span:
             _record("span", self.name, ts=self.t0, dur=self.elapsed,
                     sid=self.sid, par=par, a=a or None)
         return False
+
+    def _close_annotation(self, exc_type, exc, tb) -> None:
+        """Attributes set inside the span (the driver's dispatch tags)
+        and the error go on as metadata; the annotation closes on
+        every path."""
+        ann, self._ann = self._ann, None
+        try:
+            late = {k: v for k, v in self.attrs.items()
+                    if self._ann_attrs.get(k, _UNSET) is not v}
+            if exc_type is not None:
+                late["error"] = exc_type.__name__
+            if late:
+                ann.set_metadata(**late)
+        finally:
+            self._ann_attrs = None
+            ann.__exit__(exc_type, exc, tb)
 
 
 def span(name: str, **attrs) -> _Span:
@@ -555,7 +622,7 @@ def chunk_key(item):
         return int(item)
     if isinstance(item, tuple) and item \
             and isinstance(item[0], numbers.Integral):
-        return int(item[0])
+        return int(item[0])  # gslint: disable=host-sync (a chunk descriptor's host int, never a device value)
     return None
 
 
@@ -573,6 +640,12 @@ def event(name: str, durable: bool = False, **attrs) -> None:
 
 
 def counter(name: str, value: float = 1, **attrs) -> None:
+    """Add `value` to counter `name`. A live profiler session gets a
+    zero-length event with `value` and the attrs, armed or not."""
+    ann = _profiling()
+    if ann is not None:
+        with ann(name, value=value, **attrs):
+            pass
     if not _active():
         return
     _record("counter", name, ts=clock(), value=value, a=attrs or None)
@@ -610,7 +683,7 @@ def percentiles(samples, ps=(50, 95, 99)) -> Dict[int, float]:
     out = {}
     for p in ps:
         rank = max(1, -(-p * n // 100))  # ceil(p*n/100), 1-based
-        out[p] = float(xs[min(rank, n) - 1])
+        out[p] = float(xs[min(rank, n) - 1])  # gslint: disable=host-sync (recorded host durations, never device values)
     return out
 
 

@@ -10,22 +10,15 @@ getNetRuntime, CentralizedWeightedMatching.java:62-64). Here:
   armed recorder sees every step as a `step.<name>` span with the
   run's trace ID), while `report()`/`event_log()` and their
   accumulation semantics are unchanged for existing call sites.
-- `device_trace` — context manager around `jax.profiler.trace` for a
-  TensorBoard-readable XLA trace of the device kernels. Graceful by
-  contract: the log directory is created, a backend that cannot trace
-  (or a nested trace — jax allows one at a time) degrades to a no-op
-  with a telemetry event instead of taking down the stream it was
-  asked to observe, and a completed capture stamps a durable
-  `device_trace_captured` event carrying the log dir plus the cost
-  observatory's program inventory (utils/costmodel), so an on-chip
-  xprof capture is joinable with the cost registry it profiled.
+
+A device trace is `jax.profiler.trace(log_dir)` itself: inside a
+capture every telemetry span, `step.<name>` included, is a profiler
+annotation on the device planes' clock (utils/telemetry).
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
-import threading
 from collections import defaultdict
 from typing import Dict, List
 
@@ -92,56 +85,3 @@ class StepTimer:
                 f" {row['records']:>8} {row['records_per_s']:>7}"
             )
         return "\n".join(lines)
-
-
-# device_trace nesting guard: jax.profiler allows ONE trace at a time;
-# a nested device_trace degrades to a no-op instead of raising inside
-# the stream it observes. Depth is written under the lock only.
-_TRACE_LOCK = threading.Lock()
-_TRACE_DEPTH = 0
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str):
-    """XLA device trace (view in TensorBoard / xprof). Graceful: the
-    log dir is created, an untraceable backend (or a failed profiler
-    start) yields a no-op with a `device_trace_failed` telemetry
-    event, nested captures no-op under the outermost one, and a
-    completed capture stamps a durable `device_trace_captured` event
-    with the log dir + the cost observatory's captured-program count
-    — the on-chip feed that makes an xprof capture joinable with the
-    cost registry (utils/costmodel) it profiled."""
-    global _TRACE_DEPTH
-    import jax
-
-    os.makedirs(log_dir, exist_ok=True)
-    started = False
-    with _TRACE_LOCK:
-        if _TRACE_DEPTH == 0:
-            try:
-                jax.profiler.start_trace(log_dir)
-                started = True
-            except Exception as e:
-                telemetry.event(
-                    "device_trace_failed", log_dir=str(log_dir),
-                    error="%s: %s" % (type(e).__name__, e))
-        _TRACE_DEPTH += 1
-    try:
-        yield
-    finally:
-        with _TRACE_LOCK:
-            _TRACE_DEPTH -= 1
-            if started:
-                try:
-                    jax.profiler.stop_trace()
-                except Exception as e:
-                    telemetry.event(
-                        "device_trace_failed", log_dir=str(log_dir),
-                        error="stop: %s: %s" % (type(e).__name__, e))
-                else:
-                    from . import costmodel
-
-                    telemetry.event(
-                        "device_trace_captured", durable=True,
-                        log_dir=str(log_dir),
-                        programs=len(costmodel.programs()))

@@ -1,5 +1,6 @@
 """Compile the main path's programs for a described TPU v5e, no chip
-attached (on-chip-measurement §2): the XLA fused scan, the Pallas
+attached (ahead-of-time, against a described topology): the XLA fused scan, the driver's
+snapshot scan at the benchmark's size, the Pallas
 kernels that lower for the chip, and the sharded snapshot scan on a
 2x2 mesh. A kernel the chip's compiler refuses is pinned here with the
 reason docs and ROADMAP give, so a redesign that makes it compile
@@ -79,6 +80,24 @@ def test_xla_fused_scan_compiles(one_chip):
         _carry(one_chip), _window(one_chip, lead=(4,))).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_driver_snapshot_scan_compiles_with_round_counts(one_chip):
+    """The driver's single-chip snapshot scan at the benchmark's size
+    (2^20 slots, 8 windows of 32,768 edges) compiles for the chip, and
+    emits each window's CC and double-cover round counts."""
+    from gelly_streaming_tpu.core.driver import _build_snapshot_scan
+
+    vb, w, eb = 1 << 20, 8, 32768
+    carry = (_sds((vb + 1,), jnp.int32, one_chip),
+             _sds((vb + 1,), jnp.int32, one_chip),
+             _sds((2 * vb + 1,), jnp.int32, one_chip))
+    fn = _build_snapshot_scan(vb, ("degrees", "cc", "bipartite"))
+    args = (carry,) + _window(one_chip, eb=eb, lead=(w,))
+    fn.lower(*args).compile()
+    outs = jax.eval_shape(fn, *args)[1]
+    for key in ("cc_rounds", "cover_rounds"):
+        assert (outs[key].shape, outs[key].dtype) == ((w,), jnp.int32)
 
 
 def test_intersect_pallas_compiles(one_chip, chip_lowering):

@@ -559,3 +559,119 @@ def test_stream_file_tolerates_malformed_lines(tmp_path):
     res = list(e.stream_file(str(m)))
     assert [(r.window_start, int(r.degrees.sum())) for r in res] == \
         [(100, 2), (200, 4)]
+
+
+# ----------------------------------------------------------------------
+# the snapshot scan's counters, read back with the snapshots
+# ----------------------------------------------------------------------
+def _np_fixpoint_rounds(labels, s, d):
+    """The carried fixpoint in numpy: cc_round (the window's edges plus
+    the forest's parent links; both endpoints and both roots take the
+    smaller label) then pointer jumping, until a round changes
+    nothing. Returns (labels, rounds)."""
+    src = np.concatenate([s, np.arange(len(labels))])
+    dst = np.concatenate([d, labels])
+    rounds = 0
+    while True:
+        ls, ld = labels[src], labels[dst]
+        m = np.minimum(ls, ld)
+        new = labels.copy()
+        for idx in (src, dst, ls, ld):
+            np.minimum.at(new, idx, m)
+        new = new[new]
+        rounds += 1
+        if np.array_equal(new, labels):
+            return new, rounds
+        labels = new
+
+
+def _counters(name):
+    from gelly_streaming_tpu.utils import telemetry
+
+    return [(r["value"], r["a"]["windows"]) for r in telemetry.records()
+            if r["t"] == "counter" and r["name"] == name]
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    from gelly_streaming_tpu.utils import telemetry
+
+    monkeypatch.setenv("GS_TELEMETRY", "1")
+    monkeypatch.delenv("GS_TRACE_DIR", raising=False)
+    monkeypatch.setenv("GS_AUTOTUNE", "0")
+    telemetry.reset()
+    yield
+    telemetry.reset()
+
+
+@pytest.mark.parametrize("tier,egress", [
+    ("scan", "full"), ("scan", "delta"), ("resident", "full")])
+def test_scan_fixpoint_rounds_match_numpy(recorder, tier, egress):
+    eb, calls, per_call = 16, 3, 8
+    rng = np.random.default_rng(11)
+    src = rng.integers(0, 40, eb * per_call * calls)
+    dst = rng.integers(0, 40, eb * per_call * calls)
+    drv = StreamingAnalyticsDriver(
+        window_ms=0, analytics=("cc", "bipartite"), vertex_bucket=64,
+        edge_bucket=eb, snapshot_tier=tier, egress=egress)
+    out = []
+    for k in range(calls):
+        part = slice(k * eb * per_call, (k + 1) * eb * per_call)
+        out += drv.run_arrays(src[part], dst[part])
+    vb = drv.vb
+    slot = {int(x): i for i, x in enumerate(out[-1].vertex_ids)}
+    lab = np.arange(vb + 1)
+    cov = np.arange(2 * vb + 1)
+    want_cc, want_cover = [], []
+    for w in range(len(out)):
+        s = np.array([slot[int(x)] for x in src[w * eb:(w + 1) * eb]])
+        d = np.array([slot[int(x)] for x in dst[w * eb:(w + 1) * eb]])
+        lab, n = _np_fixpoint_rounds(lab, s, d)
+        want_cc.append(n)
+        cov, n = _np_fixpoint_rounds(
+            cov, np.concatenate([s, s + vb]), np.concatenate([d + vb, d]))
+        want_cover.append(n)
+        # the numpy loop is the device's fixpoint: same labels
+        nv = len(out[w].vertex_ids)
+        np.testing.assert_array_equal(out[w].cc_labels, lab[:nv])
+        np.testing.assert_array_equal(out[w].bipartite_odd,
+                                      (cov[:vb] == cov[vb:2 * vb])[:nv])
+    for name, want in (("driver.cc_rounds", want_cc),
+                       ("driver.cover_rounds", want_cover)):
+        got, at = _counters(name), 0
+        assert sum(w for _v, w in got) == len(out)
+        for value, windows in got:
+            assert value == sum(want[at:at + windows]), name
+            at += windows
+    assert max(want_cc) > 1 and max(want_cover) > 1
+
+
+def test_window_that_changes_nothing_takes_one_round(recorder):
+    drv = StreamingAnalyticsDriver(
+        window_ms=0, analytics=("degrees", "cc", "bipartite"),
+        vertex_bucket=64, edge_bucket=8, snapshot_tier="scan",
+        egress="full")
+    # two windows a call: one window alone takes the per-window path
+    s = np.tile([1, 2, 3, 4, 5, 6, 7, 1], 2)
+    d = np.tile([2, 3, 4, 5, 6, 7, 1, 3], 2)
+    drv.run_arrays(s, d)
+    from gelly_streaming_tpu.utils import telemetry
+
+    telemetry.reset()
+    drv.run_arrays(s, d)   # every edge already folded
+    assert _counters("driver.cc_rounds") == [(2, 2)]
+    assert _counters("driver.cover_rounds") == [(2, 2)]
+
+
+def test_readback_bytes_follow_the_output_shapes(recorder):
+    vb, eb = 64, 16
+    rng = np.random.default_rng(5)
+    src, dst = rng.integers(0, 40, 8 * eb), rng.integers(0, 40, 8 * eb)
+    drv = StreamingAnalyticsDriver(
+        window_ms=0, analytics=("degrees", "cc", "bipartite"),
+        vertex_bucket=vb, edge_bucket=eb, snapshot_tier="scan",
+        egress="full")
+    drv.run_arrays(src, dst)
+    # degrees and labels [vb+1], cover [2vb+1] int32, two int32 rounds
+    per_window = 4 * (4 * drv.vb + 3) + 8
+    assert _counters("driver.readback_bytes") == [(8 * per_window, 8)]

@@ -637,6 +637,8 @@ def test_explain_perf_stage_attribution_and_containers():
         {"t": "span", "name": "ingress.finalize", "trace": "cost-1",
          "tid": 1, "ts": 0.4, "dur": 0.5, "sid": 4, "par": 10,
          "a": {"chunk": 0}},                # ...leaf: envelope excluded
+        {"t": "span", "name": "step.snapshot_extract", "trace": "cost-1",
+         "tid": 1, "ts": 0.92, "dur": 0.04, "sid": 11},  # host extraction
     ]
     stages, attributed, ledger_total, unmapped = \
         explain_perf.stage_attribution(records)
@@ -644,10 +646,12 @@ def test_explain_perf_stage_attribution_and_containers():
     assert by_stage["dispatch"]["total_s"] == 0.25
     assert by_stage["prep"]["total_s"] == 0.01
     # step.triangles maps to a stage but PARENTS the finalize span —
-    # only the child leaf counts, never both
-    assert by_stage["d2h+finalize"]["total_s"] == 0.5
+    # only the child leaf counts, never both; the snapshot extraction
+    # after the d2h joins the same stage
+    assert by_stage["d2h+finalize"]["total_s"] == 0.54
+    assert by_stage["d2h+finalize"]["count"] == 2
     assert by_stage["dispatch"]["count"] == 1
-    assert attributed == pytest.approx(0.76, abs=1e-6)
+    assert attributed == pytest.approx(0.80, abs=1e-6)
     assert attributed == pytest.approx(ledger_total, rel=1e-3)
     assert unmapped == []
     # program attribution: the finalize span's d2h time lands on the

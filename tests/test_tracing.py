@@ -1,28 +1,23 @@
-"""utils/tracing direct coverage (previously tested only through the
-driver): the `device_trace` graceful-capture contract and the
-StepTimer span adapter.
+"""The recorder's second sink, the live profiler session, and the
+StepTimer adapter (utils/tracing, utils/telemetry).
 
-- log-dir creation: the trace dir (nested) is created before the
-  profiler starts, so a fresh GS_TRACE_DIR-style path never fails the
-  capture it was meant to hold;
-- graceful path: a backend whose profiler cannot start degrades to a
-  no-op with a `device_trace_failed` telemetry event instead of
-  taking down the stream it was asked to observe, and stop is never
-  called for a start that failed;
-- nesting: jax.profiler allows ONE trace at a time — an inner
-  device_trace no-ops under the outermost one (exactly one
-  start/stop pair), including across threads;
-- the completed-capture stamp: a real CPU capture finishes clean and
-  leaves a durable `device_trace_captured` event carrying the log dir
-  and the cost observatory's program inventory — the join key that
-  makes an on-chip xprof capture attributable;
+- Bridge: under a CPU `jax.profiler` capture with the recorder
+  disarmed (`GS_TELEMETRY=0`), spans (plain, nested, raising, with
+  attributes set inside), counters, profiler-only scopes and
+  `StepTimer.step` all reach the host plane with their stats, and the
+  ring stays empty.
+- The ingress pipeline's pool-side prep/h2d stages show on worker
+  thread lines; dispatch and finalize on the caller's.
+- Off the capture no annotation is ever constructed: the guard is one
+  `is_enabled()` call.
 - StepTimer: `step()` yields the telemetry span (so dispatch-owning
   steps can attach program/sig attrs) while report()/event_log() keep
   their accumulation semantics.
 """
 
+import glob
 import os
-import threading
+import time
 
 import pytest
 
@@ -38,134 +33,199 @@ def armed(tmp_path, monkeypatch):
     telemetry.reset()
 
 
-class _FakeProfiler:
-    """Deterministic stand-in for jax.profiler: counts start/stop
-    pairs, optionally fails on start."""
+def _host_events(log_dir: str) -> dict:
+    """{name: [(line, start_ns, end_ns, stats)]} over the host plane."""
+    from jax.profiler import ProfileData
 
-    def __init__(self, fail_start=False):
-        self.starts = 0
-        self.stops = 0
-        self.fail_start = fail_start
+    path = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    out, n = {}, 0
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (n, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats)))
+            n += 1
+    return out
 
-    def start_trace(self, log_dir):
-        if self.fail_start:
-            raise RuntimeError("profiler unavailable on this backend")
-        self.starts += 1
 
-    def stop_trace(self):
-        self.stops += 1
-
-
-@pytest.fixture
-def fake_profiler(monkeypatch):
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    """One CPU capture of every bridged hook, the recorder disarmed;
+    returns (host events, the ring's records after the capture)."""
     import jax
 
-    prof = _FakeProfiler()
-    monkeypatch.setattr(jax, "profiler", prof)
-    return prof
+    from gelly_streaming_tpu.ops import ingress_pipeline
 
-
-# ----------------------------------------------------------------------
-# log-dir creation + the one-start-one-stop contract
-# ----------------------------------------------------------------------
-def test_device_trace_creates_log_dir(tmp_path, fake_profiler):
-    log_dir = str(tmp_path / "traces" / "run0")  # nested, absent
-    with tracing.device_trace(log_dir):
-        assert os.path.isdir(log_dir)
-    assert (fake_profiler.starts, fake_profiler.stops) == (1, 1)
-
-
-def test_device_trace_nested_is_noop(tmp_path, fake_profiler):
-    log_dir = str(tmp_path / "t")
-    with tracing.device_trace(log_dir):
-        with tracing.device_trace(log_dir):
-            with tracing.device_trace(log_dir):
-                pass
-        # inner exits must not stop the outer capture
-        assert fake_profiler.stops == 0
-    assert (fake_profiler.starts, fake_profiler.stops) == (1, 1)
-
-
-def test_device_trace_nested_across_threads(tmp_path, fake_profiler):
-    """The nesting guard is process-global (jax.profiler is): a
-    concurrent capture from another thread no-ops too."""
-    log_dir = str(tmp_path / "t")
-    entered = threading.Event()
-    release = threading.Event()
-
-    def inner():
-        with tracing.device_trace(log_dir):
-            entered.set()
-            release.wait(timeout=10)
-
-    with tracing.device_trace(log_dir):
-        t = threading.Thread(target=inner)
-        t.start()
-        assert entered.wait(timeout=10)
-        assert fake_profiler.starts == 1    # inner never started
-        release.set()
-        t.join()
-    assert (fake_profiler.starts, fake_profiler.stops) == (1, 1)
-
-
-# ----------------------------------------------------------------------
-# graceful degradation
-# ----------------------------------------------------------------------
-def test_device_trace_failed_start_degrades_to_noop(
-        tmp_path, armed, monkeypatch):
-    import jax
-
-    prof = _FakeProfiler(fail_start=True)
-    monkeypatch.setattr(jax, "profiler", prof)
-    log_dir = str(tmp_path / "t")
-    with tracing.device_trace(log_dir):
-        pass                                # body still runs
-    assert prof.stops == 0                  # no stop for a failed start
-    evs = [r for r in telemetry.records() if r["t"] == "event"]
-    fail = next(e for e in evs if e["name"] == "device_trace_failed")
-    assert "profiler unavailable" in fail["a"]["error"]
-    assert not any(e["name"] == "device_trace_captured" for e in evs)
-
-
-def test_device_trace_body_exception_still_stops(tmp_path,
-                                                 fake_profiler):
-    with pytest.raises(ValueError):
-        with tracing.device_trace(str(tmp_path / "t")):
-            raise ValueError("stream died mid-capture")
-    assert (fake_profiler.starts, fake_profiler.stops) == (1, 1)
-
-
-# ----------------------------------------------------------------------
-# the real CPU path + the captured stamp feeding the observatory
-# ----------------------------------------------------------------------
-def test_device_trace_cpu_capture_stamps_durable_event(
-        tmp_path, armed, monkeypatch):
-    """End-to-end on the real jax.profiler (CPU): the capture
-    completes, and the durable `device_trace_captured` event carries
-    the log dir plus the cost observatory's program count — the
-    correlation record an on-chip xprof session is joined by."""
-    import jax.numpy as jnp
-
-    from gelly_streaming_tpu.utils import costmodel, metrics
-
-    monkeypatch.setenv("GS_COSTMODEL", "1")
-    costmodel.reset()
+    saved = {k: os.environ.get(k)
+             for k in ("GS_TELEMETRY", "GS_PIPELINE_WORKERS")}
+    os.environ.update(GS_TELEMETRY="0", GS_PIPELINE_WORKERS="2")
+    telemetry.reset()
+    ingress_pipeline.reset_pool()
+    log_dir = str(tmp_path_factory.mktemp("capture"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
-        log_dir = str(tmp_path / "xla")
-        with tracing.device_trace(log_dir):
-            fn = metrics.wrap_jit(
-                "trace_toy", __import__("jax").jit(lambda x: x + 1))
-            fn(jnp.arange(8)).block_until_ready()
-        assert os.path.isdir(log_dir)
-        evs = [r for r in telemetry.records() if r["t"] == "event"]
-        cap = next(e for e in evs
-                   if e["name"] == "device_trace_captured")
-        assert cap["a"]["log_dir"] == log_dir
-        # the program the capture profiled is in the inventory count
-        assert cap["a"]["programs"] >= 1
-        assert ("trace_toy", "i32[8]") in costmodel.programs()
+        with telemetry.span("bridge.main"):
+            with telemetry.span("bridge.span", records=3):
+                pass
+            with telemetry.span("bridge.outer"):
+                with telemetry.span("bridge.inner", depth=2):
+                    pass
+            with pytest.raises(ValueError):
+                with telemetry.span("bridge.raises", records=1):
+                    raise ValueError("stage died")
+            with telemetry.span("bridge.after"):
+                pass
+            with telemetry.span("bridge.late", records=4) as sp:
+                sp.attrs.update(program="snapshot_scan", sig="i32[4]")
+            telemetry.counter("bridge.count", 7, windows=2)
+            with telemetry.trace_scope("bridge.scope", chunk=5):
+                pass
+            with tracing.StepTimer().step("bridge_step", 9):
+                pass
+
+            def prep(item):
+                time.sleep(0.002)
+                return item
+
+            ingress_pipeline.run_pipeline(
+                range(4), prep, lambda p: p, lambda d: d,
+                lambda raw: None)
+        records = telemetry.records()
     finally:
-        costmodel.reset()
+        jax.profiler.stop_trace()
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+        ingress_pipeline.reset_pool()
+        telemetry.reset()
+    return _host_events(log_dir), records
+
+
+def _one(events, name):
+    got = events.get(name, [])
+    assert len(got) == 1, (name, got)
+    return got[0]
+
+
+def _within(inner, outer) -> bool:
+    return (inner[0] == outer[0] and outer[1] <= inner[1]
+            and inner[2] <= outer[2])
+
+
+def test_span_reaches_host_plane_with_stats(capture):
+    events, _ = capture
+    line, start, end, stats = _one(events, "bridge.span")
+    assert stats == {"records": 3} and end >= start
+    assert _within(_one(events, "bridge.span"), _one(events, "bridge.main"))
+
+
+def test_nested_span_inside_its_parent(capture):
+    events, _ = capture
+    inner = _one(events, "bridge.inner")
+    assert inner[3] == {"depth": 2}
+    assert _within(inner, _one(events, "bridge.outer"))
+
+
+def test_raising_span_closes_with_error(capture):
+    events, _ = capture
+    raised = _one(events, "bridge.raises")
+    assert raised[3] == {"records": 1, "error": "ValueError"}
+    after = _one(events, "bridge.after")
+    # closed on the exception path: the next span is not inside it
+    assert raised[2] <= after[1]
+
+
+def test_attributes_set_inside_go_on_as_metadata(capture):
+    events, _ = capture
+    assert _one(events, "bridge.late")[3] == {
+        "records": 4, "program": "snapshot_scan", "sig": "i32[4]"}
+
+
+def test_counter_is_an_event_with_value_and_attrs(capture):
+    events, _ = capture
+    count = _one(events, "bridge.count")
+    assert count[3] == {"value": 7, "windows": 2}
+    assert _within(count, _one(events, "bridge.main"))
+
+
+def test_profiler_scope_and_steptimer_reach_the_capture(capture):
+    events, _ = capture
+    assert _one(events, "bridge.scope")[3] == {"chunk": 5}
+    assert _one(events, "step.bridge_step")[3] == {"records": 9}
+
+
+def test_bridge_needs_no_armed_recorder(capture):
+    _events, records = capture
+    assert records == []
+
+
+def test_ingress_worker_stages_on_worker_lines(capture):
+    events, _ = capture
+    main = _one(events, "bridge.main")
+    for name in ("ingress.dispatch", "ingress.finalize"):
+        got = events[name]
+        assert len(got) == 4 and all(_within(e, main) for e in got), name
+    for name in ("ingress.prep", "ingress.h2d"):
+        got = events[name]
+        assert len(got) == 4, name
+        assert all(e[0] != main[0] for e in got), name
+        assert sorted(e[3]["chunk"] for e in got) == [0, 1, 2, 3]
+
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: counts what the
+    hooks construct; `live` plays the session."""
+
+    live = False
+    made = []
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.live
+
+    def __init__(self, name, **attrs):
+        self.made.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **attrs):
+        pass
+
+
+def _every_hook():
+    with telemetry.span("a", n=1):
+        with tracing.StepTimer().step("b", 2):
+            pass
+    telemetry.counter("c", 3)
+    with telemetry.trace_scope("d"):
+        pass
+
+
+@pytest.mark.parametrize("live,made", [
+    (False, []), (True, ["a", "step.b", "c", "d"])])
+def test_annotations_only_inside_a_session(monkeypatch, live, made):
+    stub = type("Stub", (_CountingAnnotation,), {"live": live, "made": []})
+    monkeypatch.setattr(telemetry, "_ANNOTATION", stub)
+    monkeypatch.setenv("GS_TELEMETRY", "0")
+    telemetry.reset()
+    try:
+        _every_hook()
+        assert stub.made == made
+        assert telemetry.records() == []
+    finally:
+        telemetry.reset()
 
 
 # ----------------------------------------------------------------------

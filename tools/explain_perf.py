@@ -76,6 +76,7 @@ STAGE_OF = {
     "step.triangles": "dispatch",
     "ingress.finalize": "d2h+finalize",
     "step.snapshot_wait": "d2h+finalize",
+    "step.snapshot_extract": "d2h+finalize",
     "step.checkpoint": "checkpoint",
 }
 CONTAINERS = {
