@@ -100,7 +100,10 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
     economics). Cover layout matches the driver's
     host state: (+) = v, (−) = vb + v, sentinel slot 2vb.
 
-    The CC and double-cover fixpoints also emit their round counts,
+    Each window folds into the CC and cover labels through their roots
+    (ops/unionfind.cc_fold_rooted): every carry that enters the scan is
+    flat and min-rooted, so a fixpoint round costs the window's edges,
+    not the table. Both folds also emit their round counts,
     `cc_rounds` and `cover_rounds` ([W] int32), in every egress and
     donate variant: the read-back's fixpoint counters (finalize).
 
@@ -156,8 +159,8 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
                 outs["deg"] = new_deg
             deg = new_deg
         if want_cc:
-            new_labels, outs["cc_rounds"] = uf.cc_fixpoint(
-                labels, s, d, rounds=True)
+            new_labels, outs["cc_rounds"] = uf.cc_fold_rooted(
+                labels, s, d)
             chg = (new_labels[:vb] != labels[:vb]) \
                 if (deltas or delta_out) else None
             if delta_out:
@@ -177,8 +180,8 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
             d2 = jnp.concatenate([
                 jnp.where(valid, d + vb, sent2),
                 jnp.where(valid, d, sent2)])
-            new_cover, outs["cover_rounds"] = uf.cc_fixpoint(
-                cover, s2, d2, rounds=True)
+            new_cover, outs["cover_rounds"] = uf.cc_fold_rooted(
+                cover, s2, d2)
             if deltas or delta_out:
                 # the consumer-visible value is the odd flag, so the
                 # mask (and the delta wire) tracks IT, not raw labels

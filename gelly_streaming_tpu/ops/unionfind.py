@@ -9,6 +9,17 @@ and for `Candidates`' O(C²·V) merge (example/util/Candidates.java:76-138):
   edge batch as one XLA program — scatter-min both directions plus
   `labels = labels[labels]` compression inside a `lax.while_loop`,
   converging in O(log diameter) rounds.
+- `cc_fixpoint(carried=True)`: fold a batch into a carried forest of
+  any shape — the forest's parent links ride along as edges in every
+  round, so a round sweeps every slot.
+- `cc_fold_rooted`: the driver's single-chip snapshot scan's fold.
+  Its carry is always flat and min-rooted (a converged fixpoint's
+  labeling), so each edge can be contracted to its endpoints' roots
+  once; the loop is then a fresh labeling of the contracted graph,
+  sized by the window's edges, and one gather after it relabels the
+  table. Same labels, bit for bit. The sharded scan (its per-round
+  pmin over shards), the cohort scan, the Pallas window kernel and
+  the per-window host wrappers keep `cc_fixpoint(carried=True)`.
 - `bipartite_labels`: 2-coloring via the bipartite double cover — the
   graph is bipartite iff (v,+) and (v,−) never share a component —
   which reduces bipartiteness to the same cc kernel (idiomatic
@@ -37,8 +48,11 @@ def cc_round(labels: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
     untouched members reach the smaller label via pointer jumping.
     Shared by the single-chip loop, the sharded loop (which adds a pmin
     exchange per round), and the fused entry step."""
-    ls = labels[src]
-    ld = labels[dst]
+    return _hook(labels, src, dst, labels[src], labels[dst])
+
+
+def _hook(labels, src, dst, ls, ld):
+    """cc_round's scatter-mins, given the endpoints' labels."""
     m = jnp.minimum(ls, ld)
     return (labels.at[src].min(m).at[dst].min(m)
             .at[ls].min(m).at[ld].min(m))
@@ -85,6 +99,49 @@ def cc_fixpoint(labels0: jax.Array, src: jax.Array, dst: jax.Array,
     labels, _, n = jax.lax.while_loop(
         cond, body, (labels0, jnp.array(True), jnp.int32(0)))
     return (labels, n) if rounds else labels
+
+
+def cc_fold_rooted(labels0: jax.Array, src: jax.Array, dst: jax.Array):
+    """Fold a window of edges into a FLAT, MIN-ROOTED carried forest
+    (`labels0[labels0] == labels0`, every slot labelled by its
+    component's least slot — what a converged fixpoint leaves) through
+    its roots. Returns (labels, rounds), labels bit-identical to
+    `cc_fixpoint(labels0, src, dst, carried=True, rounds=True)`'s.
+
+    Each edge is contracted once to its endpoints' roots
+    (labels0[src], labels0[dst]); a root starts at its own id, so the
+    loop is a fresh labeling of the contracted graph, which needs no
+    forest links (the island split cannot arise: no contracted edge
+    touches a non-root). Hooking, pointer jumping and the change test
+    run over the touched roots only, so every operation inside the
+    loop is sized by the window's edges, not by the table; the one
+    whole-table step is the flatten `lab[labels0]` after it, which
+    hands each root's new label to its members. The least slot of a
+    union of components is the least of their roots, so the result is
+    again flat and min-rooted."""
+    labels0 = labels0.astype(jnp.int32)
+    rs = labels0[src]
+    rd = labels0[dst]
+    touched = jnp.concatenate([rs, rd])
+    e = rs.shape[0]
+
+    def cond(state):
+        return state[2]
+
+    def body(state):
+        # `cur` is lab[touched], carried so that nothing reads the table
+        # after the round's scatters begin (no copy of it per round)
+        lab, cur, _, n = state
+        new = _hook(lab, rs, rd, cur[:e], cur[e:])
+        # pointer jumping over the touched roots (duplicates write the
+        # same value); hooked labels are themselves touched roots
+        nxt = new[new[touched]]
+        new = new.at[touched].set(nxt)
+        return new, nxt, jnp.any(nxt != cur), n + 1
+
+    lab, _, _, n = jax.lax.while_loop(
+        cond, body, (labels0, touched, jnp.array(True), jnp.int32(0)))
+    return lab[labels0], n
 
 
 @functools.partial(jax.jit, static_argnames=("num_vertices",))

@@ -1,6 +1,8 @@
 """Columnar streaming driver: end-to-end ingest→device analytics with
 carried state, bucket growth, sharding, and checkpoint/resume."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -585,6 +587,27 @@ def _np_fixpoint_rounds(labels, s, d):
         labels = new
 
 
+def _np_fold_rooted_rounds(labels, s, d):
+    """The scan's fold in numpy (ops/unionfind.cc_fold_rooted): the
+    window's edges contracted to their roots, cc_round over them, then
+    pointer jumping over the touched roots, until a round changes none
+    of them; one flatten after. Returns (labels, rounds)."""
+    rs, rd = labels[s], labels[d]
+    touched = np.concatenate([rs, rd])
+    lab = labels.copy()
+    rounds = 0
+    while True:
+        before = lab[touched]
+        ls, ld = lab[rs], lab[rd]
+        m = np.minimum(ls, ld)
+        for idx in (rs, rd, ls, ld):
+            np.minimum.at(lab, idx, m)
+        lab[touched] = lab[lab[touched]]
+        rounds += 1
+        if np.array_equal(lab[touched], before):
+            return lab[labels], rounds
+
+
 def _counters(name):
     from gelly_streaming_tpu.utils import telemetry
 
@@ -626,10 +649,18 @@ def test_scan_fixpoint_rounds_match_numpy(recorder, tier, egress):
     for w in range(len(out)):
         s = np.array([slot[int(x)] for x in src[w * eb:(w + 1) * eb]])
         d = np.array([slot[int(x)] for x in dst[w * eb:(w + 1) * eb]])
-        lab, n = _np_fixpoint_rounds(lab, s, d)
+        s2, d2 = np.concatenate([s, s + vb]), np.concatenate([d + vb, d])
+        # rounds: the fold through the roots, never more than the
+        # carried fixpoint's; labels: both give the same, bit for bit
+        folded, n = _np_fold_rooted_rounds(lab, s, d)
+        lab, n_old = _np_fixpoint_rounds(lab, s, d)
+        np.testing.assert_array_equal(folded, lab)
+        assert n <= n_old
         want_cc.append(n)
-        cov, n = _np_fixpoint_rounds(
-            cov, np.concatenate([s, s + vb]), np.concatenate([d + vb, d]))
+        folded, n = _np_fold_rooted_rounds(cov, s2, d2)
+        cov, n_old = _np_fixpoint_rounds(cov, s2, d2)
+        np.testing.assert_array_equal(folded, cov)
+        assert n <= n_old
         want_cover.append(n)
         # the numpy loop is the device's fixpoint: same labels
         nv = len(out[w].vertex_ids)
@@ -675,3 +706,180 @@ def test_readback_bytes_follow_the_output_shapes(recorder):
     # degrees and labels [vb+1], cover [2vb+1] int32, two int32 rounds
     per_window = 4 * (4 * drv.vb + 3) + 8
     assert _counters("driver.readback_bytes") == [(8 * per_window, 8)]
+
+
+# ----------------------------------------------------------------------
+# the scan folds each window through the carry's roots
+# ----------------------------------------------------------------------
+def test_snapshot_scan_matches_host_snapshot_with_sentinel_windows():
+    """The scan over a [W, eb] stack whose rows include all-invalid
+    (sentinel) windows and a ragged one gives the host twin's
+    snapshots window for window, from a carry the twin folded."""
+    import jax.numpy as jnp
+
+    from gelly_streaming_tpu.core.driver import _build_snapshot_scan
+    from gelly_streaming_tpu.ops import host_snapshot
+
+    vb, eb, w = 64, 16, 8
+    rng = np.random.default_rng(21)
+    deg = np.zeros(vb, np.int32)
+    lab = np.arange(vb, dtype=np.int32)
+    cov = np.arange(2 * vb, dtype=np.int32)
+    host_snapshot.snapshot_windows(   # folds the carry in place
+        rng.integers(0, 50, 6 * eb), rng.integers(0, 50, 6 * eb),
+        np.arange(0, 6 * eb + 1, eb), vb, deg, lab, cov)
+    s_w = rng.integers(0, 60, (w, eb)).astype(np.int32)
+    d_w = rng.integers(0, 60, (w, eb)).astype(np.int32)
+    valid = np.ones((w, eb), bool)
+    valid[[2, 5]] = False
+    valid[7, eb // 2:] = False
+    run = _build_snapshot_scan(vb, ("degrees", "cc", "bipartite"))
+    carry = (jnp.asarray(np.append(deg, 0)), jnp.asarray(np.append(lab, vb)),
+             jnp.asarray(np.append(cov, 2 * vb)))
+    _, outs = run(carry, jnp.asarray(s_w), jnp.asarray(d_w),
+                  jnp.asarray(valid))
+    offs = np.concatenate([[0], np.cumsum(valid.sum(1))])
+    want = host_snapshot.snapshot_windows(s_w[valid], d_w[valid], offs, vb,
+                                          deg, lab, cov)
+    for key, n in (("deg", vb), ("labels", vb), ("cover", 2 * vb)):
+        np.testing.assert_array_equal(np.asarray(outs[key])[:, :n],
+                                      want[key])
+    np.testing.assert_array_equal(np.asarray(outs["labels"])[:, vb], vb)
+    np.testing.assert_array_equal(np.asarray(outs["cover"])[:, 2 * vb],
+                                  2 * vb)
+    for key in ("cc_rounds", "cover_rounds"):
+        assert list(np.asarray(outs[key])[[2, 5]]) == [1, 1]
+
+
+def _flat_min_rooted(lab):
+    lab = np.asarray(lab)
+    return bool(np.array_equal(lab[lab], lab)
+                and np.all(lab <= np.arange(len(lab))))
+
+
+@pytest.mark.parametrize("entry", [
+    "fresh", "load_state_dict", "bucket_growth",
+    "engine_state_from_mirrors"])
+def test_scan_carries_enter_flat_and_min_rooted(monkeypatch, entry):
+    """The scan's fold needs a flat, min-rooted carry: every way one
+    enters the scan gives one — a fresh driver, a restored checkpoint,
+    the cover re-laid over a grown vertex bucket, and the engine-layout
+    state built from the host mirrors."""
+    from gelly_streaming_tpu.core import driver as driver_mod
+
+    carries = []
+    real = driver_mod._build_snapshot_scan
+
+    def spy(*args, **kwargs):
+        fn = real(*args, **kwargs)
+
+        def run(carry, *xs):
+            carries.append([np.asarray(c) for c in carry])
+            return fn(carry, *xs)
+        return run
+
+    monkeypatch.setattr(driver_mod, "_build_snapshot_scan", spy)
+    monkeypatch.setenv("GS_AUTOTUNE", "0")
+
+    def make():
+        return StreamingAnalyticsDriver(
+            window_ms=0, analytics=("degrees", "cc", "bipartite"),
+            vertex_bucket=64, edge_bucket=16, snapshot_tier="scan",
+            egress="full")
+
+    rng = np.random.default_rng(7)
+    half = 12 * 16
+    late = 200 if entry == "bucket_growth" else 50
+    src = np.concatenate([rng.integers(0, 50, half),
+                          rng.integers(0, late, half)])
+    dst = np.concatenate([rng.integers(0, 50, half),
+                          rng.integers(0, late, half)])
+    drv = make()
+    drv.run_arrays(src[:half], dst[:half])
+    if entry == "load_state_dict":
+        drv2 = make()
+        drv2.load_state_dict(drv.state_dict())
+        drv = drv2
+        carries.clear()
+    if entry == "engine_state_from_mirrors":
+        st = drv._engine_state_from_mirrors()
+        assert _flat_min_rooted(st["labels"])
+        assert _flat_min_rooted(st["bip_labels"])
+    drv.run_arrays(src[half:], dst[half:])
+    assert carries
+    for _deg, lab, cov in carries:
+        assert _flat_min_rooted(lab) and _flat_min_rooted(cov)
+    if entry == "bucket_growth":
+        assert drv.vb > 64
+        assert {len(lab) for _d, lab, _c in carries} == {65, drv.vb + 1}
+
+
+def _loop_ops_past(lowered, v):
+    """The ops inside each while loop that scatters into a table (the
+    fixpoint loops) that produce a tensor with a dimension of `v` or
+    more — a scatter's in-place result aside — and the scatters whose
+    updates have one. Returns (number of such loops, offenders)."""
+    def dims(t):
+        m = re.match(r"tensor<([0-9x]*)x?[a-z]", str(t))
+        return [int(x) for x in m.group(1).split("x") if x] if m else []
+
+    def walk(op):
+        for region in op.regions:
+            for block in region:
+                for child in block:
+                    yield child.operation
+                    yield from walk(child.operation)
+
+    module = lowered.compiler_ir("stablehlo")
+    loops, bad = 0, []
+    for loop in walk(module.operation):
+        if loop.name != "stablehlo.while":
+            continue
+        body = list(walk(loop))
+        if not any(o.name == "stablehlo.scatter" for o in body):
+            continue
+        loops += 1
+        for o in body:
+            if o.name == "stablehlo.scatter":
+                shapes = [dims(o.operands[2].type)]
+            else:
+                shapes = [dims(r.type) for r in o.results]
+            if any(d >= v for s in shapes for d in s):
+                bad.append((o.name, shapes))
+    return loops, bad
+
+
+@pytest.mark.parametrize("program", ["driver_scan", "carried_fixpoint"])
+def test_fold_loop_has_no_table_sized_ops(program):
+    """Inside the scan's CC and double-cover fixpoint loops nothing is
+    as large as the table: every scatter updates the window's touched
+    slots, every gather and compare reads them. The carried fixpoint
+    (forest links as edges, whole-table pointer jumping) is the
+    control that the check finds such ops in."""
+    import jax
+    import jax.numpy as jnp
+
+    from gelly_streaming_tpu.core.driver import _build_snapshot_scan
+
+    vb, eb, w = 1000, 16, 2
+    i32 = jnp.int32
+    table = jax.ShapeDtypeStruct((vb + 1,), i32)
+    edges = jax.ShapeDtypeStruct((eb,), i32)
+    if program == "driver_scan":
+        fn = _build_snapshot_scan(vb, ("degrees", "cc", "bipartite"))
+        stack = jax.ShapeDtypeStruct((w, eb), i32)
+        lowered = fn.lower(
+            (table, table, jax.ShapeDtypeStruct((2 * vb + 1,), i32)),
+            stack, stack, jax.ShapeDtypeStruct((w, eb), jnp.bool_))
+        assert _loop_ops_past(lowered, vb) == (2, [])
+        # compiled, the fold loop keeps the table in place: the only
+        # copies in its body are of the touched slots' labels
+        compiled = jax.jit(unionfind.cc_fold_rooted).lower(
+            table, edges, edges).compile().as_text()
+        body = re.search(r"body=%?([\w.\-]+)", compiled).group(1)
+        block = compiled.split("\n%" + body + " ", 1)[1].split("\n}", 1)[0]
+        assert re.search(r"s32\[%d\]\S* copy\(" % (vb + 1), block) is None
+    else:
+        fn = jax.jit(lambda l, s, d: unionfind.cc_fixpoint(l, s, d))
+        loops, bad = _loop_ops_past(fn.lower(table, edges, edges), vb)
+        assert loops == 1 and bad
