@@ -88,47 +88,25 @@ def _frozen_delta(idx: np.ndarray, vals: np.ndarray) -> tuple:
     return (idx, vals)
 
 
-def _build_snapshot_scan(vb: int, analytics: tuple,
-                         deltas: bool = False, egress: str = "full",
-                         cap: int = 0, donate: bool = False):
-    """One jitted lax.scan over a [W, eb] window stack, carrying
-    (degrees, cc labels, double-cover labels) and emitting PER-WINDOW
-    snapshots — the driver's batched single-chip fast path (sharded
-    meshes use parallel.sharded.make_sharded_snapshot_scan): one
-    dispatch + one d2h per run_arrays call instead of one per analytic
-    per window (per-dispatch latency dominates per-window
-    economics). Cover layout matches the driver's
-    host state: (+) = v, (−) = vb + v, sentinel slot 2vb.
+def snapshot_fold_body(vb: int, analytics: tuple, deltas: bool = False,
+                       egress: str = "full", cap: int = 0):
+    """The per-window body of the batched snapshot scan, shared by the
+    single-chip scan (_build_snapshot_scan) and the mesh scan
+    (parallel/sharded.make_sharded_snapshot_scan): body(carry, xs)
+    folds one window (src, dst, valid) into the carried (degrees, cc
+    labels, double-cover labels) and emits that window's snapshots.
+    Cover layout: (+) = v, (−) = vb + v. Padding lanes go to each
+    table's LAST slot, read from the carry's shapes: the single chip
+    carries [vb+1] / [2vb+1] (sentinels vb, 2vb), the mesh engine
+    [vb+2] / [2vb+2] (sentinels vb+1, 2vb+1), so neither layout is
+    converted on the hot path.
 
     Each window folds into the CC and cover labels through their roots
     (ops/unionfind.cc_fold_rooted): every carry that enters the scan is
     flat and min-rooted, so a fixpoint round costs the window's edges,
     not the table. Both folds also emit their round counts,
     `cc_rounds` and `cover_rounds` ([W] int32), in every egress and
-    donate variant: the read-back's fixpoint counters (finalize).
-
-    With `deltas`, each analytic also emits a per-window changed-slot
-    bool mask over [:vb] (new state vs the scan carry — computed
-    on-device, so a consumer of the reference's improving streams
-    (SimpleEdgeStream.java:473-481) can reconstruct per-update records
-    from snapshot + mask without diffing full vectors on host).
-
-    With egress="delta" (ops/delta_egress), the per-window output is
-    the COMPACT changed-slot wire instead of full vectors: per analytic
-    an int32 count plus [cap]-sized (indices, new values) rows —
-    2-3 orders of magnitude fewer d2h bytes on settled streams; the
-    driver reconstructs full snapshots from its host mirrors, and a
-    count exceeding `cap` routes the chunk to the bit-exact host fold.
-    The full masks are then NOT emitted (the wire subsumes them).
-
-    With `donate` (the RESIDENT tier, ops/resident_engine), the carry
-    argument is donated where the backend honors donation — the
-    ResidentState slabs update in place across super-batches instead
-    of being re-allocated per dispatch — and under delta egress the
-    final cover row is emitted as an explicit FRESH output
-    (`cover_final`): the next super-batch donates the carry buffers,
-    so the drain must never alias them."""
-    import jax
+    donate variant: the read-back's fixpoint counters (finalize)."""
     import jax.numpy as jnp
 
     from ..ops import delta_egress
@@ -142,11 +120,12 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
     def body(carry, xs):
         deg, labels, cover = carry
         src, dst, valid = xs
-        s = jnp.where(valid, src, vb)
-        d = jnp.where(valid, dst, vb)
+        sent = labels.shape[0] - 1
+        s = jnp.where(valid, src, sent)
+        d = jnp.where(valid, dst, sent)
         outs = {}
         if want_deg:
-            new_deg = deg.at[s].add(1).at[d].add(1)  # slot vb: pads
+            new_deg = deg.at[s].add(1).at[d].add(1)  # last slot: pads
             chg = (new_deg[:vb] != deg[:vb]) \
                 if (deltas or delta_out) else None
             if delta_out:
@@ -173,7 +152,7 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
                 outs["labels"] = new_labels
             labels = new_labels
         if want_bip:
-            sent2 = 2 * vb
+            sent2 = cover.shape[0] - 1
             s2 = jnp.concatenate([
                 jnp.where(valid, s, sent2),
                 jnp.where(valid, s + vb, sent2)])
@@ -197,6 +176,51 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
                 outs["cover"] = new_cover
             cover = new_cover
         return (deg, labels, cover), outs
+
+    return body
+
+
+def _build_snapshot_scan(vb: int, analytics: tuple,
+                         deltas: bool = False, egress: str = "full",
+                         cap: int = 0, donate: bool = False):
+    """One jitted lax.scan of snapshot_fold_body over a [W, eb] window
+    stack, carrying (degrees, cc labels, double-cover labels) and
+    emitting PER-WINDOW snapshots — the driver's batched single-chip
+    fast path (sharded meshes use
+    parallel.sharded.make_sharded_snapshot_scan, the same body): one
+    dispatch + one d2h per run_arrays call instead of one per analytic
+    per window (per-dispatch latency dominates per-window
+    economics). Cover layout matches the driver's
+    host state: (+) = v, (−) = vb + v, sentinel slot 2vb.
+
+    With `deltas`, each analytic also emits a per-window changed-slot
+    bool mask over [:vb] (new state vs the scan carry — computed
+    on-device, so a consumer of the reference's improving streams
+    (SimpleEdgeStream.java:473-481) can reconstruct per-update records
+    from snapshot + mask without diffing full vectors on host).
+
+    With egress="delta" (ops/delta_egress), the per-window output is
+    the COMPACT changed-slot wire instead of full vectors: per analytic
+    an int32 count plus [cap]-sized (indices, new values) rows —
+    2-3 orders of magnitude fewer d2h bytes on settled streams; the
+    driver reconstructs full snapshots from its host mirrors, and a
+    count exceeding `cap` routes the chunk to the bit-exact host fold.
+    The full masks are then NOT emitted (the wire subsumes them).
+
+    With `donate` (the RESIDENT tier, ops/resident_engine), the carry
+    argument is donated where the backend honors donation — the
+    ResidentState slabs update in place across super-batches instead
+    of being re-allocated per dispatch — and under delta egress the
+    final cover row is emitted as an explicit FRESH output
+    (`cover_final`): the next super-batch donates the carry buffers,
+    so the drain must never alias them."""
+    import jax
+    import jax.numpy as jnp
+
+    body = snapshot_fold_body(vb, analytics, deltas=deltas, egress=egress,
+                              cap=cap)
+    delta_out = egress == "delta"
+    want_bip = "bipartite" in analytics
 
     if donate:
         from ..ops import resident_engine

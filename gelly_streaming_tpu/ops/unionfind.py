@@ -12,14 +12,17 @@ and for `Candidates`' O(C²·V) merge (example/util/Candidates.java:76-138):
 - `cc_fixpoint(carried=True)`: fold a batch into a carried forest of
   any shape — the forest's parent links ride along as edges in every
   round, so a round sweeps every slot.
-- `cc_fold_rooted`: the driver's single-chip snapshot scan's fold.
-  Its carry is always flat and min-rooted (a converged fixpoint's
+- `cc_fold_rooted`: the fold of the driver's snapshot scan, on one
+  chip and on a mesh (which gathers each chunk's edges and runs the
+  same body on every chip, core/driver.snapshot_fold_body). Its
+  carry is always flat and min-rooted (a converged fixpoint's
   labeling), so each edge can be contracted to its endpoints' roots
   once; the loop is then a fresh labeling of the contracted graph,
   sized by the window's edges, and one gather after it relabels the
-  table. Same labels, bit for bit. The sharded scan (its per-round
-  pmin over shards), the cohort scan, the Pallas window kernel and
-  the per-window host wrappers keep `cc_fixpoint(carried=True)`.
+  table. Same labels, bit for bit. The sharded summary scan and the
+  per-window sharded kernels (a pmin over shards each round), the
+  cohort scan, the Pallas window kernel and the per-window host
+  wrappers keep `cc_fixpoint(carried=True)`.
 - `bipartite_labels`: 2-coloring via the bipartite double cover — the
   graph is bipartite iff (v,+) and (v,−) never share a component —
   which reduces bipartiteness to the same cc kernel (idiomatic

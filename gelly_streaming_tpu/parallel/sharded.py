@@ -362,67 +362,16 @@ def make_sharded_pane_reduce(mesh, vertex_bucket: int, pane_bucket: int,
 # full sharded window triangle pipeline (P1 + P6: all_to_all + pmax + psum)
 # ----------------------------------------------------------------------
 
-_TABLE_MODE = None  # resolved once per process (reset: _reset_table_mode)
-
-
-def _reset_table_mode() -> None:
-    """Test hook: forget the memoized table-mode selection so a test
-    can re-resolve against a different committed PERF.json."""
-    global _TABLE_MODE
-    _TABLE_MODE = None
-
-
-def resolve_table_mode() -> str:
+def resolve_table_mode(n: int, vb: int, kb: int, cap: int) -> str:
     """Neighbor-row distribution mode for the sharded window counter
-    ("replicated" pmax table vs "owner" row gather), selected from
-    committed backend-matched measurements (PERF.json `sharded_table`
-    section, tools/profile_kernels.py) — the same measured-default
-    policy as the kernel selections in ops/triangles.py. Until a
-    committed measurement shows the owner gather ≥5% faster, the
-    proven replicated table stands. The mode only matters on n>1
-    meshes (the virtual CPU mesh here; real ICI when multi-chip
-    hardware exists — window_collective_bytes models that side).
-    Memoized per process like the other measurement-driven selections
-    (the driver rebuilds kernels on reconfiguration; re-reading
-    PERF.json each time is needless I/O — ADVICE r3)."""
-    global _TABLE_MODE
-    if _TABLE_MODE is not None:
-        return _TABLE_MODE
-    _TABLE_MODE = _resolve_table_mode_uncached()
-    return _TABLE_MODE
-
-
-def _resolve_table_mode_uncached() -> str:
-    perf = triangles._load_matching_perf()
-    if perf is not None:
-        row = perf.get("sharded_table", {})
-        # the section records ITS OWN backend next to the file-level
-        # one ("cpu-virtual-mesh" rows ride along inside a chip-labeled
-        # PERF.json): require it to match the LIVE backend, so virtual-
-        # mesh rows can never drive a TPU process's replicated-vs-owner
-        # selection (ADVICE r5 medium finding). The virtual mesh IS the
-        # cpu backend, so "cpu-virtual-mesh" matches a cpu process.
-        try:
-            import jax as _jax
-
-            live = _jax.default_backend()
-        except Exception as e:
-            telemetry.event("selection.fallback", durable=True,
-                            component="sharded_table",
-                            fallback="replicated",
-                            error="%s: %s" % (type(e).__name__, e))
-            return "replicated"
-        row_backend = row.get("backend")
-        if row_backend not in (live, "%s-virtual-mesh" % live):
-            return "replicated"
-        owner = row.get("owner_edges_per_s") or 0
-        repl = row.get("replicated_edges_per_s") or 0
-        # parity gate first, same as the dense selection: a fast mode
-        # whose own committed evidence says it miscounted never wins
-        if (row.get("counts_match") is True
-                and owner and repl and owner >= 1.05 * repl):
-            return "owner"
-    return "replicated"
+    at its shapes: the mode whose per-window collectives move fewer
+    bytes (window_collective_bytes), "replicated" on a tie (one
+    shard moves nothing either way). The replicated pmax table grows
+    with vb·kb whatever the window; the owner gather with the owned
+    edges, so it wins once the table outgrows the window's rows."""
+    repl = window_collective_bytes(n, vb, kb, cap, "replicated")["total"]
+    owner = window_collective_bytes(n, vb, kb, cap, "owner")["total"]
+    return "owner" if owner < repl else "replicated"
 
 
 def window_collective_bytes(n: int, vb: int, kb: int, cap: int,
@@ -644,7 +593,6 @@ class ShardedTriangleWindowKernel:
                  table: str = None):
         self.mesh = mesh
         self.n = n = shard_count(mesh)
-        self.table = table if table else resolve_table_mode()
 
         def _mult_of_n(x: int) -> int:  # shard_map splits the leading
             return -(-x // n) * n       # dim; K splits into n slices
@@ -660,6 +608,8 @@ class ShardedTriangleWindowKernel:
             self.kb)
         self.cap = min(max(8, cap_factor * (self.eb // n) // n),
                        self.eb // n)
+        self.table = table if table else resolve_table_mode(
+            n, self.vb, self.kb, self.cap)
         # per-stage counters of the shared ingress pipeline (same
         # contract as TriangleWindowKernel.stage_timers)
         self.stage_timers = ingress_pipeline.StageTimers()
@@ -1169,86 +1119,38 @@ def make_sharded_summary_scan(mesh, eb: int, vb: int, kb: int, cap: int,
 def make_sharded_snapshot_scan(mesh, vb: int, analytics: tuple,
                                deltas: bool = False):
     """Sharded form of the driver's batched snapshot scan
-    (core/driver._build_snapshot_scan): lax.scan over [W, eb] window
-    stacks with the edge axis sharded over the mesh, carrying the
-    ShardedWindowEngine's state layouts — degrees [vb+2] (sentinel
-    vb+1), cc labels [vb+2], double cover [2vb+2] ((+) = v,
-    (−) = vb + v, sentinels 2vb/2vb+1) — and emitting per-window
-    replicated snapshots. Merges ride psum (degrees) and pmin (labels)
-    over ICI inside the scan, so a whole chunk of windows costs one
-    multi-chip dispatch."""
-    want_deg = "degrees" in analytics
-    want_cc = "cc" in analytics
-    want_bip = "bipartite" in analytics
-    pmin_ex = functools.partial(jax.lax.pmin, axis_name=SHARD_AXIS)
+    (core/driver._build_snapshot_scan) over the ShardedWindowEngine's
+    state layouts — degrees and cc labels [vb+2] (sentinel vb+1),
+    double cover [2vb+2] ((+) = v, (−) = vb + v, sentinel 2vb+1) —
+    emitting per-window replicated snapshots, a whole chunk of windows
+    in one multi-chip dispatch.
 
-    def body(carry, xs):
-        deg, labels, cover = carry
-        src, dst, valid = xs          # local shard slice [eb / n]
-        sent = vb + 1
-        s = jnp.where(valid, src, sent)
-        d = jnp.where(valid, dst, sent)
-        outs = {}
-        if want_deg:
-            ones = jnp.where(valid, 1, 0)
-            local = (jax.ops.segment_sum(ones, s, vb + 2)
-                     + jax.ops.segment_sum(ones, d, vb + 2))
-            new_deg = deg + jax.lax.psum(local, SHARD_AXIS)
-            if deltas:
-                outs["deg_chg"] = new_deg[:vb] != deg[:vb]
-            deg = new_deg
-            outs["deg"] = deg
-        if want_cc:
-            new_labels = unionfind.cc_fixpoint(labels, s, d,
-                                               exchange=pmin_ex)
-            if deltas:
-                outs["labels_chg"] = new_labels[:vb] != labels[:vb]
-            labels = new_labels
-            outs["labels"] = labels
-        if want_bip:
-            sent2 = 2 * vb + 1
-            s2 = jnp.concatenate([
-                jnp.where(valid, src, sent2),
-                jnp.where(valid, src + vb, sent2)])
-            d2 = jnp.concatenate([
-                jnp.where(valid, dst + vb, sent2),
-                jnp.where(valid, dst, sent2)])
-            new_cover = unionfind.cc_fixpoint(cover, s2, d2,
-                                              exchange=pmin_ex)
-            if deltas:
-                # mask tracks the consumer-visible odd flag (the
-                # same decode _run_batched applies), not raw labels
-                outs["cover_chg"] = (
-                    (new_cover[:vb] == new_cover[vb:2 * vb])
-                    != (cover[:vb] == cover[vb:2 * vb]))
-            cover = new_cover
-            outs["cover"] = cover
-        return (deg, labels, cover), outs
+    The [W, eb] stacks arrive edge-sharded (P1); one all_gather per
+    chunk hands every chip the chunk's whole windows, and each chip
+    then runs the single-chip fold (core/driver.snapshot_fold_body:
+    degrees by scatter-add, CC and the cover by
+    ops/unionfind.cc_fold_rooted) on the replicated state (P3). The
+    same program on the same inputs keeps the outputs replicated, so
+    the fold loop holds no collective and nothing table-sized, and the
+    scan emits the same `cc_rounds` / `cover_rounds` as one chip."""
+    from ..core.driver import snapshot_fold_body
 
-    out_tree = {}
-    if want_deg:
-        out_tree["deg"] = P()
-        if deltas:
-            out_tree["deg_chg"] = P()
-    if want_cc:
-        out_tree["labels"] = P()
-        if deltas:
-            out_tree["labels_chg"] = P()
-    if want_bip:
-        out_tree["cover"] = P()
-        if deltas:
-            out_tree["cover_chg"] = P()
+    body = snapshot_fold_body(vb, analytics, deltas=deltas)
 
-    # shard_map_norep: same while_loop (cc_fixpoint) shape as the
-    # summary scan above; psum/pmin make the outputs replicated
+    def whole(x):   # [W, eb/n] shard slice -> [W, eb] on every chip
+        return jax.lax.all_gather(x, SHARD_AXIS, axis=1, tiled=True)
+
+    # shard_map_norep: the fold's while_loop has no replication rule;
+    # every chip folds the same gathered windows into the same carry
     @shard_map_norep(
         mesh, in_specs=((P(), P(), P()),
                         P(None, SHARD_AXIS), P(None, SHARD_AXIS),
                         P(None, SHARD_AXIS)),
-        out_specs=((P(), P(), P()), out_tree),
+        out_specs=((P(), P(), P()), P()),
     )
     def run(carry, s_w, d_w, valid_w):
-        return jax.lax.scan(body, carry, (s_w, d_w, valid_w))
+        return jax.lax.scan(body, carry,
+                            (whole(s_w), whole(d_w), whole(valid_w)))
 
     return jax.jit(run)
 

@@ -457,68 +457,31 @@ def test_window_collective_bytes_accounting():
     assert abs(t["total"] - r["total"] / 45e9) < 1e-12
 
 
-def test_resolve_table_mode_flips_on_committed_measurement(
-        tmp_path, monkeypatch):
-    """The mode selection follows the same committed-measurement policy
-    as the kernel choices: owner wins only with a >=5% backend-matched
-    row; absent/losing/mismatched rows keep the replicated default.
-    The selection is memoized per process, so each re-resolve goes
-    through the test reset hook."""
-    import json
-
+@pytest.mark.parametrize("n,eb,vb,kb,want", [
+    (4, 32768, 1 << 20, 128, "owner"),    # the four-chip twitter cell
+    (8, 65536, 262144, 64, "owner"),
+    (8, 1024, 64, 16, "replicated"),      # a table under the rows
+    (1, 32768, 1 << 20, 128, "replicated"),  # one shard moves nothing
+])
+def test_resolve_table_mode_picks_by_modelled_bytes(monkeypatch, n, eb, vb,
+                                                    kb, want):
+    """The kernel takes the table mode whose per-window collectives
+    move fewer bytes at its own shapes, replicated on a tie, and reads
+    no committed measurement to decide."""
     from gelly_streaming_tpu.parallel import sharded
 
-    perf_path = tmp_path / "PERF.json"
-    monkeypatch.setattr(tri_ops, "_PERF_PATH", str(perf_path))
-    backend = jax.default_backend()
+    def no_perf(*_a, **_k):
+        raise AssertionError("the table mode read PERF.json")
 
-    def write(file_backend, owner, repl, counts_match=True,
-              row_backend=None):
-        perf_path.write_text(json.dumps({
-            "backend": file_backend,
-            "sharded_table": {"backend": row_backend or file_backend,
-                              "owner_edges_per_s": owner,
-                              "replicated_edges_per_s": repl,
-                              "counts_match": counts_match}}))
-
-    write(backend, owner=2000, repl=1000)
-    sharded._reset_table_mode()
-    assert sharded.resolve_table_mode() == "owner"
-    write(backend, owner=1020, repl=1000)   # under the 5% bar
-    sharded._reset_table_mode()
-    assert sharded.resolve_table_mode() == "replicated"
-    write(backend, owner=0, repl=1000)      # missing measurement
-    sharded._reset_table_mode()
-    assert sharded.resolve_table_mode() == "replicated"
-    write("not-" + backend, owner=2000, repl=1000)  # backend mismatch
-    sharded._reset_table_mode()
-    assert sharded.resolve_table_mode() == "replicated"
-    # a fast mode whose own evidence says it miscounted never wins
-    write(backend, owner=2000, repl=1000, counts_match=False)
-    sharded._reset_table_mode()
-    assert sharded.resolve_table_mode() == "replicated"
-    # the section's OWN backend label must match the LIVE backend:
-    # virtual-mesh rows riding inside a chip-labeled PERF.json can
-    # never drive a TPU process's selection (ADVICE r5); the virtual
-    # mesh IS the cpu backend, so "<live>-virtual-mesh" still matches
-    write(backend, owner=2000, repl=1000,
-          row_backend="some-other-backend")
-    sharded._reset_table_mode()
-    assert sharded.resolve_table_mode() == "replicated"
-    write(backend, owner=2000, repl=1000,
-          row_backend="%s-virtual-mesh" % backend)
-    sharded._reset_table_mode()
-    assert sharded.resolve_table_mode() == "owner"
-    # a row with NO backend label is treated as unmatched evidence
-    perf_path.write_text(json.dumps({
-        "backend": backend,
-        "sharded_table": {"owner_edges_per_s": 2000,
-                          "replicated_edges_per_s": 1000,
-                          "counts_match": True}}))
-    sharded._reset_table_mode()
-    assert sharded.resolve_table_mode() == "replicated"
-    # don't leak a resolution made against the fake PERF.json
-    sharded._reset_table_mode()
+    monkeypatch.setattr(tri_ops, "_load_matching_perf", no_perf)
+    k = ShardedTriangleWindowKernel(make_mesh(n), edge_bucket=eb,
+                                    vertex_bucket=vb, k_bucket=kb)
+    moved = {m: sharded.window_collective_bytes(n, k.vb, k.kb, k.cap,
+                                                m)["total"]
+             for m in ("replicated", "owner")}
+    assert k.table == want
+    assert sharded.resolve_table_mode(n, k.vb, k.kb, k.cap) == want
+    assert moved[want] == min(moved.values())
 
 
 def test_sharded_assoc_pane_reduce_matches_numpy_fold():

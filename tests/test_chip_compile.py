@@ -120,24 +120,31 @@ def test_dense_triangle_pallas_compiles(one_chip, v):
 
 
 def test_sharded_snapshot_scan_compiles_on_2x2(topo):
-    """The driver's mesh path at the smoke's width: vb=65536,
-    16 windows of 32768 edges sharded over four chips."""
+    """The driver's mesh path at the four-chip cell's width: vb=2^20,
+    16 windows of 32768 edges sharded over four chips, gathered once
+    per chunk (the scan's only collective), with each window's round
+    counts."""
     from gelly_streaming_tpu.parallel.sharded import (
         make_sharded_snapshot_scan)
 
     mesh = Mesh(np.array(topo.devices[:4]), ("shard",))
     rep = NamedSharding(mesh, P())
     edges = NamedSharding(mesh, P(None, "shard"))
-    vb, eb, w = 65536, 32768, 16
+    vb, eb, w = 1 << 20, 32768, 16
     fn = make_sharded_snapshot_scan(
         mesh, vb, ("degrees", "cc", "bipartite", "triangles"))
     carry = (_sds((vb + 2,), jnp.int32, rep),
              _sds((vb + 2,), jnp.int32, rep),
              _sds((2 * vb + 2,), jnp.int32, rep))
-    compiled = fn.lower(carry, _sds((w, eb), jnp.int32, edges),
-                        _sds((w, eb), jnp.int32, edges),
-                        _sds((w, eb), jnp.bool_, edges)).compile()
-    assert "all-reduce" in compiled.as_text()
+    args = (carry, _sds((w, eb), jnp.int32, edges),
+            _sds((w, eb), jnp.int32, edges),
+            _sds((w, eb), jnp.bool_, edges))
+    text = fn.lower(*args).compile().as_text()
+    assert "all-gather" in text
+    assert "all-reduce" not in text and "all-to-all" not in text
+    outs = jax.eval_shape(fn, *args)[1]
+    for key in ("cc_rounds", "cover_rounds"):
+        assert (outs[key].shape, outs[key].dtype) == ((w,), jnp.int32)
 
 
 def _build_window(one_chip):

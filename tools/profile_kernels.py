@@ -828,11 +828,10 @@ for name, eng in (
                  "per_window_ms": round(t / num_w * 1e3, 3),
                  "edges_per_s": round(num_w * eb / t)}
 
-# owner vs replicated neighbor-row distribution (drives
-# resolve_table_mode): wall-clock at a small-table shape AND the
-# 10M-scale bucket shape (the VERDICT-flagged risk case), plus the
-# analytic ICI accounting. The top-level *_edges_per_s keys carry the
-# LARGE config — the decisive row for the selection.
+# owner vs replicated neighbor-row distribution (resolve_table_mode
+# picks by the modelled bytes below): wall-clock at a small-table
+# shape AND the 10M-scale bucket shape, plus the analytic ICI
+# accounting. The top-level *_edges_per_s keys carry the LARGE config.
 from gelly_streaming_tpu.parallel.sharded import (ici_time_model,
                                                   window_collective_bytes)
 
@@ -2102,8 +2101,7 @@ def main():
         results["sharded"] = section_sharded(REPO)
         if "error" not in results["sharded"]:
             ok_sections.append("sharded")
-            # hoist the table-mode comparison to the top level, where
-            # parallel/sharded.resolve_table_mode reads it
+            # hoist the table-mode comparison to the top level
             if "sharded_table" in results["sharded"]:
                 results["sharded_table"] = results["sharded"].pop(
                     "sharded_table")
