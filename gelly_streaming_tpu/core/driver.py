@@ -104,9 +104,14 @@ def snapshot_fold_body(vb: int, analytics: tuple, deltas: bool = False,
     Each window folds into the CC and cover labels through their roots
     (ops/unionfind.cc_fold_rooted): every carry that enters the scan is
     flat and min-rooted, so a fixpoint round costs the window's edges,
-    not the table. Both folds also emit their round counts,
-    `cc_rounds` and `cover_rounds` ([W] int32), in every egress and
-    donate variant: the read-back's fixpoint counters (finalize)."""
+    not the table, and relabels the table through the roots that moved;
+    with degrees in the analytics, the carried degrees before the
+    window's scatter-add tell which of those roots have members. Both
+    folds also emit their round counts, `cc_rounds` and
+    `cover_rounds`, and their moved roots with members,
+    `cc_relabel_roots` and `cover_relabel_roots` ([W] int32 each), in
+    every egress and donate variant: the read-back's fold counters
+    (finalize)."""
     import jax.numpy as jnp
 
     from ..ops import delta_egress
@@ -124,6 +129,9 @@ def snapshot_fold_body(vb: int, analytics: tuple, deltas: bool = False,
         s = jnp.where(valid, src, sent)
         d = jnp.where(valid, dst, sent)
         outs = {}
+        # the folds' witness of a root with no other member: an
+        # endpoint of degree 0 before this window had no edge
+        seen = (deg[s] > 0, deg[d] > 0) if want_deg else None
         if want_deg:
             new_deg = deg.at[s].add(1).at[d].add(1)  # last slot: pads
             chg = (new_deg[:vb] != deg[:vb]) \
@@ -138,8 +146,9 @@ def snapshot_fold_body(vb: int, analytics: tuple, deltas: bool = False,
                 outs["deg"] = new_deg
             deg = new_deg
         if want_cc:
-            new_labels, outs["cc_rounds"] = uf.cc_fold_rooted(
-                labels, s, d)
+            (new_labels, outs["cc_rounds"],
+             outs["cc_relabel_roots"]) = uf.cc_fold_rooted(
+                labels, s, d, seen)
             chg = (new_labels[:vb] != labels[:vb]) \
                 if (deltas or delta_out) else None
             if delta_out:
@@ -159,8 +168,13 @@ def snapshot_fold_body(vb: int, analytics: tuple, deltas: bool = False,
             d2 = jnp.concatenate([
                 jnp.where(valid, d + vb, sent2),
                 jnp.where(valid, d, sent2)])
-            new_cover, outs["cover_rounds"] = uf.cc_fold_rooted(
-                cover, s2, d2)
+            # both halves of the cover's edges join the window's own
+            # endpoints, so each half takes their witness
+            seen2 = None if seen is None else tuple(
+                jnp.concatenate([w, w]) for w in seen)
+            (new_cover, outs["cover_rounds"],
+             outs["cover_relabel_roots"]) = uf.cc_fold_rooted(
+                cover, s2, d2, seen2)
             if deltas or delta_out:
                 # the consumer-visible value is the odd flag, so the
                 # mask (and the delta wire) tracks IT, not raw labels
@@ -247,9 +261,12 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
 
 def _readback_counters(outs: dict, windows: int) -> None:
     """The read-back's counters, from a chunk's materialized scan
-    outputs (no further sync): bytes brought back, and the rounds the
-    CC and double-cover fixpoints took over the chunk's `windows` real
-    windows (rows past them are the W-bucket's sentinel windows)."""
+    outputs (no further sync): bytes brought back, and over the
+    chunk's `windows` real windows (rows past them are the W-bucket's
+    sentinel windows) the rounds the CC and double-cover folds took,
+    the moved roots with members they relabelled (`relabel_roots`),
+    and how many of the folds had more than the relabel's list holds
+    and gathered instead (`relabel_gathers`)."""
     telemetry.counter("driver.readback_bytes",
                       sum(v.nbytes for v in outs.values()),
                       windows=windows)
@@ -258,6 +275,17 @@ def _readback_counters(outs: dict, windows: int) -> None:
             telemetry.counter("driver." + key,
                               int(outs[key][:windows].sum()),
                               windows=windows)
+    moved = [outs[key][:windows] for key in ("cc_relabel_roots",
+                                             "cover_relabel_roots")
+             if key in outs]
+    if moved:
+        telemetry.counter("driver.relabel_roots",
+                          int(sum(m.sum() for m in moved)),
+                          windows=windows)
+        telemetry.counter("driver.relabel_gathers",
+                          int(sum((m > unionfind.RELABEL_K_MAX).sum()
+                                  for m in moved)),
+                          windows=windows)
 
 
 _SNAPSHOT_TIER = None  # resolved once per process (reset below)

@@ -18,11 +18,12 @@ and for `Candidates`' O(C²·V) merge (example/util/Candidates.java:76-138):
   carry is always flat and min-rooted (a converged fixpoint's
   labeling), so each edge can be contracted to its endpoints' roots
   once; the loop is then a fresh labeling of the contracted graph,
-  sized by the window's edges, and one gather after it relabels the
-  table. Same labels, bit for bit. The sharded summary scan and the
-  per-window sharded kernels (a pmin over shards each round), the
-  cohort scan, the Pallas window kernel and the per-window host
-  wrappers keep `cc_fixpoint(carried=True)`.
+  sized by the window's edges, and the table is relabelled through
+  the roots that moved, in dense passes over it (a gather only past
+  RELABEL_K_MAX of them). Same labels, bit for bit. The sharded
+  summary scan and the per-window sharded kernels (a pmin over shards
+  each round), the cohort scan, the Pallas window kernel and the
+  per-window host wrappers keep `cc_fixpoint(carried=True)`.
 - `bipartite_labels`: 2-coloring via the bipartite double cover — the
   graph is bipartite iff (v,+) and (v,−) never share a component —
   which reduces bipartiteness to the same cc kernel (idiomatic
@@ -104,12 +105,28 @@ def cc_fixpoint(labels0: jax.Array, src: jax.Array, dst: jax.Array,
     return (labels, n) if rounds else labels
 
 
-def cc_fold_rooted(labels0: jax.Array, src: jax.Array, dst: jax.Array):
+# The relabel after the fold hands each moved root's new label to its
+# members in compare-and-select passes over the table, RELABEL_BLOCK
+# roots a pass, for up to RELABEL_K_MAX roots; past that it gathers
+# `lab[labels0]`. On a TPU v5e (`tools/relabel_probe.py`, 2^20 vertex
+# slots) the gather costs 7.06 ms over CC's 2^20 + 1 slots and 14.06 ms
+# over the cover's 2^21 + 1; the relabel costs 0.12-0.13 ms with no
+# root, 0.38-0.39 ms with one pass and 1.18-1.20 ms with four, ~0.27 ms
+# a pass. Passes match the CC gather at ~29 (~3,700 roots), so the
+# list's size is set by the traffic: 512 (four passes, at most 17 % of
+# the gather) holds every window of the benchmark's streams once their
+# first calls are folded; a longer list would only speed those up.
+RELABEL_BLOCK = 128
+RELABEL_K_MAX = 512
+
+
+def cc_fold_rooted(labels0: jax.Array, src: jax.Array, dst: jax.Array,
+                   seen=None):
     """Fold a window of edges into a FLAT, MIN-ROOTED carried forest
     (`labels0[labels0] == labels0`, every slot labelled by its
     component's least slot — what a converged fixpoint leaves) through
-    its roots. Returns (labels, rounds), labels bit-identical to
-    `cc_fixpoint(labels0, src, dst, carried=True, rounds=True)`'s.
+    its roots. Returns (labels, rounds, relabels), labels bit-identical
+    to `cc_fixpoint(labels0, src, dst, carried=True, rounds=True)`'s.
 
     Each edge is contracted once to its endpoints' roots
     (labels0[src], labels0[dst]); a root starts at its own id, so the
@@ -117,11 +134,19 @@ def cc_fold_rooted(labels0: jax.Array, src: jax.Array, dst: jax.Array):
     forest links (the island split cannot arise: no contracted edge
     touches a non-root). Hooking, pointer jumping and the change test
     run over the touched roots only, so every operation inside the
-    loop is sized by the window's edges, not by the table; the one
-    whole-table step is the flatten `lab[labels0]` after it, which
-    hands each root's new label to its members. The least slot of a
-    union of components is the least of their roots, so the result is
-    again flat and min-rooted."""
+    loop is sized by the window's edges, not by the table. The least
+    slot of a union of components is the least of their roots, so the
+    result is again flat and min-rooted.
+
+    After the loop `lab` differs from `labels0` only at the touched
+    roots that moved, so only their members need a new label
+    (`_relabel_moved`). `seen` = (seen_src, seen_dst), bool like src,
+    marks the endpoints that had an edge before this window (degree
+    > 0): a root whose component is itself alone is then left out,
+    since it has no member to relabel. Without it every moved root
+    counts. `relabels` is the number of moved roots with members
+    (int32); past RELABEL_K_MAX it counts them with repeats, and the
+    table was relabelled by the gather."""
     labels0 = labels0.astype(jnp.int32)
     rs = labels0[src]
     rd = labels0[dst]
@@ -142,9 +167,65 @@ def cc_fold_rooted(labels0: jax.Array, src: jax.Array, dst: jax.Array):
         new = new.at[touched].set(nxt)
         return new, nxt, jnp.any(nxt != cur), n + 1
 
-    lab, _, _, n = jax.lax.while_loop(
+    lab, cur, _, n = jax.lax.while_loop(
         cond, body, (labels0, touched, jnp.array(True), jnp.int32(0)))
-    return lab[labels0], n
+    keep = cur != touched
+    if seen is not None:
+        # an endpoint's component has other slots only if the endpoint
+        # had an edge (a self-loop alone counts too: still exact, one
+        # root more to relabel)
+        keep &= jnp.concatenate(seen)
+    lab, k = _relabel_moved(labels0, lab, touched, cur, keep)
+    return lab, n, k
+
+
+def _relabel_moved(labels0, lab, roots, new, keep):
+    """`lab[labels0]` for a folded `lab` that differs from the flat
+    `labels0` only at `roots[keep]`, whose new labels are `new[keep]`
+    (entries may repeat). The kept entries, in order, are packed into
+    a list of RELABEL_K_MAX by a prefix sum; slot i then takes the new
+    label of its old root if that root is listed, else keeps `lab[i]`,
+    as compare-and-select passes over the table, RELABEL_BLOCK list
+    entries a pass and only the passes the count needs. A longer list
+    falls back to the gather. Returns (labels, count): the distinct
+    roots listed, or the kept entries past RELABEL_K_MAX."""
+    k_max, block = RELABEL_K_MAX, RELABEL_BLOCK
+    raw = jnp.sum(keep, dtype=jnp.int32)
+    rank = jnp.arange(-(-k_max // block) * block, dtype=jnp.int32)
+    at = jnp.searchsorted(jnp.cumsum(keep, dtype=jnp.int32), rank + 1)
+    at = jnp.minimum(at, keep.shape[0] - 1)
+    listed = rank < raw
+    old = jnp.where(listed, roots[at], -1)
+    val = new[at]
+    again = jnp.any(jnp.tril(old[:, None] == old[None, :], -1), axis=1)
+    count = jnp.where(raw > k_max, raw,
+                      jnp.sum(listed & ~again, dtype=jnp.int32))
+
+    # the table as rows of 128 slots (padded with a label no root has),
+    # so that a pass reduces over the list's axis, across rows of
+    # slots, not across a row's lanes
+    n = labels0.shape[0]
+    rows = -(-n // 1024) * 8
+    grid = jnp.pad(labels0, (0, rows * 128 - n),
+                   constant_values=-2).reshape(rows, 128)
+
+    def one_pass(b, out):
+        # a member's lab is its old root, above the root's new label,
+        # and the root's own lab is that label: so the min selects
+        r = jax.lax.dynamic_slice(old, (b * block,), (block,))
+        v = jax.lax.dynamic_slice(val, (b * block,), (block,))
+        return jnp.minimum(out, jnp.min(
+            jnp.where(r[:, None, None] == grid[None], v[:, None, None],
+                      jnp.iinfo(jnp.int32).max), axis=0))
+
+    def passes(lab):
+        out = jax.lax.fori_loop(
+            0, (raw + block - 1) // block, one_pass,
+            jnp.pad(lab, (0, rows * 128 - n)).reshape(rows, 128))
+        return out.reshape(-1)[:n]
+
+    out = jax.lax.cond(raw > k_max, lambda lab: lab[labels0], passes, lab)
+    return out, count
 
 
 @functools.partial(jax.jit, static_argnames=("num_vertices",))

@@ -4,6 +4,7 @@ NonBipartitnessCheckTest.java), run through both the host and the
 device (TPU kernel) variants of each algorithm.
 """
 
+import itertools
 import re
 
 import pytest
@@ -179,36 +180,153 @@ def _fold_case(name, seed=0):
     return lab, wins
 
 
+def _stream_windows(scenario, rng):
+    """(vb, [(src, dst, valid)]) vertex-level windows of one relabel
+    scenario, as the driver's scan receives them."""
+    import numpy as np
+
+    vb, eb = 48, 16
+    if scenario == "no_move":              # the window folded again
+        s, d = rng.integers(0, vb, eb), rng.integers(0, vb, eb)
+        wins = [(s, d), (s, d)]
+    elif scenario == "singletons_only":    # every endpoint new
+        wins = [(np.arange(k, k + 16, 2), np.arange(k + 1, k + 16, 2))
+                for k in (0, 16)]
+    elif scenario == "untouched_members":
+        # {10..14} and {20..23}, then an edge of members joins them and
+        # a new vertex joins a member: 21 and 23 are not touched
+        wins = [([10, 11, 12, 13, 20, 21, 22], [11, 12, 13, 14, 21, 22, 23]),
+                ([22, 30], [12, 21])]
+    elif scenario == "giant_root_drops":
+        # a giant rooted at 5, then new vertex 0 joins one member
+        s, d = rng.integers(5, vb, 4 * eb), rng.integers(5, vb, 4 * eb)
+        wins = [(s, d), ([0], [40])]
+    elif scenario == "over_k_max":
+        # 2048 pairs, then a chain through them: every pair's root
+        # moves, more than the relabel's list holds
+        vb, eb = 4096, 2048
+        wins = [(np.arange(0, vb, 2), np.arange(1, vb, 2)),
+                (np.arange(1, vb - 2, 2), np.arange(2, vb - 1, 2))]
+    else:                                  # random, self_loops, no_degrees
+        wins = []
+        for _ in range(4):
+            s, d = rng.integers(0, vb, eb), rng.integers(0, vb, eb)
+            if scenario == "self_loops":
+                d = np.where(rng.random(eb) < 0.5, s, d)
+            wins.append((s, d))
+    out = []
+    eb = max(eb, *(len(s) for s, _d in wins))
+    for s, d in wins:
+        s, d = np.asarray(s), np.asarray(d)
+        valid = np.ones(eb, bool)
+        valid[len(s):] = False
+        pad = np.zeros(eb - len(s), int)
+        out.append((np.append(s, pad), np.append(d, pad), valid))
+    return vb, out
+
+
+def _relabel_case(scenario, table, layout, seed=0):
+    """(table0, [(src, dst, seen, deg_of_slot)]) in the driver's layout:
+    CC [vb+1] / cover [2vb+1] on one chip, [vb+2] / [2vb+2] on a mesh,
+    padding at the last slot; `seen` from the carried degrees before
+    each window (None for `no_degrees`), and each slot's vertex degree
+    at that point."""
+    import numpy as np
+
+    rng = np.random.default_rng(sum(map(ord, scenario)) + seed)
+    vb, wins = _stream_windows(scenario, rng)
+    extra = {"one_chip": 1, "mesh": 2}[layout]
+    deg = np.zeros(vb + extra, np.int64)
+    sent = vb + extra - 1
+    n = (vb if table == "cc" else 2 * vb) + extra
+    cases = []
+    for src, dst, valid in wins:
+        s, d = np.where(valid, src, sent), np.where(valid, dst, sent)
+        seen = (deg[s] > 0, deg[d] > 0)
+        slot = np.arange(n)
+        vertex = np.where(slot >= vb, slot - vb, slot) if table == "cover" \
+            else slot
+        deg_of = deg[np.minimum(vertex, len(deg) - 1)]
+        if table == "cover":
+            sent2 = n - 1
+            s, d = (np.concatenate([np.where(valid, s, sent2),
+                                    np.where(valid, s + vb, sent2)]),
+                    np.concatenate([np.where(valid, d + vb, sent2),
+                                    np.where(valid, d, sent2)]))
+            seen = tuple(np.concatenate([w, w]) for w in seen)
+        cases.append((s, d, None if scenario == "no_degrees" else seen,
+                      deg_of))
+        np.add.at(deg, np.where(valid, src, sent), 1)
+        np.add.at(deg, np.where(valid, dst, sent), 1)
+    return np.arange(n, dtype=np.int32), cases
+
+
+RELABEL_CASES = ["%s-%s-%s" % c for c in itertools.product(
+    ["no_move", "singletons_only", "untouched_members", "giant_root_drops",
+     "over_k_max", "self_loops", "no_degrees", "random"],
+    ["cc", "cover"], ["one_chip", "mesh"])]
+
+
 @pytest.mark.parametrize("case", [
     "random", "island_split", "all_padding", "self_loops",
-    "sentinel_edges", "double_cover"])
+    "sentinel_edges", "double_cover"] + RELABEL_CASES)
 def test_cc_fold_rooted_matches_carried_fixpoint(case):
     """Folding a window through the roots of a flat, min-rooted carry
-    gives the carried fixpoint's labels bit for bit, window after
-    window, in no more rounds; the carry stays flat and min-rooted."""
+    gives the carried fixpoint's labels bit for bit, and the old
+    whole-table flatten's `lab[labels0]`, window after window, in no
+    more rounds; the carry stays flat and min-rooted. The relabel
+    counts the moved roots with members (with the degree witness: the
+    moved roots of degree > 0), and past its list gathers instead."""
     import jax
     import numpy as np
 
     from gelly_streaming_tpu.ops import unionfind
+    from tests.test_driver import _np_fold_rooted_rounds
 
     old = jax.jit(lambda l, s, d: unionfind.cc_fixpoint(
         l, s, d, carried=True, rounds=True))
     new = jax.jit(unionfind.cc_fold_rooted)
+    k_max = unionfind.RELABEL_K_MAX
+    counts = []
     for seed in range(40 if case == "random" else 1):
-        lab, wins = _fold_case(case, seed)
+        if case in RELABEL_CASES:
+            lab, wins = _relabel_case(*case.split("-"), seed=seed)
+        else:
+            lab, wins = _fold_case(case, seed)
+            wins = [(s, d, None, None) for s, d in wins]
         slots = np.arange(len(lab))
-        for s, d in wins:
+        for s, d, seen, deg_of in wins:
             s, d = np.asarray(s, np.int32), np.asarray(d, np.int32)
             want, want_n = old(lab, s, d)
-            got, got_n = new(lab, s, d)
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+            got, got_n, got_k = new(lab, s, d, seen)
+            got = np.asarray(got)
+            np.testing.assert_array_equal(got, np.asarray(want))
+            np.testing.assert_array_equal(
+                got, _np_fold_rooted_rounds(lab, s, d)[0])
             assert 1 <= int(got_n) <= int(want_n)
-            lab = np.asarray(got)
+            touched = np.concatenate([lab[s], lab[d]])
+            moved = np.unique(touched[got[touched] != touched])
+            if seen is not None:
+                moved = moved[deg_of[moved] > 0]
+            if len(moved) > k_max or int(got_k) > k_max:
+                # the gather's count: the kept entries, repeats too
+                assert int(got_k) >= max(len(moved), k_max + 1)
+            else:
+                assert int(got_k) == len(moved)
+            counts.append(int(got_k))
+            lab = got
             assert np.array_equal(lab[lab], lab) and np.all(lab <= slots)
     if case == "island_split":
         assert list(lab[[0, 1, 3, 4, 5]]) == [0, 0, 0, 0, 0]
     if case == "all_padding":
         assert int(got_n) == 1
+    scenario = case.split("-")[0]
+    if scenario in ("no_move", "singletons_only"):
+        assert counts[-1] == 0
+    if scenario in ("untouched_members", "giant_root_drops"):
+        assert 0 < counts[-1] <= k_max
+    if scenario == "over_k_max":
+        assert counts[-1] > k_max
 
 
 def test_merger_correct_under_partial_disorder():

@@ -704,8 +704,71 @@ def test_readback_bytes_follow_the_output_shapes(recorder):
         egress="full")
     drv.run_arrays(src, dst)
     # degrees and labels [vb+1], cover [2vb+1] int32, two int32 rounds
-    per_window = 4 * (4 * drv.vb + 3) + 8
+    # and two int32 counts of relabelled roots
+    per_window = 4 * (4 * drv.vb + 3) + 16
     assert _counters("driver.readback_bytes") == [(8 * per_window, 8)]
+
+
+def _np_relabels(table, s, d, seen_slot):
+    """A fold's relabel counts in numpy: (distinct touched roots that
+    move and have members, the touched entries of such roots)."""
+    new, _n = _np_fixpoint_rounds(table, s, d)
+    touched = np.concatenate([table[s], table[d]])
+    keep = (new[touched] != touched) & seen_slot(touched)
+    return new, len(np.unique(touched[keep])), int(keep.sum())
+
+
+@pytest.mark.parametrize("k_max", [unionfind.RELABEL_K_MAX, 3])
+@pytest.mark.parametrize("layout", ["one_chip", "mesh"])
+def test_relabel_counters_match_numpy(recorder, monkeypatch, layout, k_max):
+    """`driver.relabel_roots` and `driver.relabel_gathers` are recorded
+    once a chunk, on one chip and on the CPU mesh, and equal a numpy
+    count of the moved roots with members (a root of degree > 0 before
+    the window) over the chunk's windows; with the list cut to 3 the
+    folds past it gather, and count their entries."""
+    monkeypatch.setattr(unionfind, "RELABEL_K_MAX", k_max)
+    eb, calls, per_call = 16, 3, 8
+    rng = np.random.default_rng(17)
+    src = rng.integers(0, 48, eb * per_call * calls)
+    dst = rng.integers(0, 48, eb * per_call * calls)
+    kw = {"mesh": make_mesh(4)} if layout == "mesh" else {
+        "snapshot_tier": "scan", "egress": "full"}
+    drv = StreamingAnalyticsDriver(
+        window_ms=0, analytics=("degrees", "cc", "bipartite"),
+        vertex_bucket=64, edge_bucket=eb, **kw)
+    out = []
+    for k in range(calls):
+        part = slice(k * eb * per_call, (k + 1) * eb * per_call)
+        out += drv.run_arrays(src[part], dst[part])
+    vb = drv.vb
+    slot = {int(x): i for i, x in enumerate(out[-1].vertex_ids)}
+    deg = np.zeros(vb + 1, np.int64)
+    lab, cov = np.arange(vb + 1), np.arange(2 * vb + 1)
+    roots, gathers = [], []
+    for w in range(len(out)):
+        s = np.array([slot[int(x)] for x in src[w * eb:(w + 1) * eb]])
+        d = np.array([slot[int(x)] for x in dst[w * eb:(w + 1) * eb]])
+        s2, d2 = np.concatenate([s, s + vb]), np.concatenate([d + vb, d])
+        lab, n_cc, raw_cc = _np_relabels(lab, s, d, lambda r: deg[r] > 0)
+        cov, n_cov, raw_cov = _np_relabels(
+            cov, s2, d2, lambda r: deg[np.where(r >= vb, r - vb, r)] > 0)
+        roots.append(sum(raw if raw > k_max else n
+                         for n, raw in ((n_cc, raw_cc), (n_cov, raw_cov))))
+        gathers.append(int(raw_cc > k_max) + int(raw_cov > k_max))
+        np.add.at(deg, s, 1)
+        np.add.at(deg, d, 1)
+        np.testing.assert_array_equal(out[w].cc_labels,
+                                      lab[:len(out[w].vertex_ids)])
+    assert sum(roots) > 0
+    assert (sum(gathers) > 0) == (k_max == 3)
+    chunks = [w for _v, w in _counters("driver.cc_rounds")]
+    for name, want in (("driver.relabel_roots", roots),
+                       ("driver.relabel_gathers", gathers)):
+        got, at = _counters(name), 0
+        assert [w for _v, w in got] == chunks and sum(chunks) == len(out)
+        for value, windows in got:
+            assert value == sum(want[at:at + windows]), name
+            at += windows
 
 
 # ----------------------------------------------------------------------
@@ -873,11 +936,20 @@ def test_fold_loop_has_no_table_sized_ops(program):
             stack, stack, jax.ShapeDtypeStruct((w, eb), jnp.bool_))
         assert _loop_ops_past(lowered, vb) == (2, [])
         # compiled, the fold loop keeps the table in place: the only
-        # copies in its body are of the touched slots' labels
+        # copies in its body are of the touched slots' labels. The
+        # relabel's loops after it (its passes over the table, the
+        # search that packs its list) are other while loops: pick the
+        # fold loop by its own op name
         compiled = jax.jit(unionfind.cc_fold_rooted).lower(
             table, edges, edges).compile().as_text()
-        body = re.search(r"body=%?([\w.\-]+)", compiled).group(1)
-        block = compiled.split("\n%" + body + " ", 1)[1].split("\n}", 1)[0]
+        bodies = re.findall(r"body=%?([\w.\-]+), metadata=\{op_name="
+                            r"\"jit\(cc_fold_rooted\)/([^\"]*)\"", compiled)
+        assert len(bodies) == 3
+        body = [b for b, name in bodies if name == "while"]
+        assert len(body) == 1
+        block = compiled.split("\n%" + body[0] + " ", 1)[1].split(
+            "\n}", 1)[0]
+        assert "scatter" in block
         assert re.search(r"s32\[%d\]\S* copy\(" % (vb + 1), block) is None
     else:
         fn = jax.jit(lambda l, s, d: unionfind.cc_fixpoint(l, s, d))
