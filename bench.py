@@ -334,15 +334,6 @@ def run_at_scale(scale: float, metric_suffix: str = "") -> None:
             ts.append(time.perf_counter() - t0)
         cpu_rate = nfull * window_edges / float(np.median(ts))
 
-    # the tier the framework actually routes this bucket to (committed
-    # per-bucket evidence on chip, process-wide on CPU backends;
-    # ops/triangles._resolve_stream_impl) — reported so every row says
-    # what ran, and so a routed row still carries the raw chip path as
-    # its decomposition (VERDICT r4 item 5)
-    from gelly_streaming_tpu.ops.triangles import _resolve_stream_impl
-
-    tier = _resolve_stream_impl(kernel.eb)
-
     # warmup at the exact chunk shapes of the timed run (compile here)
     warmup_stream_shapes(kernel, num_edges)
     ts = []
@@ -357,7 +348,7 @@ def run_at_scale(scale: float, metric_suffix: str = "") -> None:
     assert list(timed_counts[:nfull]) == full_counts, (
         list(timed_counts[:nfull]), full_counts)
 
-    # the same routed path with the ingress pipeline FORCED
+    # the same device path with the ingress pipeline FORCED
     # SYNCHRONOUS (single-threaded prep, no worker pool): the A/B the
     # pipelined-host-ingress work is accountable to, with exact
     # window-by-window parity asserted — identical counts are part of
@@ -375,32 +366,14 @@ def run_at_scale(scale: float, metric_suffix: str = "") -> None:
     assert list(sync_counts) == list(timed_counts), \
         "pipelined path diverged from sync host-prep path"
 
-    device_path_rate = None
-    if tier != "device":
-        # decomposition row: the raw device/chip path at this scale,
-        # parity-checked against the routed tier's counts (one rep —
-        # it exists to show WHERE the crossover sits, not as the
-        # headline)
-        from gelly_streaming_tpu.ops import segment as seg_ops
-
-        seg_ops.warm_stream_buckets(kernel)
-        dev_stream = kernel._count_stream_device(src, dst)  # warm run
-        assert list(dev_stream) == list(timed_counts), \
-            "device path diverged from routed tier"
-        t0 = time.perf_counter()
-        kernel._count_stream_device(src, dst)
-        device_path_rate = num_edges / (time.perf_counter() - t0)
-
     row = {
         "metric": "edges/sec/chip, exact window triangle count "
-                  "(power-law stream, %d-edge windows)%s%s"
-                  % (window_edges,
-                     "" if tier == "device" else " [%s tier]" % tier,
-                     metric_suffix),
+                  "(power-law stream, %d-edge windows)%s"
+                  % (window_edges, metric_suffix),
         "value": round(rate),
         "unit": "edges/s",
         "device": _device(),
-        "tier": tier,
+        "tier": "device",
         "vs_baseline": round(rate / cpu_rate, 2),
         # the measured baselines, persisted (BASELINE.md milestone:
         # faithful CPU ports of WindowTriangles.java:83-140 on the same
@@ -414,7 +387,7 @@ def run_at_scale(scale: float, metric_suffix: str = "") -> None:
             round(cpu_np_sample_rate),
         "baseline_cpu_python_edges_per_s": round(cpu_py_rate),
         "vs_python_baseline": round(rate / cpu_py_rate, 2),
-        # the ingress-pipeline A/B: the routed path with parallel
+        # the ingress-pipeline A/B: the device path with parallel
         # window prep + overlapped h2d/dispatch (the headline `value`)
         # vs the same path forced single-threaded-synchronous,
         # identical counts asserted window-by-window above
@@ -423,10 +396,6 @@ def run_at_scale(scale: float, metric_suffix: str = "") -> None:
         "pipeline_workers": ingress_pipeline.worker_count(),
         "num_edges": num_edges,
     }
-    if device_path_rate is not None:
-        row["device_path_edges_per_s"] = round(device_path_rate)
-        row["device_path_vs_baseline"] = round(
-            device_path_rate / cpu_rate, 2)
     # chosen-knob provenance: every row says what dispatch
     # configuration it actually ran — the static gates, and (when the
     # online tuner was live on the device path) the tuner's chosen arm
@@ -536,26 +505,7 @@ def run_reduce_leg(metric_suffix: str = "") -> None:
         eng.process_stream(src, dst, val)
         ts.append(time.perf_counter() - t0)
     rate = num_edges / float(np.median(ts))
-    from gelly_streaming_tpu.ops.windowed_reduce import (
-        _resolve_reduce_impl)
-
-    tier = _resolve_reduce_impl("sum")
     from gelly_streaming_tpu.utils import telemetry as _telemetry
-
-    device_path_rate = None
-    if tier != "device":
-        # decomposition row: the raw device segment-kernel path (one
-        # warm + one timed rep), parity-checked against the routed
-        # tier's already-verified windows like the triangles leg
-        dev = eng._device_process_stream(src.astype(np.int64),
-                                         dst.astype(np.int64), val)
-        for (cells, _cnt), want in zip(dev, base):
-            np.testing.assert_array_equal(
-                cells[:num_vertices].astype(np.int64), want)
-        t0 = time.perf_counter()
-        eng._device_process_stream(src.astype(np.int64),
-                                   dst.astype(np.int64), val)
-        device_path_rate = num_edges / (time.perf_counter() - t0)
 
     print(json.dumps({
         "metric": "edges/sec/chip, windowed reduceOnEdges "
@@ -564,7 +514,7 @@ def run_reduce_leg(metric_suffix: str = "") -> None:
         "value": round(rate),
         "unit": "edges/s",
         "device": _device(),
-        "tier": tier,
+        "tier": "device",
         "vs_baseline": round(rate / cpu_rate, 2),
         "baseline_cpu_edges_per_s": round(cpu_rate),
         # secondary: the port made contract-equal (values AND counts)
@@ -573,10 +523,6 @@ def run_reduce_leg(metric_suffix: str = "") -> None:
         "num_edges": num_edges,
         # trace-ID correlation (see the triangles leg's row)
         "trace": _telemetry.trace_id(),
-        **({"device_path_edges_per_s": round(device_path_rate),
-            "device_path_vs_baseline": round(
-                device_path_rate / cpu_rate, 2)}
-           if device_path_rate is not None else {}),
     }), flush=True)
 
 
@@ -742,7 +688,7 @@ def run_gnn_leg(metric_suffix: str = "") -> None:
         "knobs": {"eb": eb, "vb": vb, "feature_dim": F,
                   "act": _knobs.get_str("GS_GNN_ACT") or "relu",
                   "pallas": _knobs.get_str("GS_GNN_PALLAS")
-                  or "auto"},
+                  or "off"},
         "trace": _telemetry.trace_id(),
     }), flush=True)
 

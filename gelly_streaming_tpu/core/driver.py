@@ -288,57 +288,14 @@ def _readback_counters(outs: dict, windows: int) -> None:
                           windows=windows)
 
 
-_SNAPSHOT_TIER = None  # resolved once per process (reset below)
-
-
-def _reset_snapshot_tier() -> None:
-    """Test hook: forget the memoized snapshot-tier selection."""
-    global _SNAPSHOT_TIER
-    _SNAPSHOT_TIER = None
-
-
 def resolve_snapshot_tier() -> str:
-    """Batched snapshot-analytics tier: the device scan by default;
-    the RESIDENT megakernel (ops/resident_engine) when the GS_RESIDENT
-    pin or committed backend-matched `resident_ab` rows select it —
-    that gate compares resident against the best committed alternative
-    (scan AND native), so adopting it can never regress a stream
-    native already serves faster; else the native C++ carried
-    union-find (native.snapshot_windows) only when (a) this process
-    runs a CPU backend — on chip the scan always stands — and (b)
-    committed backend-matched `host_snapshot` rows
-    (tools/profile_kernels.py) all show parity and a ≥5% win, and
-    (c) the library exports the symbol. The same measured-default
-    policy as ops/triangles._resolve_stream_impl."""
-    global _SNAPSHOT_TIER
-    if _SNAPSHOT_TIER is not None:
-        return _SNAPSHOT_TIER
-    tier = "scan"
-    try:
-        from ..ops import resident_engine
+    """Batched snapshot-analytics tier: the device scan, or the
+    RESIDENT megakernel (ops/resident_engine) when GS_RESIDENT pins
+    it. The native and host tiers below them are the demotion
+    ladder's rungs and the `snapshot_tier=` pin's."""
+    from ..ops import resident_engine
 
-        if resident_engine.resolve_resident():
-            _SNAPSHOT_TIER = "resident"
-            return _SNAPSHOT_TIER
-        if _jax_backend() == "cpu":
-            perf = tri_ops._load_matching_perf("cpu")
-            if (tri_ops.rows_clear_bar(
-                    (perf or {}).get("host_snapshot", []),
-                    "native_edges_per_s", "scan_edges_per_s")
-                    and native.snapshot_available()):
-                tier = "native"
-    except Exception as e:
-        telemetry.event("selection.fallback", durable=True,
-                        component="snapshot_tier", fallback=tier,
-                        error="%s: %s" % (type(e).__name__, e))
-    _SNAPSHOT_TIER = tier
-    return tier
-
-
-def _jax_backend() -> str:
-    import jax as _jax
-
-    return _jax.default_backend()
+    return "resident" if resident_engine.resolve_resident() else "scan"
 
 
 @dataclasses.dataclass
@@ -409,7 +366,7 @@ class StreamingAnalyticsDriver:
         if egress not in (None, "full", "delta"):
             raise ValueError(f"unknown egress: {egress!r}")
         # d2h egress of the batched snapshot scan: explicit pin (tests,
-        # tools/egress_ab.py) or committed-evidence resolution
+        # tools/egress_ab.py) or the GS_EGRESS knob
         # (ops/delta_egress.resolve_egress); sharded meshes always full
         self._egress_pin = egress
         # multi-tenant label (core/tenancy.py serving story): when this
@@ -421,7 +378,7 @@ class StreamingAnalyticsDriver:
         self.window_ms = window_ms
         self.analytics = tuple(analytics)
         # batched snapshot analytics tier: explicit pin (tests, the
-        # profiler's A/B) or committed-evidence resolution
+        # profiler's A/B) or resolve_snapshot_tier()
         self._snapshot_tier = snapshot_tier
         self.emit_deltas = bool(emit_deltas)
         self.mesh = mesh
@@ -908,11 +865,8 @@ class StreamingAnalyticsDriver:
 
     def _scan_chunk(self) -> int:
         """Windows per snapshot-scan dispatch: _SCAN_CHUNK, compile-
-        size-capped per-PROGRAM on TPU backends (ops/triangles
-        .compile_cap "snapshot_scan"; the caps predate this chip
-        attachment, ROADMAP queue 1)."""
-        return min(self._SCAN_CHUNK,
-                   tri_ops.capped_chunk(self.eb, "snapshot_scan"))
+        size-capped on TPU backends (ops/triangles.COMPILE_CAP)."""
+        return min(self._SCAN_CHUNK, tri_ops.capped_chunk(self.eb))
 
     def _scan_tuner_key(self) -> str:
         return ("snapshot_scan:eb=%d:vb=%d:%s"
@@ -1040,7 +994,7 @@ class StreamingAnalyticsDriver:
 
     def _scan_egress(self) -> str:
         """The batched scan's d2h egress format: the constructor pin,
-        else the committed-evidence resolution (ops/delta_egress).
+        else the GS_EGRESS knob (ops/delta_egress.resolve_egress).
         Sharded meshes always run full-vector egress — their snapshots
         ride replicated shard_map outputs, symmetric to compact
         ingress staying off the mesh path."""

@@ -47,8 +47,7 @@ The serving pieces around the slab:
   dispatches — and its checkpoints stay engine-interchangeable.
 - **Resident cohort tier.** With
   ops/resident_engine.resolve_resident_cohort selected
-  (GS_COHORT_RESIDENT pin or committed `tenancy_ab`/`cohort_resident`
-  parity+speedup rows), the per-(vb, kb) group's carries live as ONE
+  (GS_COHORT_RESIDENT=on), the per-(vb, kb) group's carries live as ONE
   stacked `[N, ...]` pytree on device between rounds, updated in
   place by a donated super-batch program
   (`jax.jit(..., donate_argnums)` where the backend honors donation)
@@ -1949,8 +1948,7 @@ class GnnTenantCohort:
     cap and typed rejects, per-tenant queues and window accounting,
     the bucketed program cache — without the analytics cohort's
     residency/quarantine/autotune machinery, which is specialized to
-    the 3-slab analytics carry. Those rungs graduate here the same way
-    they did there: behind committed A/B rows."""
+    the 3-slab analytics carry."""
 
     def __init__(self, edge_bucket: int, vertex_bucket: int,
                  feature_dim: int = None, activation: str = None):

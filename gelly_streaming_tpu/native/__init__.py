@@ -214,11 +214,11 @@ def triangle_count_stream(src: np.ndarray, dst: np.ndarray,
                           eb: int) -> Optional[np.ndarray]:
     """Exact triangle counts of every tumbling `eb`-sized window of the
     stream via the C++ compact-forward counter (ingest.cpp
-    gs_triangle_count_stream) — the native tier of
+    gs_triangle_count_stream), a parity oracle and profiler row beside
     ops/triangles.count_stream. Returns None when the library (or the
-    symbol, for a stale build) is unavailable; callers fall back to the
-    numpy tier. Counting invariant and results are identical to the
-    numpy and device tiers (asserted in tests/library/test_triangles.py)."""
+    symbol, for a stale build) is unavailable. Counting invariant and
+    results are identical to the numpy host twin and the device
+    program (asserted in tests/library/test_triangles.py)."""
     if not triangles_available():
         return None
     src = np.ascontiguousarray(src, np.int64)
@@ -243,7 +243,7 @@ def windowed_reduce(src: np.ndarray, dst: np.ndarray, val: np.ndarray,
                     eb: int, vbp: int, name: str, direction: str,
                     ident: int):
     """Fused (cells, counts) windowed reduce via the C++ kernel
-    (ingest.cpp gs_windowed_reduce*) — the native tier of
+    (ingest.cpp gs_windowed_reduce*) — the C++ form of
     ops/windowed_reduce.WindowedEdgeReduce for integer values. Returns
     (cells [num_w, vbp], counts [num_w, vbp]); cells pre-filled with
     `ident`. The slab dtype is int32 when the fast forms apply (int32

@@ -127,11 +127,11 @@ void gs_interner_lookup(void* h, const int32_t* dense, int64_t n,
 }
 
 // ---------------------------------------------------------------------
-// Exact window triangle count — the native tier of the streaming
-// counter (ops/triangles._resolve_stream_impl "native").
+// Exact window triangle count — the C++ form of the streaming counter,
+// a parity oracle and profiler row beside the device program.
 //
 // Same counting invariant as the device kernel (ops/triangles.py
-// build_window_counter) and the numpy tier (ops/host_triangles.py):
+// build_window_counter) and the numpy host twin (ops/host_triangles.py):
 // drop self-loops, undirect + dedupe, orient each edge
 // low(deg, id) -> high(deg, id), count every triangle once — at its
 // min-rank edge, by two-pointer intersection of the endpoints' sorted

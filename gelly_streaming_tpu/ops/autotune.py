@@ -1,16 +1,14 @@
 """Online dispatch autotuner: find the fast (windows-per-dispatch,
 K bucket, ingress format) configuration ON the stream actually running.
 
-Every dispatch knob used to be a STATIC committed-evidence gate read
-from PERF.json at import (ops/triangles._tuned_chunk/_tuned_kb/
-resolve_ingress): right for reproducibility, wrong for a stream whose
-load, skew, or dispatch latency differs from the profile stream — an
-earlier attachment's chip rows pinned end-to-end rate at ~500-770K
-edges/s while the chunk sweep was still climbing at the compile cap
-(not current numbers; PERF.md "Hot-kernel
-profile"), i.e. the static pick amortizes dispatch latency worse than
-the best live pick would. This module is the runtime's measured
-selection loop:
+Every dispatch knob has a STATIC default (ops/triangles._default_chunk
+and _tuned_kb, standard ingress): right for reproducibility, wrong for
+a stream whose load, skew, or dispatch latency differs from the one
+the default was set for — an earlier attachment's chip rows pinned
+end-to-end rate at ~500-770K edges/s while the chunk sweep was still
+climbing at the compile cap (not current numbers), i.e. the static
+pick amortizes dispatch latency worse than the best live pick would.
+This module is the runtime's measured selection loop:
 
 - The search space is SMALL and SAFE by construction: every arm is a
   configuration today's kernels already run correctly (wb rungs within
@@ -40,7 +38,7 @@ tests/operations/test_autotune.py and the chaos autotune leg).
 Pre-warm discipline: callers compile an arm's programs (AOT,
 `_stream_exec`-style caches) BEFORE its first timed round, so
 steady-state streaming still never compiles mid-measurement; arms
-never exceed the per-program compile cap (ops/triangles.compile_cap).
+never exceed the compile cap (ops/triangles.COMPILE_CAP).
 """
 
 from __future__ import annotations
@@ -53,7 +51,7 @@ from typing import Dict, List, Optional
 from ..utils import knobs
 from ..utils import telemetry
 
-_DEF_MARGIN = 1.05        # the repo-wide measured-adoption bar
+_DEF_MARGIN = 1.05        # hysteresis: a challenger must win by 5%
 _EMA_ALPHA = 0.5          # smoothing of per-arm measured rates
 _TIMELINE_CAP = 256       # bound per-tuner event history
 

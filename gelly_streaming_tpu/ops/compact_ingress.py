@@ -17,10 +17,9 @@ counts, 2.25× fewer h2d bytes. Where the end-to-end stream rate is
 transfer/dispatch bound, ingress bytes are directly on the critical
 path: this is the PCIe/DCN ingest-bandwidth lever.
 
-Adoption is evidence-gated like every other selection
-(ops/triangles.py `_resolve_*` family): the kernel only switches to
-compact ingress when a committed backend-matched `ingress_ab` row
-(tools/ingress_ab.py) shows parity and a ≥5% end-to-end win.
+The kernels run compact ingress when their `ingress="compact"`
+argument pins it, or when the online autotuner's ingress arm
+(ops/autotune.py) measures it faster on the live stream.
 
 Design provenance: the reference streams edges as (int,int) tuples
 through Flink's network stack (SimpleEdgeStream.java:60-90); the

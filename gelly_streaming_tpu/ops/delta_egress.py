@@ -27,13 +27,10 @@ bit-exact twin the demotion ladder already trusts), keeping results
 exact at every cap. GS_EGRESS_CAP shrinks the cap below the exact
 bound when the A/B shows a tighter wire wins net of rare refolds.
 
-Adoption is evidence-gated like every other selection
-(ops/triangles.resolve_ingress symmetry): full-vector is the default
-and the fallback everywhere; `resolve_egress` returns "delta" only
-when committed backend-matched `egress_ab` rows (tools/egress_ab.py)
-all show exact parity and a ≥5% end-to-end win, or when GS_EGRESS
-pins it. The sharded engines keep full-vector egress (their snapshots
-ride replicated outputs, and the mesh path has no AOT warm cache).
+Full-vector egress is the default and the fallback everywhere;
+`resolve_egress` returns "delta" only when GS_EGRESS pins it. The
+sharded engines keep full-vector egress (their snapshots ride
+replicated outputs, and the mesh path has no AOT warm cache).
 """
 
 from __future__ import annotations
@@ -41,42 +38,12 @@ from __future__ import annotations
 import numpy as np
 
 from ..utils import knobs
-from ..utils import telemetry
-
-_EGRESS = None   # "full" | "delta", resolved once per process
-
-
-def _reset_egress() -> None:
-    """Test hook: forget the memoized egress selection."""
-    global _EGRESS
-    _EGRESS = None
 
 
 def resolve_egress() -> str:
     """The d2h egress format of the batched snapshot/reduce paths:
-    GS_EGRESS pins ("full"/"delta"); unset/"auto" = "delta" only on
-    committed backend-matched `egress_ab` rows all showing parity and
-    a ≥5% win (the repo-wide measured-adoption policy,
-    ops/triangles.rows_clear_bar). Memoized per process."""
-    global _EGRESS
-    pin = knobs.get_str("GS_EGRESS")
-    if pin in ("full", "delta"):
-        return pin
-    if _EGRESS is None:
-        impl = "full"
-        try:
-            from . import triangles as tri_ops
-
-            perf = tri_ops._load_matching_perf()
-            if tri_ops.rows_clear_bar((perf or {}).get("egress_ab", []),
-                                      "speedup", lambda r: 1.0):
-                impl = "delta"
-        except Exception as e:
-            telemetry.event("selection.fallback", durable=True,
-                            component="egress", fallback=impl,
-                            error="%s: %s" % (type(e).__name__, e))
-        _EGRESS = impl
-    return _EGRESS
+    "delta" when GS_EGRESS pins it, "full" otherwise."""
+    return "delta" if knobs.get_str("GS_EGRESS") == "delta" else "full"
 
 
 def egress_cap(eb: int, vb: int) -> int:

@@ -214,10 +214,10 @@ def _build_gnn_scan(eb: int, vb: int, F: int, act: str,
     """The per-window body the scan engines fold:
     body(h, W, b, (s, d, v)) -> (h', ys). When the fused Pallas GNN
     kernel is selected (ops/pallas_window.resolve_gnn_pallas —
-    GS_GNN_PALLAS pin or committed parity+≥1.05× `gnn_ab` chip rows)
-    AND its build/trace probe succeeds, the returned body is the
-    kernel instead: one pallas_call per window streaming the edge
-    slab through VMEM with the feature slab resident — the features
+    GS_GNN_PALLAS=on) AND its build/trace probe succeeds, the
+    returned body is the kernel instead: one pallas_call per window
+    streaming the edge slab through VMEM with the feature slab
+    resident — the features
     ride the same single HBM read as the megakernel's analytics.
     `pallas_ok=False` keeps the cohort's vmapped composition pure-XLA
     (same opt-out as scan_analytics.build_cohort_scan)."""

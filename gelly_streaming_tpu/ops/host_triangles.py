@@ -1,5 +1,6 @@
-"""Vectorized HOST (numpy) window triangle kernel — the CPU-backend
-tier of the streaming window counter.
+"""Vectorized HOST (numpy) window triangle kernel — the host twin of
+the streaming window counter (parallel/host_twin.py, the demotion
+ladder's last rung).
 
 Same exact algorithm as the device kernel (ops/triangles.py
 build_window_counter): drop self-loops, undirect + dedupe, orient
@@ -10,14 +11,8 @@ keys instead of the device's K-bucketed row compare: on a CPU backend
 every XLA dispatch runs the same single core numpy uses, but pays two
 O(E log E) lax.sorts, segment scatters and fixed-shape padding per
 window — the numpy form does one argsort and touches only real edges,
-so it wins the CPU-fallback regime outright (committed PERF.json
-`host_stream` section; selection is backend-matched and measured, like
-every kernel choice in this package — ops/triangles.py
-`_resolve_stream_impl`).
-
-The chip path is untouched: on a TPU backend the device kernel always
-stands (dispatch cost amortizes over the stream, and the MXU/VPU do
-the intersection orders of magnitude faster than the host).
+so it is the cheap exact form when the device path is unavailable.
+The streaming counter itself always runs the device program.
 
 Counts match the reference pipeline (WindowTriangles.java:61-66,
 :83-140) exactly — asserted against the device kernel and the golden

@@ -15,8 +15,8 @@ into per-edge DMAs, and XLA's gather is already bandwidth-optimal.
 What the kernel can win is the compare loop's scheduling; what it can
 lose is XLA's fusion of the gather INTO the compare (which skips the
 [Ep, K] rows_a/rows_b round trip to HBM entirely). tools/
-profile_kernels.py measures both on-chip; ops/triangles.py keeps
-whichever the committed PERF.json says wins (see PERF.md).
+profile_kernels.py measures both on-chip; ops/triangles.py builds
+the XLA compare into its programs.
 
 On non-TPU backends the kernel runs in interpreter mode (virtual CPU
 mesh tests), keeping behavior identical everywhere.
@@ -36,43 +36,12 @@ from .pallas_triangles import _need_interpret
 TILE_E = 64      # default edges per grid step: the [T, CHUNK_K, K]
                  # broadcast compare materializes in VMEM, so
                  # T=64/Ck=128/K<=256 stays under the 16M scoped-vmem
-                 # limit (T=256 OOMs). The SHIPPED shape is a measured
-                 # selection — see _resolve_tile.
+                 # limit (T=256 OOMs)
 CHUNK_K = 128    # default compare-chunk width (lane-aligned)
 MAX_TILES = 2048 # grid steps per pallas_call: the [g] partial vector
                  # lives wholly in SMEM (scarce scalar memory), so cap
                  # it at 8KB and slab larger edge buckets over several
                  # calls (each slab shape is identical -> one compile)
-
-_TILE_CHOICE = None  # (tile_e, chunk_k), resolved once per process
-
-
-def _resolve_tile():
-    """The (TILE_E, CHUNK_K) shape intersect_local_pallas ships:
-    the best parity-true row of the committed chip tile sweep
-    (PERF.json `intersect.pallas_sweep`, tools/profile_kernels.py
-    section_intersect) when one exists, else the module defaults —
-    the same committed-evidence policy as every other kernel
-    selection."""
-    global _TILE_CHOICE
-    if _TILE_CHOICE is not None:
-        return _TILE_CHOICE
-    choice = (TILE_E, CHUNK_K)
-    try:
-        from .triangles import _load_tpu_perf
-
-        perf = _load_tpu_perf()
-        rows = [r for r in ((perf or {}).get("intersect", {})
-                            .get("pallas_sweep", []) or [])
-                if r.get("parity") is True and r.get("ms")
-                and r.get("tile_e") and r.get("chunk_k")]
-        if rows:
-            best = min(rows, key=lambda r: r["ms"])
-            choice = (int(best["tile_e"]), int(best["chunk_k"]))  # gslint: disable=host-sync (committed-evidence JSON ints, no device value in sight)
-    except Exception:  # gslint: disable=except-hygiene (committed-evidence probe: absence/corruption selects the proven default)
-        pass
-    _TILE_CHOICE = choice
-    return choice
 
 
 def tile_intersect_count(ra, rb, va, chunk_k: int):
@@ -146,16 +115,11 @@ def _intersect_tiles(rows_a: jax.Array, rows_b: jax.Array,
 
 
 def intersect_local_pallas(nbr: jax.Array, ea: jax.Array, eb: jax.Array,
-                           emask: jax.Array, tile_e: int = None,
-                           chunk_k: int = None) -> jax.Array:
+                           emask: jax.Array, tile_e: int = TILE_E,
+                           chunk_k: int = CHUNK_K) -> jax.Array:
     """Drop-in for ops/triangles.intersect_local (same contract: count
-    of |N_out(a) ∩ N_out(b)| over all valid oriented edges). The tile
-    shape defaults to the committed chip sweep's winner
-    (_resolve_tile); the profiler passes explicit shapes to sweep."""
-    if tile_e is None or chunk_k is None:
-        rt, rc = _resolve_tile()
-        tile_e = rt if tile_e is None else tile_e
-        chunk_k = rc if chunk_k is None else chunk_k
+    of |N_out(a) ∩ N_out(b)| over all valid oriented edges). The
+    profiler passes explicit tile shapes to sweep."""
     sentinel = nbr.shape[0] - 1
     ep = ea.shape[0]
     slab_e = MAX_TILES * tile_e

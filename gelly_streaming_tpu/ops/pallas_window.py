@@ -42,16 +42,13 @@ ops/pallas_triangles.py):
   K-overflow hands off to the call sites' existing exact-redo
   escalation, so exactness is never sacrificed.
 
-Selection is the repo's measured-adoption gate (`resolve_*` family):
-`GS_PALLAS_WINDOW` pins on/off; unset/`auto` adopts ONLY on committed
-backend-matched `pallas_ab` rows (tools/pallas_ab.py) that all show
-exact parity and ≥1.05×, so the XLA fused scan stands — and CPU
-digests stay bit-identical — until a chip row lands. Selection probes
-the built kernel: a trace in interpret mode, a lowering and compile for
+Selection (`resolve_*` family): `GS_PALLAS_WINDOW=on` pins the
+kernel; unset or `off`, the XLA fused scan runs. Selection probes the
+built kernel: a trace in interpret mode, a lowering and compile for
 the chip otherwise. A kernel pinned `on` that the chip's compiler
 refuses raises PallasUnavailable with the compiler's reason; one that
-was adopted without a pin, or runs in interpret mode, degrades to the
-XLA body with a durable `selection.fallback` event.
+runs in interpret mode degrades to the XLA body with a durable
+`selection.fallback` event.
 
 On a v5e this megakernel, the triangle-only counter, the tenant-axis
 cohort kernel and the GNN kernel are all refused today: Mosaic has no
@@ -62,10 +59,9 @@ interpret mode only, as parity oracles.
 Off-TPU the kernel runs in INTERPRET mode (the seeds' convention):
 bit-identical to the XLA scan and the host twins by construction —
 that is tier-1's parity oracle (tests/operations/
-test_pallas_window.py, ci_check gate 7) — but it times nothing real,
-so interpret rows can never clear the adoption bar. Interpret also
-unrolls the grid at trace time, so off-TPU the default edge tile is
-the whole slab (one grid step keeps the jaxpr linear); the tiled
+test_pallas_window.py, ci_check gate 7) — but it times nothing real.
+Interpret also unrolls the grid at trace time, so off-TPU the default
+edge tile is the whole slab (one grid step keeps the jaxpr linear); the tiled
 path is exercised by tests at small buckets and is the shape the
 chip session tunes (`pallas_window` DispatchTuner family: edge-tile
 × K-chunk arms).
@@ -118,121 +114,36 @@ _PROBES = {}  # (vb,kb,kind) -> bool probe verdict  # gslint: disable=thread-sha
 # ----------------------------------------------------------------------
 # selection gate (the resolve_* family)
 # ----------------------------------------------------------------------
-_PALLAS = None  # "pallas" | "xla", resolved once per process
-_COHORT_PALLAS = None  # "pallas" | "xla", resolved once per process
-_GNN_PALLAS = None  # "pallas" | "xla", resolved once per process
-
-
 def _reset_pallas_window() -> None:
-    """Test hook: forget the memoized selections and probe verdicts."""
-    global _PALLAS, _COHORT_PALLAS, _GNN_PALLAS
-    _PALLAS = None
-    _COHORT_PALLAS = None
-    _GNN_PALLAS = None
+    """Test hook: forget the memoized probe verdicts."""
     _PROBES.clear()
 
 
 def resolve_pallas_window() -> bool:
     """Should the fused-scan/triangle window bodies run the Pallas
-    megakernel instead of the XLA scan-of-gathers? GS_PALLAS_WINDOW
-    pins (`on`/`off`); unset/`auto` adopts only when committed
-    backend-matched `pallas_ab` rows (tools/pallas_ab.py) ALL show
-    exact parity and ≥1.05× (ops/triangles.rows_clear_bar — the
-    repo-wide measured-adoption policy). Interpret-mode rows can
-    never clear that bar, so CPU behavior stays bit-identical until
-    a chip row lands. Memoized per process."""
-    global _PALLAS
-    pin = knobs.get_str("GS_PALLAS_WINDOW")
-    if pin == "on":
-        return True
-    if pin == "off":
-        return False
-    if _PALLAS is None:
-        impl = "xla"
-        try:
-            perf = tri_ops._load_matching_perf()
-            if tri_ops.rows_clear_bar(
-                    (perf or {}).get("pallas_ab", []),
-                    "speedup", lambda r: 1.0):
-                impl = "pallas"
-        except Exception as e:
-            telemetry.event("selection.fallback", durable=True,
-                            component="pallas_window", fallback=impl,
-                            error="%s: %s" % (type(e).__name__, e))
-        _PALLAS = impl
-    return _PALLAS == "pallas"
+    megakernel instead of the XLA scan-of-gathers? Only when
+    GS_PALLAS_WINDOW pins it `on`; the XLA body otherwise."""
+    return knobs.get_str("GS_PALLAS_WINDOW") == "on"
 
 
 def resolve_cohort_pallas() -> bool:
     """Should build_cohort_scan run the TENANT-AXIS Pallas megakernel
     (the tenant axis as a second grid dimension of one pallas_call,
     the whole cohort's carries VMEM-resident) instead of vmapping the
-    XLA scan body over tenants? GS_COHORT_PALLAS pins (`on`/`off`);
-    unset/`auto` adopts only when committed backend-matched
-    `tenancy_ab` rows with probe `cohort_pallas` — NON-interpret rows
-    only, the interpret parity rows time nothing real — ALL show
-    exact per-tenant parity and ≥1.05× (ops/triangles.rows_clear_bar).
-    CPU cohort digests stay bit-identical until a chip row lands.
-    Memoized per process."""
-    global _COHORT_PALLAS
-    pin = knobs.get_str("GS_COHORT_PALLAS")
-    if pin == "on":
-        return True
-    if pin == "off":
-        return False
-    if _COHORT_PALLAS is None:
-        impl = "xla"
-        try:
-            perf = tri_ops._load_matching_perf()
-            rows = [r for r in (perf or {}).get("tenancy_ab", [])
-                    if r.get("probe") == "cohort_pallas"
-                    and not r.get("interpret")]
-            if tri_ops.rows_clear_bar(rows, "speedup",
-                                      lambda r: 1.0):
-                impl = "pallas"
-        except Exception as e:
-            telemetry.event("selection.fallback", durable=True,
-                            component="cohort_pallas", fallback=impl,
-                            error="%s: %s" % (type(e).__name__, e))
-        _COHORT_PALLAS = impl
-    return _COHORT_PALLAS == "pallas"
+    XLA scan body over tenants? Only when GS_COHORT_PALLAS pins it
+    `on`."""
+    return knobs.get_str("GS_COHORT_PALLAS") == "on"
 
 
 def resolve_gnn_pallas() -> bool:
     """Should the GNN engines (ops/gnn_window.py) run the fused
     Pallas GNN window kernel instead of the XLA gather/segment-sum
-    round? GS_GNN_PALLAS pins (`on`/`off`); unset/`auto` adopts only
-    when committed backend-matched `gnn_ab` rows with probe
-    `gnn_pallas` — NON-interpret rows only — ALL show exact parity
-    and ≥1.05× (ops/triangles.rows_clear_bar, the repo-wide
-    measured-adoption policy). CPU feature slabs stay bit-identical
-    until a chip row lands. Memoized per process."""
-    global _GNN_PALLAS
-    pin = knobs.get_str("GS_GNN_PALLAS")
-    if pin == "on":
-        return True
-    if pin == "off":
-        return False
-    if _GNN_PALLAS is None:
-        impl = "xla"
-        try:
-            perf = tri_ops._load_matching_perf()
-            rows = [r for r in (perf or {}).get("gnn_ab", [])
-                    if r.get("probe") == "gnn_pallas"
-                    and not r.get("interpret")]
-            if tri_ops.rows_clear_bar(rows, "speedup",
-                                      lambda r: 1.0):
-                impl = "pallas"
-        except Exception as e:
-            telemetry.event("selection.fallback", durable=True,
-                            component="gnn_pallas", fallback=impl,
-                            error="%s: %s" % (type(e).__name__, e))
-        _GNN_PALLAS = impl
-    return _GNN_PALLAS == "pallas"
+    round? Only when GS_GNN_PALLAS pins it `on`."""
+    return knobs.get_str("GS_GNN_PALLAS") == "on"
 
 
 # ----------------------------------------------------------------------
-# tiling layer (shared with the seeds' committed-evidence policy)
+# tiling layer
 # ----------------------------------------------------------------------
 def _on_tpu() -> bool:
     try:
@@ -319,9 +230,8 @@ def resolve_tiles(eb: int, kb: int, vb: int = 0,
     """(tile_e, ck) the megakernel builds at: explicit arguments (the
     A/B sweep) beat the GS_PALLAS_TILE/GS_PALLAS_CK pins beat the
     `pallas_window` tuner's persisted optimum for this shape beat the
-    defaults — the same committed-evidence ladder as the intersect
-    seed's _resolve_tile. Called at BUILD time only (knob reads must
-    not freeze inside a traced body)."""
+    defaults. Called at BUILD time only (knob reads must not freeze
+    inside a traced body)."""
     if tile_e is None:
         tile_e = knobs.get_int("GS_PALLAS_TILE") or 0
     if chunk_k is None:
@@ -333,9 +243,9 @@ def resolve_tiles(eb: int, kb: int, vb: int = 0,
             cached = autotune.load_cached_best(tuner_key(eb, vb, kb))
             if cached:
                 arm = cached.get("arm") or {}
-                tile_e = tile_e or int(arm.get("tile_e") or 0)  # gslint: disable=host-sync (committed-evidence JSON ints, no device value in sight)
-                chunk_k = chunk_k or int(arm.get("ck") or 0)  # gslint: disable=host-sync (committed-evidence JSON ints, no device value in sight)
-        except Exception:  # gslint: disable=except-hygiene (committed-evidence probe: absence/corruption selects the proven default)
+                tile_e = tile_e or int(arm.get("tile_e") or 0)  # gslint: disable=host-sync (tuner-cache JSON ints, no device value in sight)
+                chunk_k = chunk_k or int(arm.get("ck") or 0)  # gslint: disable=host-sync (tuner-cache JSON ints, no device value in sight)
+        except Exception:  # gslint: disable=except-hygiene (tuner-cache probe: absence/corruption selects the default tiles)
             pass
     tile_e = tile_e or default_tile(eb)
     tile_e = max(8, min(tile_e, eb))

@@ -37,10 +37,7 @@ this module is the refactor that joins them:
 - The **driver integration** lives in core/driver.py: `resident` is a
   snapshot tier ABOVE `scan` in the demotion ladder
   (resident → scan → native → host), selected by `resolve_resident()`
-  below — GS_RESIDENT pin or committed backend-matched `resident_ab`
-  rows (tools/resident_ab.py) clearing parity + the 1.05 bar over the
-  best committed alternative tier, the same measured-adoption policy
-  as compact ingress and delta egress.
+  below when GS_RESIDENT pins it.
 
 Exactness: the resident program is the SAME scan body as the tiers
 below it, so window-by-window results are bit-identical by
@@ -62,7 +59,6 @@ from . import segment as seg_ops
 from . import triangles as tri_ops
 from ..utils import knobs
 from ..utils import metrics
-from ..utils import telemetry
 
 
 # ----------------------------------------------------------------------
@@ -70,20 +66,12 @@ from ..utils import telemetry
 # ----------------------------------------------------------------------
 def resident_spb(eb: int) -> int:
     """Windows per super-batch of the resident megakernel: the
-    GS_RESIDENT_SPB bucket, compile-size-capped per PROGRAM on TPU
-    backends (ops/triangles.compile_cap, program key "resident_scan";
-    the caps predate this chip attachment, ROADMAP queue 1). Off-chip
-    the knob stands as asked."""
+    GS_RESIDENT_SPB bucket, compile-size-capped on TPU backends
+    (ops/triangles.COMPILE_CAP). Off-chip the knob stands as asked."""
     spb = seg_ops.bucket_size(knobs.get_int("GS_RESIDENT_SPB"))
-    try:
-        if jax.default_backend() == "tpu":
-            cap = max(1, tri_ops.compile_cap("resident_scan")
-                      // max(eb, 1))
-            spb = min(spb, seg_ops.bucket_size(cap))
-    except Exception as e:
-        telemetry.event("selection.fallback", durable=True,
-                        component="resident_spb", fallback=spb,
-                        error="%s: %s" % (type(e).__name__, e))
+    if jax.default_backend() == "tpu":
+        cap = max(1, tri_ops.COMPILE_CAP // max(eb, 1))
+        spb = min(spb, seg_ops.bucket_size(cap))
     return spb
 
 
@@ -114,60 +102,10 @@ def donate_kw() -> dict:
     return {"donate_argnums": (0,)} if donation_supported() else {}
 
 
-_RESIDENT = None  # "resident" | "scan", resolved once per process
-
-
-def _reset_resident() -> None:
-    """Test hook: forget the memoized resident-tier selection."""
-    global _RESIDENT
-    _RESIDENT = None
-
-
 def resolve_resident() -> bool:
     """Should the driver's batched snapshot path run the RESIDENT tier
-    instead of `scan`? GS_RESIDENT pins (`on`/`off`); unset/`auto`
-    adopts resident only when committed backend-matched `resident_ab`
-    driver rows (tools/resident_ab.py via tools/profile_kernels.py)
-    ALL show exact parity and ≥1.05× over the best committed
-    alternative tier in the row — scan AND, where measured, native —
-    so adopting resident can never regress a stream that native
-    already serves faster (the repo-wide measured-adoption policy,
-    ops/triangles.rows_clear_bar). Memoized per process."""
-    global _RESIDENT
-    pin = knobs.get_str("GS_RESIDENT")
-    if pin == "on":
-        return True
-    if pin == "off":
-        return False
-    if _RESIDENT is None:
-        impl = "scan"
-        try:
-            perf = tri_ops._load_matching_perf()
-            rows = [r for r in (perf or {}).get("resident_ab", [])
-                    if r.get("probe") == "driver_resident"]
-
-            def best_alternative(r):
-                return max(r.get("scan_edges_per_s") or 0,
-                           r.get("native_edges_per_s") or 0)
-
-            if tri_ops.rows_clear_bar(rows, "resident_edges_per_s",
-                                      best_alternative):
-                impl = "resident"
-        except Exception as e:
-            telemetry.event("selection.fallback", durable=True,
-                            component="resident", fallback=impl,
-                            error="%s: %s" % (type(e).__name__, e))
-        _RESIDENT = impl
-    return _RESIDENT == "resident"
-
-
-_RESIDENT_COHORT = None  # "resident" | "scan", resolved once per process
-
-
-def _reset_resident_cohort() -> None:
-    """Test hook: forget the memoized resident-cohort selection."""
-    global _RESIDENT_COHORT
-    _RESIDENT_COHORT = None
+    instead of `scan`? Only when GS_RESIDENT pins it `on`."""
+    return knobs.get_str("GS_RESIDENT") == "on"
 
 
 def resolve_resident_cohort() -> bool:
@@ -175,34 +113,9 @@ def resolve_resident_cohort() -> bool:
     rounds (the resident cohort tier: one donated `[N, ...]` carry
     pytree updated by one super-batch program, restacked only when
     membership changes) instead of restacking per-tenant host-visible
-    carries every dispatch? GS_COHORT_RESIDENT pins (`on`/`off`);
-    unset/`auto` adopts only when committed backend-matched
-    `tenancy_ab` rows with probe `cohort_resident` ALL show exact
-    per-tenant parity and ≥1.05× over per-tenant resident dispatch
-    (the repo-wide measured-adoption policy,
-    ops/triangles.rows_clear_bar). Memoized per process."""
-    global _RESIDENT_COHORT
-    pin = knobs.get_str("GS_COHORT_RESIDENT")
-    if pin == "on":
-        return True
-    if pin == "off":
-        return False
-    if _RESIDENT_COHORT is None:
-        impl = "scan"
-        try:
-            perf = tri_ops._load_matching_perf()
-            rows = [r for r in (perf or {}).get("tenancy_ab", [])
-                    if r.get("probe") == "cohort_resident"]
-            if tri_ops.rows_clear_bar(
-                    rows, "tenant_edges_per_s",
-                    lambda r: r.get("sequential_edges_per_s") or 0):
-                impl = "resident"
-        except Exception as e:
-            telemetry.event("selection.fallback", durable=True,
-                            component="resident_cohort", fallback=impl,
-                            error="%s: %s" % (type(e).__name__, e))
-        _RESIDENT_COHORT = impl
-    return _RESIDENT_COHORT == "resident"
+    carries every dispatch? Only when GS_COHORT_RESIDENT pins it
+    `on`."""
+    return knobs.get_str("GS_COHORT_RESIDENT") == "on"
 
 
 # ----------------------------------------------------------------------

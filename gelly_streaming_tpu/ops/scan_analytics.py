@@ -19,12 +19,12 @@ the carried-state semantics of the reference's continuous aggregates):
   tri_overflow    — hub outran the K bucket (host recounts exactly)
 
 Full per-vertex snapshots remain the driver's job; this engine is the
-CHIP-side throughput path. On CPU backends the driver wins instead —
+CHIP-side throughput path. On CPU backends it gains little —
 measured, not argued (FUSED_BREAKDOWN.json, tools/
 profile_fused_breakdown.py): the triangle stage compiled INTO this
 scan is the XLA stream program, which a single core runs ~15x slower
-than the measurement-selected numpy tier the driver routes through,
-while the dispatch latency fusion saves is ~µs off-chip. One program
+than the numpy host twin, while the dispatch latency fusion saves is
+~µs off-chip. One program
 per chunk pays when dispatches are costly and the MXU/VPU runs the
 intersect — the regime this engine was built for.
 """
@@ -58,8 +58,8 @@ def _build_scan(eb: int, vb: int, kb: int, pallas_ok: bool = True):
     slots.
 
     When the fused Pallas window megakernel is selected
-    (ops/pallas_window.resolve_pallas_window — GS_PALLAS_WINDOW pin or
-    committed parity+≥1.05× `pallas_ab` chip rows) AND its build/trace
+    (ops/pallas_window.resolve_pallas_window — GS_PALLAS_WINDOW=on)
+    AND its build/trace
     probe succeeds, the returned body is the megakernel instead: one
     VMEM-tiled pallas_call per window computing ALL analytics from a
     single HBM read of the edge slab, same carry layout, same
@@ -67,8 +67,8 @@ def _build_scan(eb: int, vb: int, kb: int, pallas_ok: bool = True):
     lets callers whose composition the kernel doesn't support opt
     out — build_cohort_scan's vmap form needs a pure-XLA body (its
     tenant-axis Pallas variant is its own kernel with the tenant axis
-    as a grid dimension, ops/pallas_window.maybe_cohort_body, gated
-    on its own evidence)."""
+    as a grid dimension, ops/pallas_window.maybe_cohort_body, under
+    its own pin)."""
     if pallas_ok:
         from . import pallas_window
 
@@ -132,8 +132,7 @@ def build_cohort_scan(eb: int, vb: int, kb: int, nb: int = None):
       tenant-gridded).
     - when `nb` is given AND the TENANT-AXIS Pallas megakernel
       clears its own gate+probe (ops/pallas_window.maybe_cohort_body
-      — GS_COHORT_PALLAS pin or committed non-interpret
-      `cohort_pallas` rows), the window loop scans ONE pallas_call
+      — GS_COHORT_PALLAS=on), the window loop scans ONE pallas_call
       whose second grid dimension is the tenant axis: the whole
       cohort's carries VMEM-resident, one slab pass per window round.
       Refusal (gate off, VMEM budget, trace probe) degrades to the
@@ -185,9 +184,9 @@ class SummaryEngineBase:
     # override it so /healthz never claims the single-chip scan tier
     # for a demoted or mesh-resident stream
     METRICS_TIER = "fused_scan"
-    # stream-chunk wire format; StreamSummaryEngine resolves it from
-    # committed evidence (tri_ops.resolve_ingress), the sharded engine
-    # keeps the standard format (its chunks are mesh-sharded)
+    # stream-chunk wire format; StreamSummaryEngine takes a compact
+    # pin, the sharded engine keeps the standard format (its chunks
+    # are mesh-sharded)
     ingress = "standard"
     # online dispatch autotuning (ops/autotune.py): only the
     # single-chip engine opts in — the sharded engine's jit programs
@@ -858,14 +857,11 @@ class StreamSummaryEngine(SummaryEngineBase):
         self.vb = seg_ops.bucket_size(vertex_bucket)
         self.kb = seg_ops.bucket_size(
             k_bucket if k_bucket else tri_ops._tuned_kb(self.eb))
-        # compile-size cap on TPU backends, per-PROGRAM: the fused
-        # multi-analytic scan's cap is probed separately
-        # (tri_ops.compile_cap "fused_scan")
+        # compile-size cap on TPU backends (tri_ops.COMPILE_CAP)
         self.MAX_WINDOWS = min(type(self).MAX_WINDOWS,
-                               tri_ops.capped_chunk(self.eb,
-                                                    "fused_scan"))
-        # stream-chunk wire format: same committed-evidence selection
-        # (and explicit-pin/vb-gate semantics) as TriangleWindowKernel
+                               tri_ops.capped_chunk(self.eb))
+        # stream-chunk wire format: standard unless pinned, with the
+        # same vb gate on a compact pin as TriangleWindowKernel
         if ingress == "compact":
             from . import compact_ingress
 
@@ -873,8 +869,7 @@ class StreamSummaryEngine(SummaryEngineBase):
                 raise ValueError(
                     "compact ingress is lossy for vertex_bucket %d "
                     "(ids must fit uint16)" % self.vb)
-        self.ingress = (ingress if ingress
-                        else tri_ops.resolve_ingress(self.vb))
+        self.ingress = ingress or "standard"
         # an explicit pin freezes the wire format for the tuner too
         # (the A/B tools must measure exactly what they pinned)
         self._pinned_ingress = ingress is not None

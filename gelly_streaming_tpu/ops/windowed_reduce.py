@@ -37,64 +37,6 @@ from ..utils import telemetry
 _DIRECTIONS = ("out", "in", "all")
 
 
-_REDUCE_IMPL = {}   # name -> "device" | "host", resolved once per process  # gslint: disable=thread-shared (idempotent memo of committed PERF.json evidence)
-
-
-def _resolve_reduce_impl(name: str, allow_native: bool = True) -> str:
-    """Columnar-reduce tier for monoid `name`: the device segment
-    kernels by default; the vectorized host kernel (flattened
-    one-bincount-per-chunk for sum, ufunc.at otherwise) or the C++
-    fused tier only on committed BACKEND-MATCHED `host_reduce` rows
-    showing parity and a ≥5% win for this name at every measured
-    bucket — the same measured-default policy as
-    `triangles._resolve_stream_impl`. On a CPU backend this is the
-    fallback-floor selection (since r3); on a TPU backend the rows are
-    the chip run's own host-vs-device measurements
-    (tools/profile_kernels.py section_host_reduce runs on the chip's
-    host), so a chip whose per-dispatch latency loses to the host core
-    routes the reduce engine to the measured winner instead of
-    shipping a 0.0x chip row (config #2 must actually win somewhere
-    real)."""
-    key = (name, allow_native)
-    if key in _REDUCE_IMPL:
-        return _REDUCE_IMPL[key]
-    impl = "device"
-    try:
-        import jax as _jax
-
-        from .triangles import _load_matching_perf
-
-        if _jax.default_backend() in ("cpu", "tpu"):
-            perf = _load_matching_perf()
-            rows = [r for r in (perf or {}).get("host_reduce", [])
-                    if r.get("name") == name]
-            if rows and all(r.get("parity") is True
-                            and (r.get("host_edges_per_s") or 0)
-                            >= 1.05 * (r.get("device_edges_per_s") or 0)
-                            for r in rows):
-                impl = "host"
-            # the C++ fused tier (native/ingest.cpp
-            # gs_windowed_reduce) competes under the same rule: parity
-            # + ≥5% over BOTH other tiers at every measured bucket
-            if allow_native and rows \
-                    and all(r.get("native_parity") is True
-                            and (r.get("native_edges_per_s") or 0)
-                            >= 1.05 * max(
-                                r.get("device_edges_per_s") or 0,
-                                r.get("host_edges_per_s") or 0)
-                            for r in rows):
-                from .. import native as _native
-
-                if _native.windowed_reduce_available():
-                    impl = "native"
-    except Exception as e:
-        telemetry.event("selection.fallback", durable=True,
-                        component="windowed_reduce", fallback=impl,
-                        error="%s: %s" % (type(e).__name__, e))
-    _REDUCE_IMPL[key] = impl
-    return impl
-
-
 def _device_cell_fill(name: str, dtype):
     """The DEVICE segment kernels' empty-segment identity — what an
     untouched cell of the full-egress [wb, vbp] stack holds: the XLA
@@ -141,10 +83,7 @@ class WindowedEdgeReduce:
 
     One jitted program per windows-per-dispatch bucket over fixed
     [wb, eb] shapes — steady-state streaming recompiles nothing
-    (the same dispatch economics as TriangleWindowKernel). On a CPU
-    backend with committed winning measurements the monoid tier routes
-    through the vectorized host kernel instead
-    (`_resolve_reduce_impl`; same cells/counts, no dispatches).
+    (the same dispatch economics as TriangleWindowKernel).
     """
 
     MAX_STREAM_WINDOWS = 64
@@ -182,15 +121,11 @@ class WindowedEdgeReduce:
             self.slide = None
         self.vb = seg_ops.bucket_size(vertex_bucket)
         self.eb = seg_ops.bucket_size(edge_bucket)
-        # compile-size cap on TPU backends: its own program class (a
-        # segment-reduce stack, unprobed) so a RAISED triangle cap
-        # never drags this program past the default
-        # (ops/triangles.compile_cap)
+        # compile-size cap on TPU backends (ops/triangles.COMPILE_CAP)
         from . import triangles as _tri
 
         self.MAX_STREAM_WINDOWS = min(
-            type(self).MAX_STREAM_WINDOWS,
-            _tri.capped_chunk(self.eb, "reduce_stack"))
+            type(self).MAX_STREAM_WINDOWS, _tri.capped_chunk(self.eb))
         self.name = name
         self.fn = fn
         self.direction = direction
@@ -198,10 +133,8 @@ class WindowedEdgeReduce:
         # ids + per-window valid counts with the (window, vertex) cell
         # ids computed on device (2×u16 + vals vs host-built int64
         # flat ids — fewer h2d bytes AND the id packing moves off the
-        # single host core). Same committed-evidence selection and
-        # vb gate as TriangleWindowKernel (ops/triangles.
-        # resolve_ingress; standard is the fallback whenever
-        # compact_ingress.supports(vb) is false).
+        # single host core). Standard unless pinned; a compact pin
+        # needs compact_ingress.supports(vb).
         if ingress == "compact":
             from . import compact_ingress
 
@@ -209,14 +142,12 @@ class WindowedEdgeReduce:
                 raise ValueError(
                     "compact ingress is lossy for vertex_bucket %d "
                     "(ids must fit uint16)" % self.vb)
-        self.ingress = (ingress if ingress
-                        else _tri.resolve_ingress(self.vb))
+        self.ingress = ingress or "standard"
         # d2h egress of the monoid DEVICE tier: full [wb, vbp]
         # cells+counts stacks, or the touched-cell delta wire
         # (ops/delta_egress — a window touches at most one cell per
         # contribution, so the [cap]-sized wire is exact, no overflow
-        # path needed). Same pin/evidence selection as the driver's
-        # snapshot egress.
+        # path needed). Same pin as the driver's snapshot egress.
         from . import delta_egress as _de
 
         self.egress = egress if egress else _de.resolve_egress()
@@ -225,8 +156,7 @@ class WindowedEdgeReduce:
         self.stage_timers = _ip.StageTimers()
         self._fns = {}
         # sliding mode: the inner pane engine (this engine at
-        # edge_bucket=slide — every tier decision re-resolves at the
-        # pane bucket) and the tumbling refold twin, built lazily
+        # edge_bucket=slide) and the tumbling refold twin, built lazily
         self.panes_per_window = (self.eb // self.slide
                                  if self.slide else 1)
         self._pane_engine = None
@@ -406,75 +336,37 @@ class WindowedEdgeReduce:
     def process_stream(self, src: np.ndarray, dst: np.ndarray,
                        val: np.ndarray) -> List[Tuple[np.ndarray,
                                                       np.ndarray]]:
-        # Original dtypes go to the native tier (int32 streams take
-        # the copy-free i32 kernels); the int64 upconversion the other
-        # tiers want happens ONLY on their branches — converting
-        # eagerly cost the native path two full-stream copies (~30% of
-        # its runtime at the bench shape) for arrays it never reads.
-        src0, dst0 = np.asarray(src), np.asarray(dst)  # gslint: disable=host-sync (host-input normalization: callers pass numpy/lists, never device values)
+        src = np.asarray(src)  # gslint: disable=host-sync (host-input normalization: callers pass numpy/lists, never device values)
+        dst = np.asarray(dst)  # gslint: disable=host-sync (host-input normalization: callers pass numpy/lists, never device values)
         val = np.asarray(val)  # gslint: disable=host-sync (host-input normalization: callers pass numpy/lists, never device values)
-        assert len(src0) == len(dst0) == len(val)
-        n = len(src0)
+        assert len(src) == len(dst) == len(val)
+        n = len(src)
         if n == 0:
             return []
         if self.slide is not None:
             # sliding: fold each edge into its pane once (the inner
-            # engine at edge_bucket=slide, whatever tier it resolves
-            # to), compose panes per emission on the host — one
-            # (cells, counts) pair per slide-sized emission
-            from ..utils import telemetry as _tm
-
-            with _tm.span("reduce.sliding", monoid=self.name,
-                          edges=n, slide=self.slide,
-                          panes_per_window=self.panes_per_window):
-                panes = self._pane_eng().process_stream(src0, dst0,
-                                                        val)
+            # engine at edge_bucket=slide), compose panes per emission
+            # on the host — one (cells, counts) pair per slide-sized
+            # emission
+            with telemetry.span("reduce.sliding", monoid=self.name,
+                                edges=n, slide=self.slide,
+                                panes_per_window=self.panes_per_window):
+                panes = self._pane_eng().process_stream(src, dst, val)
                 return self._compose_panes(panes)
-        from ..utils import telemetry
-
-        if self.name is not None:
-            impl = _resolve_reduce_impl(self.name)
-            if impl == "native":
-                # the C++ kernel is signed-integer-typed (selection
-                # rows are measured on ints; uint64 identities don't
-                # fit its int64 slabs) — other dtypes re-resolve as if
-                # the native tier didn't exist, so a float stream goes
-                # wherever ITS committed rows point, not blindly to
-                # the numpy tier
-                if np.issubdtype(val.dtype, np.signedinteger):
-                    # stopwatch, not span: a declined probe (None —
-                    # lib unavailable) must record NOTHING, or the
-                    # stream's edges would be double-counted against
-                    # the fallback tier's span in trace_report
-                    sw = telemetry.stopwatch("reduce.stream",
-                                             tier="native",
-                                             monoid=self.name,
-                                             edges=n)
-                    got = self._native_process_stream(src0, dst0, val)
-                    if got is not None:
-                        sw.stop()
-                        return got
-                impl = _resolve_reduce_impl(self.name,
-                                            allow_native=False)
-            if impl == "host":
-                with telemetry.span("reduce.stream", tier="host",
-                                    monoid=self.name, edges=n):
-                    return self._host_process_stream(
-                        src0.astype(np.int64, copy=False),
-                        dst0.astype(np.int64, copy=False), val)
         # device rounds run through the shared ingress pipeline, whose
         # chunk/stage spans nest under this engine-level span
         with telemetry.span("reduce.stream", tier="device",
                             monoid=self.name or "fn", edges=n):
             return self._device_process_stream(
-                src0.astype(np.int64, copy=False),
-                dst0.astype(np.int64, copy=False), val)
+                src.astype(np.int64, copy=False),
+                dst.astype(np.int64, copy=False), val)
 
     def _native_process_stream(self, src, dst, val):
-        """The C++ fused tier: one pass produces both cells and counts
-        (ingest.cpp gs_windowed_reduce), chunked only to bound the
-        dense [num_w, vbp] scratch. Same (cells, counts) per window as
-        the other tiers; cells cast back to the value dtype."""
+        """The C++ fused form, outside process_stream (the profiler and
+        the parity tests call it): one pass produces both cells and
+        counts (ingest.cpp gs_windowed_reduce), chunked only to bound
+        the dense [num_w, vbp] scratch. Same (cells, counts) per window
+        as the device path; cells cast back to the value dtype."""
         from .. import native as native_mod
 
         if not native_mod.windowed_reduce_available():
@@ -500,8 +392,7 @@ class WindowedEdgeReduce:
         return out
 
     def _device_process_stream(self, src, dst, val):
-        """The device path, selection bypassed (the profiler measures
-        both tiers through this split). Monoid chunks route through
+        """The device path of process_stream. Monoid chunks route through
         the shared three-stage ingress pipeline
         (ops/ingress_pipeline): cell-id/stack prep on the worker
         pool, h2d + dispatch in chunk order, each chunk's d2h one
@@ -687,8 +578,8 @@ class WindowedEdgeReduce:
     # ---- host (numpy) tier -------------------------------------------
 
     def _host_process_stream(self, src, dst, val):
-        """Vectorized host form of the monoid tiers, selection bypassed
-        (the profiler measures both tiers through this split): one
+        """Vectorized host form of the monoid reduce, outside
+        process_stream (the profiler and the parity tests call it): one
         flattened (window, vertex)-cell bincount per chunk for 'sum'
         (falling back to exact ufunc.at when float64 accumulation
         could round an integer sum), ufunc.at for 'min'/'max'. Same

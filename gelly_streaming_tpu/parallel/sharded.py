@@ -176,10 +176,7 @@ def make_sharded_triangle_fn(mesh):
     edge list sharded across chips and the sorted-adjacency matrix
     replicated; per-shard intersection partials reduce with one psum."""
 
-    # NOT resolve_intersect_impl(): pl.pallas_call inside shard_map
-    # fails jax 0.9's check_vma at trace time (vma=None on the
-    # out_shape), so sharded bodies use the XLA-only selection
-    # (compare on chip, binary search on CPU meshes)
+    # compare on chip, binary search on CPU meshes
     intersect = triangles.resolve_xla_intersect()
 
     @functools.partial(
@@ -476,13 +473,11 @@ def build_sharded_window_counter(n: int, eb: int, vb: int, kb: int,
     assert eb % n == 0 and kb % n == 0, (eb, kb, n)
     sent = vb
     kslice = kb // n
-    # Pinned to the broadcast compare. Not resolve_intersect_impl():
-    # pallas_call in shard_map trips check_vma. Not
-    # resolve_xla_intersect() either: the nbr table below is assembled
-    # as per-shard kslice column runs merged by pmax, so each row is a
-    # CONCATENATION of sorted runs, not globally sorted — the binary
-    # search's searchsorted contract doesn't hold (the equality compare
-    # doesn't care about order).
+    # Pinned to the broadcast compare, not resolve_xla_intersect():
+    # the nbr table below is assembled as per-shard kslice column runs
+    # merged by pmax, so each row is a CONCATENATION of sorted runs,
+    # not globally sorted — the binary search's searchsorted contract
+    # doesn't hold (the equality compare doesn't care about order).
     intersect = triangles.intersect_local
 
     def step(src, dst, valid):
@@ -1180,8 +1175,7 @@ class ShardedSummaryEngine(scan_analytics.SummaryEngineBase):
         # same multi-analytic scan program class (the PER-DEVICE slice
         # is eb/n, but conservative is cheap here)
         self.MAX_WINDOWS = min(type(self).MAX_WINDOWS,
-                               triangles.capped_chunk(self.eb,
-                                                      "fused_scan"))
+                               triangles.capped_chunk(self.eb))
         self._run = make_sharded_summary_scan(
             mesh, self.eb, self.vb, self._tri.kb, self._tri.cap,
             table=self._tri.table)

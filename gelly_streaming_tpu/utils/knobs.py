@@ -228,8 +228,8 @@ register("GS_MESH_WIRE_CHECK", "bool", False,
 register("GS_AUTOTUNE", "bool", True,
          help="`0` disables the online dispatch scheduler "
               "(`ops/autotune.py`): windows-per-dispatch / K / "
-              "ingress then run today's static committed-evidence "
-              "gates bit-identically; on, the tuner ε-greedily "
+              "ingress then run their static defaults "
+              "bit-identically; on, the tuner ε-greedily "
               "(deterministically, with 1.05× hysteresis) finds the "
               "fast configuration on the live stream")
 register("GS_AUTOTUNE_ROUND", "int", 4, lo=1,
@@ -245,31 +245,26 @@ register("GS_TUNE_CACHE", "path", None,
          default_text="`~/.cache/gelly_streaming_tpu`")
 
 # resident-state tier (ops/resident_engine.py)
-register("GS_RESIDENT", "str", "", choices=("on", "off", "auto"),
+register("GS_RESIDENT", "str", "", choices=("on", "off"),
          help="pin the resident-state snapshot tier "
-              "(`ops/resident_engine.py`): `on` forces it, `off` "
-              "never selects it; unset/`auto` = adopt only on "
-              "committed parity+≥5% `resident_ab` rows over the best "
-              "committed alternative tier",
-         default_text="auto")
+              "(`ops/resident_engine.py`): `on` selects it; unset or "
+              "`off` = the scan tier",
+         default_text="off")
 register("GS_RESIDENT_SPB", "int", 256, lo=1,
          help="windows per super-batch of the resident megakernel "
               "(one donated dispatch folds this many windows; "
-              "compile-size-capped per program on TPU backends)")
+              "compile-size-capped on TPU backends)")
 register("GS_RESIDENT_SLOTS", "int", 2, lo=1,
          help="ingest-ring depth of the resident tier: super-batches "
               "prepped+transferred ahead of dispatch (2 = the "
               "double-buffered form — slot N+1 fills while N computes)")
 
 # fused window megakernel (ops/pallas_window.py)
-register("GS_PALLAS_WINDOW", "str", "", choices=("on", "off", "auto"),
+register("GS_PALLAS_WINDOW", "str", "", choices=("on", "off"),
          help="pin the fused Pallas window megakernel "
-              "(`ops/pallas_window.py`): `on` forces it (interpret "
-              "mode off-TPU), `off` never selects it; unset/`auto` = "
-              "adopt only on committed parity+≥1.05× `pallas_ab` "
-              "rows — the XLA fused scan stands until a chip row "
-              "lands",
-         default_text="auto")
+              "(`ops/pallas_window.py`): `on` selects it (interpret "
+              "mode off-TPU); unset or `off` = the XLA fused scan",
+         default_text="off")
 register("GS_PALLAS_TILE", "int", 0, lo=0,
          help="pin the megakernel's edge-tile size (edges per grid "
               "step, power of two ≤ edge_bucket); 0 (default) = the "
@@ -284,12 +279,11 @@ register("GS_PALLAS_CK", "int", 0, lo=0,
          default_text="0 (auto)")
 
 # egress (ops/delta_egress.py)
-register("GS_EGRESS", "str", "", choices=("full", "delta", "auto"),
+register("GS_EGRESS", "str", "", choices=("full", "delta"),
          help="pin the batched d2h egress: `full` (whole snapshot "
               "vectors) or `delta` (per-window changed-slot wire, "
-              "`ops/delta_egress.py`); unset/`auto` = adopt delta "
-              "only on committed parity+≥5% `egress_ab` rows",
-         default_text="auto")
+              "`ops/delta_egress.py`); unset = `full`",
+         default_text="full")
 register("GS_EGRESS_CAP", "int", None, lo=1,
          help="per-window changed-slot capacity of the delta wire; a "
               "window that overflows it refolds its chunk on the "
@@ -378,25 +372,20 @@ register("GS_TENANT_TPD", "int", 0, lo=0,
               "tenants-per-dispatch arm choose (all ready tenants in "
               "one vmapped dispatch with GS_AUTOTUNE=0)",
          default_text="0 (auto)")
-register("GS_COHORT_RESIDENT", "str", "", choices=("on", "off", "auto"),
+register("GS_COHORT_RESIDENT", "str", "", choices=("on", "off"),
          help="pin the resident cohort tier (`core/tenancy.py`): a "
               "donated `[N, ...]` stacked-carry super-batch program "
               "per cohort instead of restacking carries every round; "
-              "`on` forces it, `off` never selects it; unset/`auto` "
-              "= adopt only on committed parity+≥5% "
-              "`tenancy_ab`/`cohort_resident` rows over per-tenant "
-              "resident dispatch",
-         default_text="auto")
-register("GS_COHORT_PALLAS", "str", "", choices=("on", "off", "auto"),
+              "`on` selects it; unset or `off` = per-round restacking",
+         default_text="off")
+register("GS_COHORT_PALLAS", "str", "", choices=("on", "off"),
          help="pin the tenant-axis Pallas cohort megakernel "
               "(`ops/pallas_window.py`): one `pallas_call` with the "
               "tenant axis as a second grid dimension serves the "
-              "whole cohort from VMEM; `on` forces it (interpret "
-              "mode off-TPU), `off` never selects it; unset/`auto` = "
-              "adopt only on committed non-interpret parity+≥1.05× "
-              "`tenancy_ab`/`cohort_pallas` rows — the vmapped XLA "
-              "cohort scan stands until a chip row lands",
-         default_text="auto")
+              "whole cohort from VMEM; `on` selects it (interpret "
+              "mode off-TPU); unset or `off` = the vmapped XLA "
+              "cohort scan",
+         default_text="off")
 
 # durable serving front-end (utils/wal.py + core/serve.py)
 register("GS_WAL", "bool", True,
@@ -584,15 +573,12 @@ register("GS_GNN_ACT", "str", "relu", choices=("relu", "abs",
               "EXACT elementwise ops (relu/abs/identity) so the "
               "numpy twin stays a bit-exactness oracle; read at "
               "engine construction")
-register("GS_GNN_PALLAS", "str", "", choices=("on", "off", "auto"),
+register("GS_GNN_PALLAS", "str", "", choices=("on", "off"),
          help="pin the fused Pallas GNN window kernel "
-              "(`ops/pallas_window.maybe_gnn_body`): `on` forces it "
-              "(interpret mode off-TPU), `off` never selects it; "
-              "unset/`auto` = adopt only on committed parity+≥1.05× "
-              "non-interpret `gnn_ab` rows with probe `gnn_pallas` "
-              "— the XLA gather/segment-sum body stands until a "
-              "chip row lands",
-         default_text="auto")
+              "(`ops/pallas_window.maybe_gnn_body`): `on` selects it "
+              "(interpret mode off-TPU); unset or `off` = the XLA "
+              "gather/segment-sum body",
+         default_text="off")
 
 # tenant observatory (utils/provenance.py, per-tenant attribution)
 register("GS_PROVENANCE", "bool", False,

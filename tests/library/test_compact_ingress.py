@@ -102,20 +102,12 @@ def test_compact_pin_rejects_wide_vertex_bucket():
                              ingress="compact")
 
 
-def test_compact_stream_counts_match_device_path(monkeypatch):
+def test_compact_stream_counts_match_device_path():
     """End-to-end: the compact program's counts == the standard device
     path's counts == the escalating per-window kernel, on a stream
     sized to produce ragged tails and nonzero triangles."""
-    from gelly_streaming_tpu.ops import triangles as tri_mod
-
-    # pin the device tier: count_windows must exercise the compact
-    # DEVICE path even where committed CPU evidence selects a host tier
-    monkeypatch.setattr(tri_mod, "_STREAM_IMPL", "device")
-
     vb, eb, n = 128, 256, 2400  # 10 windows with a 96-edge ragged tail
     src, dst = _stream(n, vb, seed=21)
-    # the baseline is PINNED standard: committed winning ingress_ab
-    # rows must not silently turn this into compact-vs-compact
     kernel = TriangleWindowKernel(edge_bucket=eb, vertex_bucket=vb,
                                   ingress="standard")
     std = kernel._count_stream_device(src, dst)
@@ -186,48 +178,31 @@ def test_compact_parity_at_vb_65536_boundary():
     assert sum(want) > 0
 
 
-def test_vb_gate_falls_back_to_standard_everywhere(tmp_path,
-                                                   monkeypatch):
-    """With committed WINNING ingress_ab rows, every engine adopts
-    compact — except when supports(vb) is false (vb > 65536), where
-    each resolves standard instead of wrapping ids."""
-    import json
-
-    import jax
-
-    from gelly_streaming_tpu.ops import triangles as tri_mod
+def test_vb_gate_falls_back_to_standard_everywhere():
+    """Every engine runs standard ingress unless pinned; a compact pin
+    is honoured where ids fit uint16 (supports(vb)) and is an ERROR
+    past the gate (vb > 65536) instead of wrapping ids."""
     from gelly_streaming_tpu.ops.scan_analytics import (
         StreamSummaryEngine)
     from gelly_streaming_tpu.ops.windowed_reduce import (
         WindowedEdgeReduce)
 
-    perf = tmp_path / "PERF.json"
-    perf.write_text(json.dumps({
-        "backend": jax.default_backend(),
-        "ingress_ab": [{"probe": "stream_ab", "parity": True,
-                        "speedup": 1.5}]}))
-    monkeypatch.setattr(tri_mod, "_PERF_PATH", str(perf))
-    monkeypatch.setattr(tri_mod, "_INGRESS", None)
-    try:
-        small = dict(edge_bucket=64, vertex_bucket=256)
-        big = dict(edge_bucket=64, vertex_bucket=1 << 17)
-        assert TriangleWindowKernel(**small).ingress == "compact"
-        assert TriangleWindowKernel(**big).ingress == "standard"
-        assert StreamSummaryEngine(**small).ingress == "compact"
-        assert StreamSummaryEngine(**big).ingress == "standard"
-        assert WindowedEdgeReduce(vertex_bucket=256,
-                                  edge_bucket=64).ingress == "compact"
-        assert WindowedEdgeReduce(vertex_bucket=1 << 17,
-                                  edge_bucket=64).ingress == "standard"
-        # an explicit compact pin past the gate is an ERROR everywhere
-        with pytest.raises(ValueError):
-            StreamSummaryEngine(ingress="compact", **big)
-        with pytest.raises(ValueError):
-            WindowedEdgeReduce(vertex_bucket=1 << 17, edge_bucket=64,
-                               ingress="compact")
-    finally:
-        monkeypatch.undo()
-        tri_mod._INGRESS = None
+    small = dict(edge_bucket=64, vertex_bucket=256)
+    big = dict(edge_bucket=64, vertex_bucket=1 << 17)
+    for kw in (small, big):
+        assert TriangleWindowKernel(**kw).ingress == "standard"
+        assert StreamSummaryEngine(**kw).ingress == "standard"
+        assert WindowedEdgeReduce(**kw).ingress == "standard"
+    assert TriangleWindowKernel(ingress="compact",
+                                **small).ingress == "compact"
+    assert StreamSummaryEngine(ingress="compact",
+                               **small).ingress == "compact"
+    assert WindowedEdgeReduce(ingress="compact",
+                              **small).ingress == "compact"
+    with pytest.raises(ValueError):
+        StreamSummaryEngine(ingress="compact", **big)
+    with pytest.raises(ValueError):
+        WindowedEdgeReduce(ingress="compact", **big)
 
 
 def test_compact_reduce_rejects_out_of_range_ids():

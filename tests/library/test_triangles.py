@@ -101,14 +101,8 @@ def test_cpu_backend_selects_binary_search_intersect():
     import jax.numpy as jnp
 
     assert jax.default_backend() == "cpu"  # conftest pins the backend
-    tri_ops._INTERSECT_CHOICE = None       # force re-resolution
-    try:
-        assert (tri_ops.resolve_intersect_impl()
-                is tri_ops.intersect_local_bsearch)
-        assert (tri_ops.resolve_xla_intersect()
-                is tri_ops.intersect_local_bsearch)
-    finally:
-        tri_ops._INTERSECT_CHOICE = None
+    assert (tri_ops.resolve_xla_intersect()
+            is tri_ops.intersect_local_bsearch)
     rng = np.random.default_rng(5)
     vb, k, ep = 128, 64, 512
     # rows exactly as the builder lays them out: unique ascending
@@ -259,128 +253,6 @@ def test_escalation_ladder_widens_to_kmax():
     assert all(b > a for a, b in zip(ladder, ladder[1:]))
 
 
-def test_dense_choice_is_measurement_driven(tmp_path, monkeypatch):
-    """triangle_count's dense path comes from committed PERF.json
-    on-chip measurements: XLA by default (and always off-TPU), Pallas
-    only when the measurements were taken on a TPU and every measured
-    V shows parity-checked speedup ≥1.05."""
-    import json
-    import sys
-
-    # import BEFORE jax is monkeypatched: pallas_intersect's own
-    # module-level jax imports must resolve against the real jax
-    from gelly_streaming_tpu.ops.pallas_intersect import \
-        intersect_local_pallas
-
-    # off-TPU (this CI): always XLA at the standard limit
-    tri_ops._DENSE_CHOICE = None
-    assert tri_ops._resolve_dense_choice() == ("xla", tri_ops.DENSE_LIMIT)
-
-    # fake a TPU backend + measurements in an isolated file
-    class _FakeJax:
-        @staticmethod
-        def default_backend():
-            return "tpu"
-
-    perf_path = str(tmp_path / "PERF.json")
-    monkeypatch.setattr(tri_ops, "_PERF_PATH", perf_path)
-    monkeypatch.setitem(sys.modules, "jax", _FakeJax)
-    try:
-        with open(perf_path, "w") as f:
-            json.dump({"backend": "tpu",
-                       "dense": [{"v": 1024, "pallas_speedup": 1.4},
-                                 {"v": 2048, "pallas_speedup": 1.2}]}, f)
-        tri_ops._DENSE_CHOICE = None
-        assert tri_ops._resolve_dense_choice() == (
-            "pallas", 2 * tri_ops.DENSE_LIMIT)
-
-        # one losing size vetoes the switch
-        with open(perf_path, "w") as f:
-            json.dump({"backend": "tpu",
-                       "dense": [{"v": 1024, "pallas_speedup": 1.4},
-                                 {"v": 2048, "pallas_speedup": 0.9}]}, f)
-        tri_ops._DENSE_CHOICE = None
-        assert tri_ops._resolve_dense_choice() == (
-            "xla", tri_ops.DENSE_LIMIT)
-
-        # measurements recorded on a CPU backend never flip the default
-        with open(perf_path, "w") as f:
-            json.dump({"backend": "cpu",
-                       "dense": [{"v": 1024, "pallas_speedup": 9.9}]}, f)
-        tri_ops._DENSE_CHOICE = None
-        assert tri_ops._resolve_dense_choice() == (
-            "xla", tri_ops.DENSE_LIMIT)
-
-        # intersect selection: same policy (parity + >=1.05 on tpu)
-        with open(perf_path, "w") as f:
-            json.dump({"backend": "tpu",
-                       "intersect": {"parity_pallas": True,
-                                     "pallas_vs_xla_compare": 1.3}}, f)
-        tri_ops._INTERSECT_CHOICE = None
-        assert tri_ops.resolve_intersect_impl() is intersect_local_pallas
-        with open(perf_path, "w") as f:
-            json.dump({"backend": "tpu",
-                       "intersect": {"parity_pallas": True,
-                                     "pallas_vs_xla_compare": 0.8}}, f)
-        tri_ops._INTERSECT_CHOICE = None
-        assert tri_ops.resolve_intersect_impl() is tri_ops.intersect_local
-        # tuned K: the fastest MEASURED sweep entry wins outright (its
-        # per_window_ms already includes that K's recount cost); rows
-        # for other edge buckets are ignored
-        with open(perf_path, "w") as f:
-            json.dump({"backend": "tpu", "window": [
-                {"edge_bucket": 4096, "k_sweep": [
-                    {"k_bucket": 32, "per_window_ms": 2.0,
-                     "overflow_recounts_per_run": 0},
-                    {"k_bucket": 64, "per_window_ms": 5.0,
-                     "overflow_recounts_per_run": 0},
-                    {"k_bucket": 16, "per_window_ms": 1.0,
-                     "overflow_recounts_per_run": 3}]}]}, f)
-        tri_ops._TUNED_KB.clear()
-        assert tri_ops._tuned_kb(4096) == 16
-        assert tri_ops._tuned_kb(8192) == min(
-            128, 2 * int(np.sqrt(8192)))  # unmeasured bucket: heuristic
-
-        # K tuning is backend-MATCHED: a cpu-labeled sweep never tunes
-        # a (fake-)tpu process
-        with open(perf_path, "w") as f:
-            json.dump({"backend": "cpu", "window": [
-                {"edge_bucket": 4096, "k_sweep": [
-                    {"k_bucket": 16, "per_window_ms": 1.0,
-                     "overflow_recounts_per_run": 0}]}]}, f)
-        tri_ops._TUNED_KB.clear()
-        assert tri_ops._tuned_kb(4096) == min(
-            128, 2 * int(np.sqrt(4096)))
-    finally:
-        tri_ops._DENSE_CHOICE = None
-        tri_ops._INTERSECT_CHOICE = None
-        tri_ops._INTERSECT_JIT = None
-        tri_ops._TUNED_KB.clear()
-
-
-def test_tuned_kb_uses_cpu_sweep_on_cpu_backend(tmp_path, monkeypatch):
-    """The real backend here IS cpu: a cpu-labeled committed sweep
-    drives K selection (the CPU-fallback speedup path)."""
-    import json
-
-    import jax
-
-    if jax.default_backend() != "cpu":
-        pytest.skip("needs a real cpu backend (conftest pins one)")
-    perf_path = str(tmp_path / "PERF.json")
-    monkeypatch.setattr(tri_ops, "_PERF_PATH", perf_path)
-    with open(perf_path, "w") as f:
-        json.dump({"backend": "cpu", "window": [
-            {"edge_bucket": 4096, "k_sweep": [
-                {"k_bucket": 16, "per_window_ms": 1.0,
-                 "overflow_recounts_per_run": 0}]}]}, f)
-    tri_ops._TUNED_KB.clear()
-    try:
-        assert tri_ops._tuned_kb(4096) == 16
-    finally:
-        tri_ops._TUNED_KB.clear()
-
-
 def test_kernels_empty_and_tiny():
     assert tri_ops.triangle_count_sparse(np.array([]), np.array([]), 0) == 0
     assert tri_ops.triangle_count_dense(np.array([0]), np.array([1]), 2) == 0
@@ -459,8 +331,7 @@ def test_host_window_count_vs_brute_force(seed):
 def test_host_count_stream_matches_device_kernel():
     """Same window boundaries, same exact counts as
     TriangleWindowKernel._count_stream_device on a skewed stream with
-    duplicates — the parity contract `host_stream` selection rows
-    assert before the tier can ever win."""
+    duplicates — the parity contract of the host twin."""
     from gelly_streaming_tpu.ops import host_triangles
     from gelly_streaming_tpu.ops.triangles import TriangleWindowKernel
 
@@ -495,38 +366,6 @@ def test_host_window_count_wedge_chunking():
         assert host_triangles.window_count(src, dst) == want
     finally:
         host_triangles._WEDGE_CHUNK = orig
-
-
-def test_host_tier_selected_end_to_end(tmp_path, monkeypatch):
-    """With committed winning cpu rows, TriangleWindowKernel routes
-    count_stream/count_windows through the numpy tier (and warms
-    nothing)."""
-    import json
-
-    monkeypatch.setattr(tri_ops, "_PERF_PATH",
-                        str(tmp_path / "PERF.json"))
-    monkeypatch.setattr(tri_ops, "_STREAM_IMPL", None)
-    (tmp_path / "PERF.json").write_text(json.dumps({
-        "backend": "cpu",
-        "host_stream": [{"edge_bucket": 8192, "parity": True,
-                         "host_edges_per_s": 2_000_000,
-                         "device_edges_per_s": 800_000}]}))
-    try:
-        kern = tri_ops.TriangleWindowKernel(edge_bucket=512,
-                                            vertex_bucket=256)
-        rng = np.random.default_rng(3)
-        src = rng.integers(0, 256, 1024).astype(np.int32)
-        dst = rng.integers(0, 256, 1024).astype(np.int32)
-        got = kern.count_stream(src, dst)
-        # the selected tier compiled nothing
-        assert not kern._stream_execs
-        assert got == kern._count_stream_device(src, dst)
-        execs_before = dict(kern._stream_execs)
-        kern.warm_chunks()   # must be a no-op, not a compile storm
-        assert kern._stream_execs == execs_before
-    finally:
-        monkeypatch.undo()
-        tri_ops._STREAM_IMPL = None
 
 
 # ----------------------------------------------------------------------
@@ -576,49 +415,14 @@ def test_native_count_stream_matches_both_tiers():
             == host_triangles.count_stream(src, dst, eb))
 
 
-@needs_native
-def test_native_tier_selected_end_to_end(tmp_path, monkeypatch):
-    """Committed rows where the native tier wins everywhere route
-    count_stream AND count_windows through C++ (no compiles)."""
-    import json
-
-    monkeypatch.setattr(tri_ops, "_PERF_PATH",
-                        str(tmp_path / "PERF.json"))
-    monkeypatch.setattr(tri_ops, "_STREAM_IMPL", None)
-    (tmp_path / "PERF.json").write_text(json.dumps({
-        "backend": "cpu",
-        "host_stream": [{"edge_bucket": 8192, "parity": True,
-                         "host_edges_per_s": 2_000_000,
-                         "device_edges_per_s": 800_000,
-                         "native_parity": True,
-                         "native_edges_per_s": 6_000_000}]}))
-    try:
-        assert tri_ops._resolve_stream_impl() == "native"
-        kern = tri_ops.TriangleWindowKernel(edge_bucket=512,
-                                            vertex_bucket=256)
-        rng = np.random.default_rng(3)
-        src = rng.integers(0, 256, 1024).astype(np.int32)
-        dst = rng.integers(0, 256, 1024).astype(np.int32)
-        got = kern.count_stream(src, dst)
-        assert not kern._stream_execs          # nothing compiled
-        assert got == kern._count_stream_device(src, dst)
-        wins = [(src[:300], dst[:300]), (src[300:800], dst[300:800])]
-        assert (kern.count_windows(wins)
-                == [kern.count(*w) for w in wins])
-    finally:
-        monkeypatch.undo()
-        tri_ops._STREAM_IMPL = None
-
-
 def test_stream_prefetch_parity_and_error_propagation(monkeypatch):
     """The producer-thread prefetch path (default) and the
     single-threaded form (GS_STREAM_PREFETCH=0) return identical
     counts in window order; a prep failure mid-stream surfaces as the
     original exception, not a hang or a truncated result."""
     # ingress pinned standard: the hand-built bad_chunk below fabricates
-    # STANDARD-format stacks, and committed winning ingress_ab rows
-    # would otherwise resolve the kernel compact (this test pins the
-    # pipeline loop's contract, not the wire-format selection)
+    # STANDARD-format stacks (this test pins the pipeline loop's
+    # contract, not the wire-format selection)
     kern = tri_ops.TriangleWindowKernel(edge_bucket=256,
                                        vertex_bucket=128,
                                        ingress="standard")

@@ -123,8 +123,6 @@ def test_native_reduce_tier_matches_numpy(direction, name):
     identical (cells, counts) to the numpy tier on ragged, skewed,
     duplicate-heavy streams — both the i32 fast path and the i64
     form."""
-    from gelly_streaming_tpu.ops import windowed_reduce as wr
-
     rng = np.random.default_rng(47)
     n, nv, eb = 9_500, 700, 1024
     src = (rng.zipf(1.4, n) % nv).astype(np.int64)
@@ -143,50 +141,6 @@ def test_native_reduce_tier_matches_numpy(direction, name):
             np.testing.assert_array_equal(
                 gc[occ] if name != "sum" else gc,
                 wc[occ] if name != "sum" else wc)
-
-
-@needs_native_reduce
-def test_native_reduce_selected_end_to_end(tmp_path, monkeypatch):
-    """Committed rows where the native tier wins route process_stream
-    through C++ for integer values (and keep numpy for floats)."""
-    import json
-
-    from gelly_streaming_tpu.ops import triangles as tri_ops
-    from gelly_streaming_tpu.ops import windowed_reduce as wr
-
-    monkeypatch.setattr(tri_ops, "_PERF_PATH",
-                        str(tmp_path / "PERF.json"))
-    monkeypatch.setattr(wr, "_REDUCE_IMPL", {})
-    (tmp_path / "PERF.json").write_text(json.dumps({
-        "backend": "cpu",
-        "host_reduce": [{"name": "sum", "edge_bucket": 8192,
-                         "parity": True,
-                         "host_edges_per_s": 60_000_000,
-                         "device_edges_per_s": 20_000_000,
-                         "native_parity": True,
-                         "native_edges_per_s": 120_000_000}]}))
-    try:
-        assert wr._resolve_reduce_impl("sum") == "native"
-        rng = np.random.default_rng(3)
-        src = rng.integers(0, 100, 3000).astype(np.int32)
-        dst = rng.integers(0, 100, 3000).astype(np.int32)
-        val = rng.integers(1, 50, 3000).astype(np.int32)
-        eng = WindowedEdgeReduce(vertex_bucket=128, edge_bucket=512,
-                                 name="sum", direction="all")
-        got = eng.process_stream(src, dst, val)
-        want = numpy_reference(src, dst, val, 512, "all", "sum")
-        for (gc, gn), (wc, wn) in zip(got, want):
-            np.testing.assert_array_equal(gc[:100], wc[:100])
-            np.testing.assert_array_equal(gn[:100], wn[:100])
-        # float values: numpy tier stands in transparently
-        fval = val.astype(np.float32)
-        gotf = eng.process_stream(src, dst, fval)
-        wantf = numpy_reference(src, dst, fval, 512, "all", "sum")
-        for (gc, gn), (wc, wn) in zip(gotf, wantf):
-            np.testing.assert_allclose(gc[:100], wc[:100])
-    finally:
-        monkeypatch.undo()
-        wr._REDUCE_IMPL.clear()
 
 
 @needs_native_reduce
@@ -296,44 +250,3 @@ def test_window_chunking_boundaries():
     for (gc, gn), (wc, wn) in zip(got, want):
         np.testing.assert_array_equal(gc[:nv], wc)
         np.testing.assert_array_equal(gn[:nv], wn)
-
-
-@needs_native_reduce
-def test_reduce_tier_chip_routing_on_chip_labeled_rows(tmp_path,
-                                                       monkeypatch):
-    """A TPU-backend process consults chip-labeled host_reduce rows
-    (the chip run's section measures the chip host's tiers): winning
-    rows route the engine off the device path; cpu-labeled rows never
-    do."""
-    import json
-
-    import jax
-
-    from gelly_streaming_tpu.ops import triangles as tri_ops
-    from gelly_streaming_tpu.ops import windowed_reduce as wr
-
-    perf = tmp_path / "PERF.json"
-    monkeypatch.setattr(tri_ops, "_PERF_PATH", str(perf))
-    monkeypatch.setattr(wr, "_REDUCE_IMPL", {})
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rows = [{"name": "sum", "edge_bucket": 8192, "parity": True,
-             "host_edges_per_s": 60_000_000,
-             "device_edges_per_s": 200_000,
-             "native_parity": True,
-             "native_edges_per_s": 120_000_000}]
-    try:
-        perf.write_text(json.dumps(
-            {"backend": "tpu", "host_reduce": rows}))
-        assert wr._resolve_reduce_impl("sum") == "native"
-        assert wr._resolve_reduce_impl(
-            "sum", allow_native=False) == "host"
-        # unmeasured monoid keeps the device path
-        assert wr._resolve_reduce_impl("min") == "device"
-        # the same rows labeled cpu must not drive a chip process
-        wr._REDUCE_IMPL.clear()
-        perf.write_text(json.dumps(
-            {"backend": "cpu", "host_reduce": rows}))
-        assert wr._resolve_reduce_impl("sum") == "device"
-    finally:
-        monkeypatch.undo()
-        wr._REDUCE_IMPL.clear()

@@ -3,7 +3,6 @@ full-vector egress window-by-window across tiers, through the
 cap-overflow host refold, a mid-stream tier demotion, and a
 checkpoint kill→resume; plus the resolve_egress adoption gate."""
 
-import os
 
 import numpy as np
 import pytest
@@ -19,9 +18,6 @@ def _clean_env(monkeypatch):
     monkeypatch.setenv("GS_AUTOTUNE", "0")  # egress in isolation
     monkeypatch.delenv("GS_EGRESS", raising=False)
     monkeypatch.delenv("GS_EGRESS_CAP", raising=False)
-    delta_egress._reset_egress()
-    yield
-    delta_egress._reset_egress()
 
 
 def _stream(n=6144, v=700, seed=5):
@@ -189,36 +185,13 @@ def test_reduce_delta_equals_full(name, direction):
 
 
 # ----------------------------------------------------------------------
-# the adoption gate
+# the egress pin
 # ----------------------------------------------------------------------
 def test_resolve_egress_defaults_full_and_honors_pin(monkeypatch):
-    delta_egress._reset_egress()
-    assert delta_egress.resolve_egress() in ("full", "delta")
+    assert delta_egress.resolve_egress() == "full"
     monkeypatch.setenv("GS_EGRESS", "delta")
     assert delta_egress.resolve_egress() == "delta"
     monkeypatch.setenv("GS_EGRESS", "full")
-    assert delta_egress.resolve_egress() == "full"
-
-
-def test_resolve_egress_requires_clearing_rows(monkeypatch):
-    from gelly_streaming_tpu.ops import triangles as tri_ops
-
-    def fake_perf(rows):
-        return lambda *a, **k: {"egress_ab": rows}
-
-    delta_egress._reset_egress()
-    monkeypatch.setattr(tri_ops, "_load_matching_perf", fake_perf([
-        {"probe": "driver_ab", "parity": True, "speedup": 1.2},
-        {"probe": "reduce_ab", "parity": True, "speedup": 1.07}]))
-    assert delta_egress.resolve_egress() == "delta"
-    delta_egress._reset_egress()
-    monkeypatch.setattr(tri_ops, "_load_matching_perf", fake_perf([
-        {"probe": "driver_ab", "parity": True, "speedup": 1.2},
-        {"probe": "reduce_ab", "parity": True, "speedup": 1.02}]))
-    assert delta_egress.resolve_egress() == "full"
-    delta_egress._reset_egress()
-    monkeypatch.setattr(tri_ops, "_load_matching_perf", fake_perf([
-        {"probe": "driver_ab", "parity": False, "speedup": 9.9}]))
     assert delta_egress.resolve_egress() == "full"
 
 

@@ -5,7 +5,7 @@ lattice snapping helpers, kill→resume through checkpoint + WAL
 (gnn→gnn and the gnn→host demotion hand-off), the vmapped tenant
 cohort at N ∈ {1, 3, 8} vs sequential engines, the fused Pallas GNN
 kernel (interpret parity, VMEM-refusal fallback event, the
-GS_GNN_PALLAS evidence gate), the analytic cost-model registration
+GS_GNN_PALLAS pin), the analytic cost-model registration
 (the repo's first MXU-class intensity rows), and the disarmed-default
 digest pin."""
 
@@ -18,7 +18,6 @@ import pytest
 from gelly_streaming_tpu.core.tenancy import GnnTenantCohort
 from gelly_streaming_tpu.ops import gnn_window as gw
 from gelly_streaming_tpu.ops import pallas_window as pw
-from gelly_streaming_tpu.ops import triangles as tri_ops
 from gelly_streaming_tpu.utils import faults, resilience, telemetry
 
 
@@ -363,23 +362,7 @@ def test_resolve_gnn_pallas_pins_and_evidence(monkeypatch):
     monkeypatch.setenv("GS_GNN_PALLAS", "off")
     assert pw.resolve_gnn_pallas() is False
     monkeypatch.delenv("GS_GNN_PALLAS")
-
-    def fake_perf(rows):
-        return lambda *a, **k: {"gnn_ab": rows}
-
-    winning = [{"probe": "gnn_pallas", "parity": True,
-                "speedup": 1.3}]
-    losing = [{"probe": "gnn_pallas", "parity": True,
-               "speedup": 1.01}]
-    interp = [{"probe": "gnn_pallas", "parity": True,
-               "speedup": 2.0, "interpret": True}]
-    for rows, want in ((winning, True), (losing, False),
-                       (interp, False), ([], False)):
-        monkeypatch.setattr(tri_ops, "_load_matching_perf",
-                            fake_perf(rows))
-        pw._reset_pallas_window()
-        assert pw.resolve_gnn_pallas() is want, rows
-    pw._reset_pallas_window()
+    assert pw.resolve_gnn_pallas() is False
 
 
 # ----------------------------------------------------------------------

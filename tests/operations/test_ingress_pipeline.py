@@ -6,7 +6,6 @@ parallel interning scheme's exact slot parity — the parametrized
 extension of test_iter_edge_chunks_prefetch_matches_sync to the whole
 ingress layer."""
 
-import os
 
 import numpy as np
 import pytest
@@ -223,11 +222,9 @@ def test_pipeline_matches_sync_every_engine(name, fn, ingress,
 
 
 def test_host_and_native_tiers_parallel_parity(pool_env):
-    """The CPU-fallback tiers (numpy + native C++) count identical
-    windows through the pool and sequentially."""
+    """The host twin counts identical windows through the pool and
+    sequentially, and the native C++ counter agrees with it."""
     from gelly_streaming_tpu.ops import host_triangles
-    from gelly_streaming_tpu.ops.triangles import (
-        _native_count_stream_parallel)
 
     from gelly_streaming_tpu import native
 
@@ -237,8 +234,9 @@ def test_host_and_native_tiers_parallel_parity(pool_env):
     for workers in (1, 2, 4):
         pool_env(workers)
         assert host_triangles.count_stream(src, dst, 128) == want
-        if native.triangles_available():
-            assert _native_count_stream_parallel(src, dst, 128) == want
+    if native.triangles_available():
+        assert [int(c) for c in
+                native.triangle_count_stream(src, dst, 128)] == want
 
 
 def test_stage_timers_populated_by_stream_run():

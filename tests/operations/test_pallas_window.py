@@ -2,7 +2,7 @@
 mode parity against the host twins across all four analytics (the
 524K/32768 acceptance row included), ragged window tails, vb/eb
 bucket boundaries, the K-overflow exact-redo handoff, the
-GS_PALLAS_WINDOW evidence gate (default off = committed digests
+GS_PALLAS_WINDOW pin (default off = the XLA body's digests
 unchanged), the trace-failure fallback chaos leg (durable
 `selection.fallback`, stream survives), the VMEM-budget `supports`
 gate, the tile tuner family, and the analytic cost-model
@@ -192,13 +192,11 @@ def test_cohort_scan_stays_xla_with_parity(pallas_on, monkeypatch):
 
 
 # ----------------------------------------------------------------------
-# the evidence gate
+# the pin
 # ----------------------------------------------------------------------
 def test_gate_default_off_digests_unchanged(pallas_unset):
-    """GS_PALLAS_WINDOW unset: the XLA body is selected (no committed
-    pallas_ab rows clear the bar on this backend) and the digests are
-    the committed ones — which the pinned megakernel reproduces
-    bit-for-bit."""
+    """GS_PALLAS_WINDOW unset: the XLA body is selected, and the
+    pinned megakernel reproduces its digests bit-for-bit."""
     src, dst = _stream(1024, 120, seed=8)
     eng = StreamSummaryEngine(edge_bucket=256, vertex_bucket=256)
     assert not eng._pallas
@@ -226,35 +224,12 @@ def test_gate_default_off_digests_unchanged(pallas_unset):
 
 
 def test_resolve_pins(monkeypatch):
-    pw._reset_pallas_window()
     monkeypatch.setenv("GS_PALLAS_WINDOW", "on")
     assert pw.resolve_pallas_window() is True
     monkeypatch.setenv("GS_PALLAS_WINDOW", "off")
     assert pw.resolve_pallas_window() is False
     monkeypatch.delenv("GS_PALLAS_WINDOW")
-    pw._reset_pallas_window()
-
-
-def test_resolve_evidence_gate(monkeypatch):
-    """auto adopts only when every committed pallas_ab row shows
-    parity AND ≥1.05× — the repo-wide measured-adoption bar."""
-    def fake_perf(rows):
-        return lambda *a, **k: {"pallas_ab": rows}
-
-    winning = [{"probe": "engine_pallas", "parity": True,
-                "speedup": 1.3},
-               {"probe": "stream_pallas", "parity": True,
-                "speedup": 1.1}]
-    losing = [dict(winning[0]), dict(winning[1], speedup=1.01)]
-    no_parity = [dict(winning[0], parity=False), dict(winning[1])]
-    monkeypatch.delenv("GS_PALLAS_WINDOW", raising=False)
-    for rows, want in ((winning, True), (losing, False),
-                       (no_parity, False), ([], False)):
-        monkeypatch.setattr(tri_ops, "_load_matching_perf",
-                            fake_perf(rows))
-        pw._reset_pallas_window()
-        assert pw.resolve_pallas_window() is want, rows
-    pw._reset_pallas_window()
+    assert pw.resolve_pallas_window() is False
 
 
 # ----------------------------------------------------------------------
@@ -501,36 +476,12 @@ def test_cohort_kernel_interpret_parity(cohort_pallas_on):
 
 
 def test_cohort_resolve_pins(monkeypatch):
-    pw._reset_pallas_window()
     monkeypatch.setenv("GS_COHORT_PALLAS", "on")
     assert pw.resolve_cohort_pallas() is True
     monkeypatch.setenv("GS_COHORT_PALLAS", "off")
     assert pw.resolve_cohort_pallas() is False
     monkeypatch.delenv("GS_COHORT_PALLAS")
-    pw._reset_pallas_window()
-
-
-def test_cohort_resolve_evidence_gate_ignores_interpret(monkeypatch):
-    """auto adopts only on committed NON-interpret cohort_pallas rows
-    with parity AND ≥1.05x — interpret rows are parity evidence, not
-    speed evidence, and must never flip the gate."""
-    def fake_perf(rows):
-        return lambda *a, **k: {"tenancy_ab": rows}
-
-    winning = [{"probe": "cohort_pallas", "parity": True,
-                "speedup": 1.4, "tenants": 8}]
-    interp = [dict(winning[0], interpret=True)]
-    losing = [dict(winning[0], speedup=1.01)]
-    other = [{"probe": "cohort_serving", "parity": True,
-              "speedup": 2.0, "tenants": 8}]
-    monkeypatch.delenv("GS_COHORT_PALLAS", raising=False)
-    for rows, want in ((winning, True), (interp, False),
-                       (losing, False), (other, False), ([], False)):
-        monkeypatch.setattr(tri_ops, "_load_matching_perf",
-                            fake_perf(rows))
-        pw._reset_pallas_window()
-        assert pw.resolve_cohort_pallas() is want, rows
-    pw._reset_pallas_window()
+    assert pw.resolve_cohort_pallas() is False
 
 
 def test_cohort_vmem_budget_scales_with_rows(monkeypatch):
@@ -567,8 +518,7 @@ def test_cohort_vmem_budget_scales_with_rows(monkeypatch):
 
 def test_cohort_gate_default_off_is_vmapped_scan(pallas_unset,
                                                  monkeypatch):
-    """GS_COHORT_PALLAS unset on a backend with no committed
-    non-interpret rows: build_cohort_scan returns the vmapped XLA
+    """GS_COHORT_PALLAS unset: build_cohort_scan returns the vmapped XLA
     scan, bit-identical to today's default."""
     monkeypatch.delenv("GS_COHORT_PALLAS", raising=False)
     from gelly_streaming_tpu.ops import scan_analytics as sa

@@ -95,54 +95,20 @@ def test_driver_parity_delta_egress_and_deltas():
 
 
 # ----------------------------------------------------------------------
-# selection gate + ladder
+# selection pin + ladder
 # ----------------------------------------------------------------------
 def test_resolve_resident_pins(monkeypatch):
-    resident_engine._reset_resident()
     monkeypatch.setenv("GS_RESIDENT", "on")
     assert resident_engine.resolve_resident() is True
     monkeypatch.setenv("GS_RESIDENT", "off")
     assert resident_engine.resolve_resident() is False
     monkeypatch.delenv("GS_RESIDENT")
-    resident_engine._reset_resident()
-
-
-def test_resolve_resident_evidence_gate(monkeypatch):
-    """auto adopts resident only when every committed driver row shows
-    parity AND >=1.05x over the best alternative (scan and native)."""
-    from gelly_streaming_tpu.ops import triangles as tri_ops
-
-    def fake_perf(rows):
-        return lambda *a, **k: {"resident_ab": rows}
-
-    winning = [{"probe": "driver_resident", "parity": True,
-                "resident_edges_per_s": 2_000_000,
-                "scan_edges_per_s": 1_000_000,
-                "native_edges_per_s": 1_500_000}]
-    losing_to_native = [dict(winning[0],
-                             native_edges_per_s=3_000_000)]
-    monkeypatch.delenv("GS_RESIDENT", raising=False)
-    monkeypatch.setattr(tri_ops, "_load_matching_perf",
-                        fake_perf(winning))
-    resident_engine._reset_resident()
-    assert resident_engine.resolve_resident() is True
-    monkeypatch.setattr(tri_ops, "_load_matching_perf",
-                        fake_perf(losing_to_native))
-    resident_engine._reset_resident()
     assert resident_engine.resolve_resident() is False
-    resident_engine._reset_resident()
 
 
 def test_resident_tier_resolution_flows_to_driver(monkeypatch):
     monkeypatch.setenv("GS_RESIDENT", "on")
-    resident_engine._reset_resident()
-    driver_mod._reset_snapshot_tier()
-    try:
-        assert driver_mod.resolve_snapshot_tier() == "resident"
-    finally:
-        monkeypatch.delenv("GS_RESIDENT")
-        resident_engine._reset_resident()
-        driver_mod._reset_snapshot_tier()
+    assert driver_mod.resolve_snapshot_tier() == "resident"
 
 
 def test_resident_demotes_to_scan_with_parity():

@@ -463,17 +463,11 @@ def test_window_collective_bytes_accounting():
     (8, 1024, 64, 16, "replicated"),      # a table under the rows
     (1, 32768, 1 << 20, 128, "replicated"),  # one shard moves nothing
 ])
-def test_resolve_table_mode_picks_by_modelled_bytes(monkeypatch, n, eb, vb,
-                                                    kb, want):
+def test_resolve_table_mode_picks_by_modelled_bytes(n, eb, vb, kb, want):
     """The kernel takes the table mode whose per-window collectives
-    move fewer bytes at its own shapes, replicated on a tie, and reads
-    no committed measurement to decide."""
+    move fewer bytes at its own shapes, replicated on a tie."""
     from gelly_streaming_tpu.parallel import sharded
 
-    def no_perf(*_a, **_k):
-        raise AssertionError("the table mode read PERF.json")
-
-    monkeypatch.setattr(tri_ops, "_load_matching_perf", no_perf)
     k = ShardedTriangleWindowKernel(make_mesh(n), edge_bucket=eb,
                                     vertex_bucket=vb, k_bucket=kb)
     moved = {m: sharded.window_collective_bytes(n, k.vb, k.kb, k.cap,

@@ -126,9 +126,8 @@ def test_time_units_complete():
 
 def test_ingress_ab_parity_failure_is_evidence_not_a_crash(monkeypatch):
     """ADVICE r4: a parity failure between wire formats must commit a
-    {parity: false} row (which rows_clear_bar rejects, so compact
-    ingress is never adopted on it) instead of crashing the tool and
-    losing the profiler section's probe rows."""
+    {parity: false} row with no speedup claim instead of crashing the
+    tool and losing the profiler section's probe rows."""
     import jax
     import jax.numpy as jnp
 
@@ -154,7 +153,6 @@ def test_ingress_ab_parity_failure_is_evidence_not_a_crash(monkeypatch):
     (row,) = results
     assert row["parity"] is False
     assert "speedup" not in row
-    assert not tri.rows_clear_bar([row], "speedup", lambda r: 1.0)
 
 
 def test_compile_cache_dir_honours_env(monkeypatch):

@@ -322,7 +322,6 @@ def test_resident_cohort_kill_recovers_onto_any_tier(
     every target finishes the streams bit-exactly equal to the
     fault-free oracle."""
     from gelly_streaming_tpu.core.tenancy import TenantCohort
-    from gelly_streaming_tpu.ops import resident_engine
 
     eb, vb = 256, 256
     streams = _cohort_streams(eb=eb, vb=vb)
@@ -331,64 +330,59 @@ def test_resident_cohort_kill_recovers_onto_any_tier(
             for tid, (s, d) in streams.items()}
 
     monkeypatch.setenv("GS_COHORT_RESIDENT", "on")
-    resident_engine._reset_resident_cohort()
-    try:
-        co = TenantCohort(edge_bucket=eb, vertex_bucket=vb)
-        for tid in streams:
-            co.admit(tid)
-        co.enable_auto_checkpoint(str(tmp_path / "tenants"),
-                                  every_n_windows=2)
-        head, cursors = {}, {tid: 0 for tid in streams}
-        for _ in range(4):
-            for tid, (s, d) in streams.items():
-                c = cursors[tid]
-                co.feed(tid, s[c:c + eb], d[c:c + eb])
-                cursors[tid] = min(len(s), c + eb)
-            for tid, res in co.pump().items():
-                head.setdefault(tid, []).extend(res)
-        assert co.resident_dispatches > 0
-        del co  # the kill: the resident stack is gone with it
+    co = TenantCohort(edge_bucket=eb, vertex_bucket=vb)
+    for tid in streams:
+        co.admit(tid)
+    co.enable_auto_checkpoint(str(tmp_path / "tenants"),
+                              every_n_windows=2)
+    head, cursors = {}, {tid: 0 for tid in streams}
+    for _ in range(4):
+        for tid, (s, d) in streams.items():
+            c = cursors[tid]
+            co.feed(tid, s[c:c + eb], d[c:c + eb])
+            cursors[tid] = min(len(s), c + eb)
+        for tid, res in co.pump().items():
+            head.setdefault(tid, []).extend(res)
+    assert co.resident_dispatches > 0
+    del co  # the kill: the resident stack is gone with it
 
-        if target == "single":
-            # (iii) demote-all: each tenant's checkpoint restores
-            # into a plain single-stream engine
-            for tid, (s, d) in streams.items():
-                eng = StreamSummaryEngine(edge_bucket=eb,
-                                          vertex_bucket=vb)
-                assert eng.try_resume(str(
-                    tmp_path / "tenants" / ("tenant_%s.npz" % tid)))
-                off = eng.resume_offset()
-                assert 0 < off <= len(head[tid]) * eb
-                tail = eng.process(s[off:], d[off:])
-                assert head[tid][:off // eb] + tail == full[tid]
-            return
-
-        if target == "scan":
-            monkeypatch.setenv("GS_COHORT_RESIDENT", "off")
-            resident_engine._reset_resident_cohort()
-        co2 = TenantCohort(edge_bucket=eb, vertex_bucket=vb)
-        for tid in streams:
-            co2.admit(tid)
-        co2.enable_auto_checkpoint(str(tmp_path / "tenants"),
-                                   every_n_windows=2)
-        resumed = co2.resume_all()
-        assert all(resumed.values())
-        final, cursors = {}, {}
-        for tid in streams:
-            off = co2.resume_offset(tid)
+    if target == "single":
+        # (iii) demote-all: each tenant's checkpoint restores
+        # into a plain single-stream engine
+        for tid, (s, d) in streams.items():
+            eng = StreamSummaryEngine(edge_bucket=eb,
+                                      vertex_bucket=vb)
+            assert eng.try_resume(str(
+                tmp_path / "tenants" / ("tenant_%s.npz" % tid)))
+            off = eng.resume_offset()
             assert 0 < off <= len(head[tid]) * eb
-            final[tid] = head[tid][:off // eb]
-            cursors[tid] = off
-        _pump_all(co2, streams, cursors, final, 2 * eb)
-        for tid in streams:
-            final[tid].extend(co2.close(tid))
-        if target == "resident":
-            assert co2.resident_dispatches > 0
-        else:
-            assert co2.resident_dispatches == 0
-        assert final == full
-    finally:
-        resident_engine._reset_resident_cohort()
+            tail = eng.process(s[off:], d[off:])
+            assert head[tid][:off // eb] + tail == full[tid]
+        return
+
+    if target == "scan":
+        monkeypatch.setenv("GS_COHORT_RESIDENT", "off")
+    co2 = TenantCohort(edge_bucket=eb, vertex_bucket=vb)
+    for tid in streams:
+        co2.admit(tid)
+    co2.enable_auto_checkpoint(str(tmp_path / "tenants"),
+                               every_n_windows=2)
+    resumed = co2.resume_all()
+    assert all(resumed.values())
+    final, cursors = {}, {}
+    for tid in streams:
+        off = co2.resume_offset(tid)
+        assert 0 < off <= len(head[tid]) * eb
+        final[tid] = head[tid][:off // eb]
+        cursors[tid] = off
+    _pump_all(co2, streams, cursors, final, 2 * eb)
+    for tid in streams:
+        final[tid].extend(co2.close(tid))
+    if target == "resident":
+        assert co2.resident_dispatches > 0
+    else:
+        assert co2.resident_dispatches == 0
+    assert final == full
 
 
 def test_sharded_engine_state_roundtrip_through_file(tmp_path):

@@ -45,7 +45,8 @@ def test_driver_phase_matches_reference(stream, guards, monkeypatch, tier):
     src, dst, refs = stream
     mesh = None
     if tier == "scan":
-        monkeypatch.setattr(driver, "_SNAPSHOT_TIER", "scan")
+        monkeypatch.delenv("GS_RESIDENT", raising=False)
+        assert driver.resolve_snapshot_tier() == "scan"
     else:
         mesh = make_mesh(4)
     chip_smoke.run_driver(src, dst, refs, guards, EB, VB, mesh=mesh)

@@ -12,6 +12,9 @@ import pytest
 from gelly_streaming_tpu.core.driver import StreamingAnalyticsDriver
 
 ANALYTICS = ("degrees", "cc", "bipartite")
+# the single-chip snapshot tiers: None is the unpinned default (the
+# scan); native and host are the demotion ladder's rungs
+TIERS = pytest.mark.parametrize("tier", [None, "native", "host"])
 
 
 def fuzz_stream(num_edges, num_vertices, seed):
@@ -71,21 +74,23 @@ def roundtrip(driver, src, dst, chunks=1):
     return windows
 
 
-def test_batched_single_chip_fuzz():
+@TIERS
+def test_batched_single_chip_fuzz(tier):
     src, dst = fuzz_stream(6000, 700, seed=11)
     drv = StreamingAnalyticsDriver(
         window_ms=0, analytics=ANALYTICS, vertex_bucket=256,
-        edge_bucket=512, emit_deltas=True)
+        edge_bucket=512, emit_deltas=True, snapshot_tier=tier)
     assert roundtrip(drv, src, dst) >= 11
 
 
-def test_deltas_are_sparse():
+@TIERS
+def test_deltas_are_sparse(tier):
     """The point of the masks: windows that touch few vertices emit few
     records, not vb-length vectors."""
     src, dst = fuzz_stream(4096, 2000, seed=3)
     drv = StreamingAnalyticsDriver(
         window_ms=0, analytics=ANALYTICS, vertex_bucket=4096,
-        edge_bucket=1024, emit_deltas=True)
+        edge_bucket=1024, emit_deltas=True, snapshot_tier=tier)
     results = drv.run_arrays(src, dst)
     for res in results[1:]:
         ids, _ = res.delta_degrees
@@ -94,7 +99,8 @@ def test_deltas_are_sparse():
         assert len(ids) < len(res.vertex_ids)  # strictly sparse here
 
 
-def test_per_window_path_matches_batched():
+@TIERS
+def test_per_window_path_matches_batched(tier):
     """Single-window calls route through _window (host-diff deltas);
     feeding the same stream window-by-window must reconstruct
     identically to the batched device-mask path."""
@@ -102,11 +108,11 @@ def test_per_window_path_matches_batched():
     eb = 512
     drv_b = StreamingAnalyticsDriver(
         window_ms=0, analytics=ANALYTICS, vertex_bucket=256,
-        edge_bucket=eb, emit_deltas=True)
+        edge_bucket=eb, emit_deltas=True, snapshot_tier=tier)
     batched = drv_b.run_arrays(src, dst)
     drv_w = StreamingAnalyticsDriver(
         window_ms=0, analytics=ANALYTICS, vertex_bucket=256,
-        edge_bucket=eb, emit_deltas=True)
+        edge_bucket=eb, emit_deltas=True, snapshot_tier=tier)
     recon = Reconstructor()
     for i, lo in enumerate(range(0, len(src), eb)):
         (res,) = drv_w.run_arrays(src[lo:lo + eb], dst[lo:lo + eb])
@@ -120,7 +126,8 @@ def test_per_window_path_matches_batched():
             np.testing.assert_array_equal(vals_w, vals_b)
 
 
-def test_event_time_windows_with_growth():
+@TIERS
+def test_event_time_windows_with_growth(tier):
     """Event-time windows of ragged sizes + vertex-bucket growth mid
     stream (the scan rebuilds at the wider bucket) keep the delta
     contract."""
@@ -131,7 +138,7 @@ def test_event_time_windows_with_growth():
     ts = np.sort(rng.integers(0, 4000, n))
     drv = StreamingAnalyticsDriver(
         window_ms=250, analytics=ANALYTICS, vertex_bucket=64,
-        edge_bucket=64, emit_deltas=True)
+        edge_bucket=64, emit_deltas=True, snapshot_tier=tier)
     recon = Reconstructor()
     for res in drv.run_arrays(src, dst, ts):
         recon.apply(res)
@@ -148,11 +155,12 @@ def test_sharded_mesh_deltas():
     assert roundtrip(drv, src, dst, chunks=2) == 8
 
 
-def test_off_by_default():
+@TIERS
+def test_off_by_default(tier):
     src, dst = fuzz_stream(1024, 200, seed=2)
     drv = StreamingAnalyticsDriver(
         window_ms=0, analytics=ANALYTICS, vertex_bucket=256,
-        edge_bucket=512)
+        edge_bucket=512, snapshot_tier=tier)
     for res in drv.run_arrays(src, dst):
         assert res.delta_degrees is None
         assert res.delta_cc is None

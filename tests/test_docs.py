@@ -22,7 +22,6 @@ DOC_SYMBOLS = [
     ("gelly_streaming_tpu/ops/neighborhood.py", "def window_stack_combine"),
     ("gelly_streaming_tpu/ops/segment.py",
      "def segmented_reduce_associative"),
-    ("gelly_streaming_tpu/ops/triangles.py", "def resolve_intersect_impl"),
     ("gelly_streaming_tpu/ops/triangles.py", "def resolve_xla_intersect"),
     ("gelly_streaming_tpu/ops/triangles.py", "def _tuned_kb"),
     ("gelly_streaming_tpu/parallel/sharded.py",

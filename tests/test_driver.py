@@ -11,6 +11,11 @@ from gelly_streaming_tpu.ops import triangles as tri_ops
 from gelly_streaming_tpu.ops import unionfind
 from gelly_streaming_tpu.parallel.mesh import make_mesh
 
+# the single-chip snapshot tiers: None is the unpinned default (the
+# scan, as on the chip); native and host are the demotion ladder's
+# rungs, reached otherwise only through a demotion
+TIERS = pytest.mark.parametrize("tier", [None, "native", "host"])
+
 
 def _stream(seed=0, n=3000, v=500):
     rng = np.random.default_rng(seed)
@@ -40,12 +45,23 @@ def _reference_results(src, dst, ts, window_ms):
     return out
 
 
-@pytest.mark.parametrize("sharded", [False, True])
-def test_driver_matches_independent_analytics(sharded):
+# the mesh case plus each single-chip tier; the unpinned ids keep
+# their pre-ladder names
+MESH_AND_TIERS = pytest.mark.parametrize("sharded,tier", [
+    pytest.param(False, None, id="False"),
+    pytest.param(True, None, id="True"),
+    pytest.param(False, "native", id="False-native"),
+    pytest.param(False, "host", id="False-host"),
+])
+
+
+@MESH_AND_TIERS
+def test_driver_matches_independent_analytics(sharded, tier):
     src, dst, ts = _stream()
     mesh = make_mesh() if sharded else None
     drv = StreamingAnalyticsDriver(window_ms=1000, mesh=mesh,
-                                   vertex_bucket=64, edge_bucket=64)
+                                   vertex_bucket=64, edge_bucket=64,
+                                   snapshot_tier=tier)
     results = drv.run_arrays(src, dst, ts)  # buckets must grow en route
     refs = _reference_results(src, dst, ts, 1000)
     assert len(results) == len(refs)
@@ -65,11 +81,26 @@ def test_driver_matches_independent_analytics(sharded):
         assert labels.min() >= 0
 
 
-def test_driver_cc_partition_matches_host():
+def test_unpinned_driver_runs_the_chip_path_on_cpu(monkeypatch):
+    """With no pin, the CPU runs the chip's program: the device
+    snapshot scan and the device triangle program at K = 128."""
+    import jax
+
+    monkeypatch.delenv("GS_RESIDENT", raising=False)
+    assert jax.default_backend() == "cpu"
+    drv = StreamingAnalyticsDriver(window_ms=0, edge_bucket=32768)
+    assert drv._base_tier() == "scan"
+    assert drv._effective_tier() == "scan"
+    assert tri_ops._resolve_stream_impl(drv.eb) == "device"
+    assert tri_ops._tuned_kb(drv.eb) == 128
+
+
+@TIERS
+def test_driver_cc_partition_matches_host(tier):
     src = np.array([1, 2, 10, 20, 2])
     dst = np.array([2, 3, 11, 21, 10])
     drv = StreamingAnalyticsDriver(window_ms=100,
-                                   analytics=("cc",))
+                                   analytics=("cc",), snapshot_tier=tier)
     (res,) = drv.run_arrays(src, dst, np.zeros(5, np.int64))
     ids = res.vertex_ids
     lab = res.cc_labels
@@ -80,10 +111,12 @@ def test_driver_cc_partition_matches_host():
     assert groups == [[1, 2, 3, 10, 11], [20, 21]]
 
 
-def test_driver_count_windows_without_timestamps():
+@TIERS
+def test_driver_count_windows_without_timestamps(tier):
     src, dst, _ = _stream(n=300)
     drv = StreamingAnalyticsDriver(window_ms=1000, edge_bucket=128,
-                                   analytics=("triangles",))
+                                   analytics=("triangles",),
+                                   snapshot_tier=tier)
     results = drv.run_arrays(src, dst)
     assert [r.num_edges for r in results] == [128, 128, 44]
     for r, s in zip(results, range(0, 300, 128)):
@@ -91,16 +124,17 @@ def test_driver_count_windows_without_timestamps():
             src[s:s + 128], dst[s:s + 128], 500)
 
 
-def test_driver_checkpoint_resume():
+@TIERS
+def test_driver_checkpoint_resume(tier):
     src, dst, ts = _stream(seed=3)
     half = len(src) // 2
     a = StreamingAnalyticsDriver(window_ms=500, vertex_bucket=64,
-                                 edge_bucket=64)
+                                 edge_bucket=64, snapshot_tier=tier)
     a.run_arrays(src[:half], dst[:half], ts[:half])
     state = a.state_dict()
 
     b = StreamingAnalyticsDriver(window_ms=500, vertex_bucket=64,
-                                 edge_bucket=64)
+                                 edge_bucket=64, snapshot_tier=tier)
     b.load_state_dict(state)
     out_b = b.run_arrays(src[half:], dst[half:], ts[half:])
     out_a = a.run_arrays(src[half:], dst[half:], ts[half:])
@@ -119,23 +153,26 @@ def test_driver_ascending_timestamp_contract():
                        np.array([500, 100]))
 
 
-def test_driver_tracing_and_file(tmp_path):
+@TIERS
+def test_driver_tracing_and_file(tmp_path, tier):
     p = tmp_path / "edges.txt"
     p.write_text("1 2 100\n2 3 150\n1 3 180\n3 4 300\n")
-    drv = StreamingAnalyticsDriver(window_ms=200, tracing=True)
+    drv = StreamingAnalyticsDriver(window_ms=200, tracing=True,
+                                   snapshot_tier=tier)
     results = drv.run_file(str(p))
     assert [r.triangles for r in results] == [1, 0]
     report = drv.trace_report()
     assert {row["op"] for row in report} >= {"intern", "triangles"}
 
 
-def test_driver_cross_mode_checkpoint_converts():
+@TIERS
+def test_driver_cross_mode_checkpoint_converts(tier):
     """A single-chip checkpoint now CONVERTS onto a mesh driver (and
     vice versa — the engine slabs are gathered replicated state): the
     resumed sharded session continues with the checkpointed analytics
     instead of refusing. Full round-trip equality is pinned by
     tests/test_checkpoint_roundtrip.py's cross-mode suite."""
-    a = StreamingAnalyticsDriver(window_ms=500)
+    a = StreamingAnalyticsDriver(window_ms=500, snapshot_tier=tier)
     a.run_arrays(np.array([1, 2]), np.array([2, 3]),
                  np.array([100, 200]))
     state = a.state_dict()
@@ -147,7 +184,8 @@ def test_driver_cross_mode_checkpoint_converts():
         np.asarray(st["degree_state"])[:len(a._degrees)], a._degrees)
 
 
-def test_driver_auto_checkpoint_failure_recovery(tmp_path):
+@TIERS
+def test_driver_auto_checkpoint_failure_recovery(tmp_path, tier):
     """Crash/recover: a driver checkpointing every 2 windows dies; a
     fresh driver resumes from the snapshot cursor and the final state
     matches an uninterrupted run."""
@@ -155,23 +193,27 @@ def test_driver_auto_checkpoint_failure_recovery(tmp_path):
     src, dst, _ = _stream(seed=7, n=1024)
     eb = 128  # count-based windows: 8 windows of 128 edges
 
-    a = StreamingAnalyticsDriver(window_ms=0, edge_bucket=eb)
+    a = StreamingAnalyticsDriver(window_ms=0, edge_bucket=eb,
+                                 snapshot_tier=tier)
     a.enable_auto_checkpoint(ckpt, every_n_windows=2)
     a.run_arrays(src[: 6 * eb], dst[: 6 * eb])  # "crash" after 6 windows
 
-    b = StreamingAnalyticsDriver(window_ms=0, edge_bucket=eb)
+    b = StreamingAnalyticsDriver(window_ms=0, edge_bucket=eb,
+                                 snapshot_tier=tier)
     assert b.try_resume(ckpt)
     assert b.windows_done == 6  # checkpoint fired at window 6
     out_b = b.run_arrays(src[b.windows_done * eb:],
                          dst[b.windows_done * eb:])
 
-    c = StreamingAnalyticsDriver(window_ms=0, edge_bucket=eb)
+    c = StreamingAnalyticsDriver(window_ms=0, edge_bucket=eb,
+                                 snapshot_tier=tier)
     out_c = c.run_arrays(src, dst)
     np.testing.assert_array_equal(out_b[-1].degrees, out_c[-1].degrees)
     np.testing.assert_array_equal(out_b[-1].cc_labels, out_c[-1].cc_labels)
     assert out_b[-1].triangles == out_c[-1].triangles
-    assert not StreamingAnalyticsDriver(window_ms=0).try_resume(
-        str(tmp_path / "missing.ckpt"))
+    assert not StreamingAnalyticsDriver(
+        window_ms=0, snapshot_tier=tier).try_resume(
+            str(tmp_path / "missing.ckpt"))
 
 
 def test_stream_file_matches_run_file(tmp_path):
@@ -319,12 +361,13 @@ def test_sharded_bucket_growth_carries_engine_state():
     assert out[-1].triangles == want[-1].triangles
 
 
-def test_driver_count_based_partial_window_guard():
+@TIERS
+def test_driver_count_based_partial_window_guard(tier):
     # ADVICE r1: a chunked count-based feed whose chunk is not an
     # edge_bucket multiple closes a short window and would silently
     # shift every later boundary — the driver must refuse more input
     drv = StreamingAnalyticsDriver(window_ms=0, edge_bucket=8,
-                                   analytics=("degrees",))
+                                   analytics=("degrees",), snapshot_tier=tier)
     src = np.arange(12) % 5
     drv.run_arrays(src, (src + 1) % 5)  # closes an 8 + partial-4 window
     with pytest.raises(ValueError, match="partial window"):
@@ -334,14 +377,15 @@ def test_driver_count_based_partial_window_guard():
     drv.run_arrays(src[:8], (src[:8] + 1) % 5)
 
 
-def test_partial_window_flag_not_persisted_before_final_window(tmp_path):
+@TIERS
+def test_partial_window_flag_not_persisted_before_final_window(tmp_path, tier):
     """A mid-call checkpoint taken BEFORE the call's short final window
     must not record closed_partial: a crash between that checkpoint and
     the short window would otherwise leave a state that refuses an
     exact replay of the remaining edges (code-review r2 finding)."""
     ckpt = str(tmp_path / "ck.npz")
     drv = StreamingAnalyticsDriver(window_ms=0, edge_bucket=8,
-                                   analytics=("degrees",))
+                                   analytics=("degrees",), snapshot_tier=tier)
     drv.enable_auto_checkpoint(ckpt, every_n_windows=1)
     src = np.arange(20) % 5  # 2 full windows + partial 4-edge window
     drv.run_arrays(src, (src + 1) % 5)
@@ -351,11 +395,13 @@ def test_partial_window_flag_not_persisted_before_final_window(tmp_path):
     # checkpoint cut at windows_done=2 (the every-window cadence means
     # the final checkpoint has 3 windows; rebuild the 2-window one)
     fresh = StreamingAnalyticsDriver(window_ms=0, edge_bucket=8,
-                                     analytics=("degrees",))
+                                     analytics=("degrees",),
+                                     snapshot_tier=tier)
     fresh.enable_auto_checkpoint(ckpt, every_n_windows=1)
     fresh.run_arrays(src[:16], (src[:16] + 1) % 5)  # exactly 2 windows
     resumed = StreamingAnalyticsDriver(window_ms=0, edge_bucket=8,
-                                       analytics=("degrees",))
+                                       analytics=("degrees",),
+                                       snapshot_tier=tier)
     assert resumed.try_resume(ckpt)
     assert not resumed._closed_partial
     # replaying the remaining edges must succeed and close the stream
@@ -363,9 +409,11 @@ def test_partial_window_flag_not_persisted_before_final_window(tmp_path):
     assert len(out) == 1 and out[-1].num_edges == 4
 
 
-def test_driver_reset_gives_clean_rerun():
+@TIERS
+def test_driver_reset_gives_clean_rerun(tier):
     drv = StreamingAnalyticsDriver(window_ms=0, edge_bucket=8,
-                                   analytics=("degrees", "cc"))
+                                   analytics=("degrees", "cc"),
+                                   snapshot_tier=tier)
     src = np.arange(16) % 7
     dst = (src + 2) % 7
     first = drv.run_arrays(src, dst)
@@ -376,18 +424,21 @@ def test_driver_reset_gives_clean_rerun():
     np.testing.assert_array_equal(first[-1].cc_labels, again[-1].cc_labels)
 
 
-def test_driver_checkpoint_carries_vertex_bucket(tmp_path):
+@TIERS
+def test_driver_checkpoint_carries_vertex_bucket(tmp_path, tier):
     # ADVICE r1: resume must adopt the checkpointed vertex bucket up
     # front instead of dying deep in the engine with a mismatch error
     p = str(tmp_path / "ck.npz")
     a = StreamingAnalyticsDriver(window_ms=0, vertex_bucket=16,
-                                 edge_bucket=8, analytics=("degrees",))
+                                 edge_bucket=8, analytics=("degrees",),
+                                 snapshot_tier=tier)
     src = np.arange(64) % 40  # grows the vertex bucket past 16
     a.run_arrays(src, (src + 3) % 40)
     import gelly_streaming_tpu.utils.checkpoint as ckpt
     ckpt.save(p, a.state_dict())
     b = StreamingAnalyticsDriver(window_ms=0, vertex_bucket=1 << 12,
-                                 edge_bucket=8, analytics=("degrees",))
+                                 edge_bucket=8, analytics=("degrees",),
+                                 snapshot_tier=tier)
     assert b.try_resume(p)
     # single-chip keeps the LARGER pre-sized constructor bucket (so a
     # caller who pre-sized to avoid bucket-doubling recompiles doesn't
@@ -395,7 +446,8 @@ def test_driver_checkpoint_carries_vertex_bucket(tmp_path):
     # checkpoint's grown bucket (code-review r2 finding)
     assert b.vb == 1 << 12
     c = StreamingAnalyticsDriver(window_ms=0, vertex_bucket=16,
-                                 edge_bucket=8, analytics=("degrees",))
+                                 edge_bucket=8, analytics=("degrees",),
+                                 snapshot_tier=tier)
     assert c.try_resume(p)
     assert c.vb == a.vb
     ra = a.run_arrays(src[:8], (src[:8] + 3) % 40)
@@ -405,8 +457,8 @@ def test_driver_checkpoint_carries_vertex_bucket(tmp_path):
     np.testing.assert_array_equal(ra[-1].degrees, rc[-1].degrees)
 
 
-@pytest.mark.parametrize("sharded", [False, True])
-def test_batched_scan_path_matches_per_window_path(sharded):
+@MESH_AND_TIERS
+def test_batched_scan_path_matches_per_window_path(sharded, tier):
     """The batched snapshot-scan fast path (one dispatch per call,
     single-chip jit or shard_map over the mesh) must produce
     bit-identical per-window snapshots to the per-window path
@@ -424,9 +476,11 @@ def test_batched_scan_path_matches_per_window_path(sharded):
 
     for mode in ("count", "event"):
         a = StreamingAnalyticsDriver(window_ms=1000, edge_bucket=eb,
-                                     vertex_bucket=16, mesh=mesh)
+                                     vertex_bucket=16, mesh=mesh,
+                                     snapshot_tier=tier)
         b = StreamingAnalyticsDriver(window_ms=1000, edge_bucket=eb,
-                                     vertex_bucket=16, mesh=mesh)
+                                     vertex_bucket=16, mesh=mesh,
+                                     snapshot_tier=tier)
         if mode == "count":
             batched = a.run_arrays(src, dst)
             single = []
@@ -504,7 +558,8 @@ def test_stream_file_multi_crash_resume_fuzz(tmp_path):
             np.testing.assert_array_equal(got[key], want[key], err_msg=key)
 
 
-def test_try_resume_corrupt_checkpoint_starts_fresh(tmp_path):
+@TIERS
+def test_try_resume_corrupt_checkpoint_starts_fresh(tmp_path, tier):
     """A truncated/corrupt checkpoint file (external damage — save()
     itself is atomic) must behave like a missing one: warn, return
     False, full reprocess stays correct. Semantic mismatches (e.g.
@@ -514,14 +569,14 @@ def test_try_resume_corrupt_checkpoint_starts_fresh(tmp_path):
 
     from gelly_streaming_tpu.utils import checkpoint
 
-    d = StreamingAnalyticsDriver(window_ms=100)
+    d = StreamingAnalyticsDriver(window_ms=100, snapshot_tier=tier)
     d.run_arrays(np.array([1, 2, 3]), np.array([2, 3, 4]))
     ck = str(tmp_path / "c.ckpt")
     checkpoint.save(ck, d.state_dict())
     raw = open(ck, "rb").read()
     open(ck, "wb").write(raw[:len(raw) // 2])
 
-    e = StreamingAnalyticsDriver(window_ms=100)
+    e = StreamingAnalyticsDriver(window_ms=100, snapshot_tier=tier)
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         assert e.try_resume(ck) is False
@@ -538,26 +593,27 @@ def test_try_resume_corrupt_checkpoint_starts_fresh(tmp_path):
     raw2[mid] ^= 0xFF
     raw2[mid + 1] ^= 0xFF
     open(ck2, "wb").write(bytes(raw2))
-    f = StreamingAnalyticsDriver(window_ms=100)
+    f = StreamingAnalyticsDriver(window_ms=100, snapshot_tier=tier)
     with warnings.catch_warnings(record=True):
         warnings.simplefilter("always")
         assert f.try_resume(ck2) is False
 
 
-def test_stream_file_tolerates_malformed_lines(tmp_path):
+@TIERS
+def test_stream_file_tolerates_malformed_lines(tmp_path, tier):
     """The ingest parser drops malformed lines (native and Python
     fallbacks agree — tests/test_native.py pins that); the driver sees
     only the valid records, and an all-garbage file behaves like an
     empty one."""
     g = tmp_path / "garbage.txt"
     g.write_text("hello world\nfoo bar baz\n# comment\n")
-    d = StreamingAnalyticsDriver(window_ms=100)
+    d = StreamingAnalyticsDriver(window_ms=100, snapshot_tier=tier)
     assert list(d.stream_file(str(g))) == []
     assert d.windows_done == 0
 
     m = tmp_path / "mixed.txt"
     m.write_text("x\n1 2 100\nbad line\n3 4 200\n")
-    e = StreamingAnalyticsDriver(window_ms=100)
+    e = StreamingAnalyticsDriver(window_ms=100, snapshot_tier=tier)
     res = list(e.stream_file(str(m)))
     assert [(r.window_start, int(r.degrees.sum())) for r in res] == \
         [(100, 2), (200, 4)]

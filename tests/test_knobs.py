@@ -114,12 +114,43 @@ def test_str_choices(monkeypatch):
 
 
 def test_egress_accepts_documented_auto(monkeypatch):
-    # the README table renders GS_EGRESS's default as `auto`; setting
-    # the documented default explicitly must behave like unset
-    monkeypatch.setenv("GS_EGRESS", "auto")
-    assert knobs.get_str("GS_EGRESS") == "auto"
+    # the README table renders GS_EGRESS's default as `full`; setting
+    # the documented default explicitly must behave like unset, and
+    # the retired `auto` value is refused rather than ignored
     from gelly_streaming_tpu.ops import delta_egress
-    assert delta_egress.resolve_egress() in ("full", "delta")
+    monkeypatch.delenv("GS_EGRESS", raising=False)
+    assert delta_egress.resolve_egress() == "full"
+    monkeypatch.setenv("GS_EGRESS", "full")
+    assert delta_egress.resolve_egress() == "full"
+    monkeypatch.setenv("GS_EGRESS", "auto")
+    with pytest.raises(knobs.KnobError):
+        knobs.get_str("GS_EGRESS")
+
+
+def _pin_resolvers():
+    from gelly_streaming_tpu.ops import pallas_window, resident_engine
+    return {"GS_RESIDENT": resident_engine.resolve_resident,
+            "GS_COHORT_RESIDENT": resident_engine.resolve_resident_cohort,
+            "GS_PALLAS_WINDOW": pallas_window.resolve_pallas_window,
+            "GS_COHORT_PALLAS": pallas_window.resolve_cohort_pallas,
+            "GS_GNN_PALLAS": pallas_window.resolve_gnn_pallas}
+
+
+@pytest.mark.parametrize("name", ["GS_RESIDENT", "GS_COHORT_RESIDENT",
+                                  "GS_PALLAS_WINDOW", "GS_COHORT_PALLAS",
+                                  "GS_GNN_PALLAS"])
+def test_tier_pin_is_on_or_off_only(monkeypatch, name):
+    """A tier pin selects its tier only at `on`; `off` is the same as
+    unset, and the retired `auto` is refused rather than ignored."""
+    resolve = _pin_resolvers()[name]
+    assert resolve() is False
+    monkeypatch.setenv(name, "off")
+    assert resolve() is False
+    monkeypatch.setenv(name, "on")
+    assert resolve() is True
+    monkeypatch.setenv(name, "auto")
+    with pytest.raises(knobs.KnobError):
+        resolve()
 
 
 def test_path_kind(monkeypatch):

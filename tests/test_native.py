@@ -226,50 +226,23 @@ def test_snapshot_tier_checkpoint_interop(tmp_path):
         _assert_results_equal(rest, want[done:])
 
 
-def test_snapshot_tier_resolver_gates(monkeypatch, tmp_path):
-    """resolve_snapshot_tier: evidence-gated like the other selections
-    — flips only on backend-matched all-parity >=5% wins, never on a
-    chip backend."""
-    import json
-
+def test_snapshot_tier_resolver_gates(monkeypatch):
+    """resolve_snapshot_tier: the scan on every backend, the resident
+    tier only under the GS_RESIDENT=on pin. The native tier is reached
+    only by the `snapshot_tier=` pin or the demotion ladder."""
     import jax
 
-    from gelly_streaming_tpu import native as native_mod
-
-    if not native_mod.snapshot_available():
-        import pytest
-
-        pytest.skip("libgsnative lacks gs_snapshot_windows")
-
     from gelly_streaming_tpu.core import driver as drv_mod
-    from gelly_streaming_tpu.ops import triangles as tri_mod
 
-    perf = tmp_path / "PERF.json"
-    monkeypatch.setattr(tri_mod, "_PERF_PATH", str(perf))
-
-    def configure(file_backend, process_backend, rows):
-        perf.write_text(json.dumps(
-            {"backend": file_backend, "host_snapshot": rows}))
-        monkeypatch.setattr(jax, "default_backend",
-                            lambda: process_backend)
-        monkeypatch.setattr(drv_mod, "_SNAPSHOT_TIER", None)
-
-    win = [{"parity": True, "scan_edges_per_s": 100,
-            "native_edges_per_s": 900}]
-    configure("cpu", "cpu", win)
-    assert drv_mod.resolve_snapshot_tier() == "native"
-    configure("cpu", "tpu", win)   # chip process: scan always stands
-    assert drv_mod.resolve_snapshot_tier() == "scan"
-    configure("tpu", "cpu", win)   # wrong-backend file
-    assert drv_mod.resolve_snapshot_tier() == "scan"
-    configure("cpu", "cpu", [{"parity": False,
-                              "scan_edges_per_s": 100,
-                              "native_edges_per_s": 900}])
-    assert drv_mod.resolve_snapshot_tier() == "scan"
-    configure("cpu", "cpu", [{"parity": True,
-                              "scan_edges_per_s": 100,
-                              "native_edges_per_s": 103}])
-    assert drv_mod.resolve_snapshot_tier() == "scan"
+    monkeypatch.delenv("GS_RESIDENT", raising=False)
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert drv_mod.resolve_snapshot_tier() == "scan"
+        monkeypatch.setenv("GS_RESIDENT", "off")
+        assert drv_mod.resolve_snapshot_tier() == "scan"
+        monkeypatch.setenv("GS_RESIDENT", "on")
+        assert drv_mod.resolve_snapshot_tier() == "resident"
+        monkeypatch.delenv("GS_RESIDENT")
 
 
 def test_snapshot_tier_delta_parity():

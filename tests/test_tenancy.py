@@ -38,7 +38,6 @@ EB, VB = 128, 256
 @pytest.fixture(autouse=True)
 def _clean(monkeypatch):
     from gelly_streaming_tpu.ops import pallas_window
-    from gelly_streaming_tpu.ops import resident_engine
 
     for k in ("GS_TENANT_MAX", "GS_TENANT_QUEUE_WINDOWS",
               "GS_TENANT_ADMISSION", "GS_TENANT_TPD", "GS_AUTOTUNE",
@@ -46,11 +45,9 @@ def _clean(monkeypatch):
         monkeypatch.delenv(k, raising=False)
     monkeypatch.setenv("GS_AUTOTUNE", "0")
     resilience.reset_demotions()
-    resident_engine._reset_resident_cohort()
     pallas_window._reset_pallas_window()
     yield
     resilience.reset_demotions()
-    resident_engine._reset_resident_cohort()
     pallas_window._reset_pallas_window()
 
 
@@ -523,12 +520,10 @@ def test_resident_cohort_parity(monkeypatch, n_tenants):
     reproduce the scan-tier cohort (and thus the N sequential
     engines) exactly — and must have actually dispatched through the
     resident path."""
-    from gelly_streaming_tpu.ops import resident_engine
 
     streams = streams_for(n_tenants)
     want = oracle(streams)
     monkeypatch.setenv("GS_COHORT_RESIDENT", "on")
-    resident_engine._reset_resident_cohort()
     got, co = run_cohort(streams, piece=EB)
     assert got == want
     assert co.resident_dispatches > 0, \
@@ -536,16 +531,14 @@ def test_resident_cohort_parity(monkeypatch, n_tenants):
 
 
 def test_resident_cohort_defaults_off_digest_identical(monkeypatch):
-    """GS_COHORT_RESIDENT unset on a backend with no committed
-    cohort_resident rows clearing the bar: the dispatch plan and the
-    results are bit-identical to the scan-tier cohort."""
-    from gelly_streaming_tpu.ops import resident_engine
+    """GS_COHORT_RESIDENT unset: the dispatch plan is the scan-tier
+    cohort's, and the pinned resident tier reproduces its results
+    bit-identically."""
 
     streams = streams_for(3)
     base, co0 = run_cohort(streams)
     assert co0.resident_dispatches == 0
     monkeypatch.setenv("GS_COHORT_RESIDENT", "on")
-    resident_engine._reset_resident_cohort()
     got, _co = run_cohort(streams)
     assert got == base
 
@@ -558,7 +551,6 @@ def test_resident_stack_replacement_never_strands_a_carry(monkeypatch):
     replaced the stack while t3 still held a res_row into it — t3's
     final partial window then folded onto a pad row's fresh carry
     instead of its own, silently wrong analytics."""
-    from gelly_streaming_tpu.ops import resident_engine
 
     rng = np.random.default_rng(7)
     streams = {}
@@ -569,48 +561,22 @@ def test_resident_stack_replacement_never_strands_a_carry(monkeypatch):
             rng.integers(0, VB, edges).astype(np.int32))
     want = oracle(streams)
     monkeypatch.setenv("GS_COHORT_RESIDENT", "on")
-    resident_engine._reset_resident_cohort()
     # piece=2*EB staggers exhaustion so the batch membership churns
     # across rounds before the per-tenant closes cut the tails
     got, co = run_cohort(streams, piece=2 * EB)
     assert co.resident_dispatches > 0
     assert got == want
-    resident_engine._reset_resident_cohort()
 
 
 def test_resolve_resident_cohort_pins_and_gate(monkeypatch):
     from gelly_streaming_tpu.ops import resident_engine
-    from gelly_streaming_tpu.ops import triangles as tri_ops
 
     monkeypatch.setenv("GS_COHORT_RESIDENT", "on")
     assert resident_engine.resolve_resident_cohort() is True
     monkeypatch.setenv("GS_COHORT_RESIDENT", "off")
     assert resident_engine.resolve_resident_cohort() is False
     monkeypatch.delenv("GS_COHORT_RESIDENT")
-
-    def fake_perf(rows):
-        return lambda *a, **k: {"tenancy_ab": rows}
-
-    # the committed-evidence bar: EVERY cohort_resident row parity
-    # with throughput ≥1.05x its own sequential baseline — the N=1
-    # row's honest ~1x keeps auto off
-    winning = [{"probe": "cohort_resident", "parity": True,
-                "tenants": 8, "tenant_edges_per_s": 2000,
-                "sequential_edges_per_s": 1000, "speedup": 2.0}]
-    with_n1 = winning + [
-        {"probe": "cohort_resident", "parity": True, "tenants": 1,
-         "tenant_edges_per_s": 990, "sequential_edges_per_s": 1000,
-         "speedup": 0.99}]
-    other = [{"probe": "cohort_serving", "parity": True, "tenants": 8,
-              "tenant_edges_per_s": 2000,
-              "sequential_edges_per_s": 1000, "speedup": 2.0}]
-    for rows, want in ((winning, True), (with_n1, False),
-                       (other, False), ([], False)):
-        monkeypatch.setattr(tri_ops, "_load_matching_perf",
-                            fake_perf(rows))
-        resident_engine._reset_resident_cohort()
-        assert resident_engine.resolve_resident_cohort() is want, rows
-    resident_engine._reset_resident_cohort()
+    assert resident_engine.resolve_resident_cohort() is False
 
 
 def test_tuner_rekeys_on_cohort_size_bucket(monkeypatch, tmp_path):

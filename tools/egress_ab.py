@@ -19,10 +19,10 @@ Timing is median-of-3 with min/max dispersion committed in the row
 load noise, not evidence). GS_AUTOTUNE is pinned OFF inside the
 probes so the egress lever is measured in isolation.
 
-The committed `egress_ab` rows are what ops/delta_egress.
-resolve_egress gates on: parity true AND >=5% on EVERY row, or
-full-vector stands. Run alone on the chip's host; commit policy identical to tools/ingress_ab.py (PERF.json only when
-backend-matched, PERF_<backend>.json always).
+The rows are a record: the runtime selects delta egress only when
+GS_EGRESS=delta pins it. Run alone on the chip's host; commit policy
+identical to tools/ingress_ab.py (PERF.json only when backend-matched,
+PERF_<backend>.json always).
 """
 
 import hashlib

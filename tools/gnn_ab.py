@@ -19,9 +19,8 @@ Three probes, each a JSON row:
   gnn_pallas — the fused Pallas GNN kernel (GS_GNN_PALLAS=on) vs the
               XLA gather/segment-sum round (pinned off). Off-TPU this
               runs in interpret mode and the row carries
-              `interpret: true`; pallas_window.resolve_gnn_pallas
-              ignores interpret rows for adoption, so those rows are
-              PARITY evidence, not speed evidence.
+              `interpret: true`: those rows are PARITY evidence,
+              not speed evidence.
 
 Timing is median-of-3 with min/max dispersion in the row (the ingress
 A/B's flip-flop taught us a single draw is load noise). GS_AUTOTUNE
@@ -161,8 +160,7 @@ def cohort_oracle(streams, eb, vb, F):
 
 class scoped_env:
     """Pin GS_* knobs for one probe side and restore afterwards,
-    resetting the memoised Pallas resolvers so the pin is seen
-    (resolve_* caches the auto decision per process)."""
+    resetting the Pallas probe verdicts so each side builds anew."""
 
     def __init__(self, **pins):
         self.pins = pins

@@ -174,10 +174,9 @@ def stream_ab(jax, jnp, num_edges, results):
                                                warmup=1)
     t_cmp, t_cmp_min, t_cmp_max = _timed_stats(run_cmp, reps=3,
                                                warmup=1)
-    # A parity failure is committed as evidence ({parity: false}, no
-    # speedup claim) instead of crashing the tool and losing the whole
-    # section's probe rows; the selection gate (rows_clear_bar)
-    # rejects the row, so compact ingress is never adopted on it.
+    # A parity failure is recorded ({parity: false}, no speedup
+    # claim) instead of crashing the tool and losing the whole
+    # section's probe rows.
     parity = counts_std == counts_cmp
     row = {
         "probe": "stream_ab",
@@ -214,13 +213,11 @@ PROBE_NAMES = ("latency", "h2d", "device_compute", "stream_ab")
 def commit_results(results, backend: str) -> None:
     """Merge this run's rows into the committed evidence under the
     same policy as tools/profile_kernels.py's flush: `ingress_ab`
-    carries ONLY the stream_ab rows (resolve_ingress's gate checks
-    parity+speedup on every row), the other probes land under
+    carries ONLY the stream_ab rows, the other probes land under
     `ingress_probes`; PERF.json updates only when its backend label
     matches the LIVE backend (a CPU run never overwrites chip-labeled
-    selections), while the per-backend archive PERF_<backend>.json
-    always takes the rows (ops/triangles._load_matching_perf reads it
-    when PERF.json belongs to the other backend). Only keys this run
+    rows), while the per-backend archive PERF_<backend>.json always
+    takes the rows. Only keys this run
     produced are replaced — a stream_ab-only run keeps the committed
     bandwidth/latency probes."""
     ab = [r for r in results if r.get("probe") == "stream_ab"]

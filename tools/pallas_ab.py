@@ -22,13 +22,10 @@ Timing is median-of-3 with min/max dispersion committed in the row
 load noise, not evidence). GS_AUTOTUNE is pinned OFF inside the
 probes so the kernel lever is measured in isolation.
 
-The committed rows are what ops/pallas_window.resolve_pallas_window
-gates on: parity true AND `speedup` ≥1.05 on EVERY row, or the XLA
-scan stands. On a CPU backend the kernel runs in INTERPRET mode —
-parity is real evidence there, speed is not (interpret rows
-committed from a CPU run can never honestly clear the bar, and the
-backend-matched loader keeps them from ever driving a chip
-selection). Commit policy identical to tools/resident_ab.py.
+The rows are a record: the runtime runs the megakernel only when
+GS_PALLAS_WINDOW=on pins it. On a CPU backend the kernel runs in
+INTERPRET mode — parity is real evidence there, speed is not.
+Commit policy identical to tools/resident_ab.py.
 
 --sweep drives the `pallas_window` DispatchTuner family (edge-tile ×
 K-chunk arms) through two full measurement passes and persists the

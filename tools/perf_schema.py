@@ -2,13 +2,12 @@
 """Lightweight schema check for PERF.json (and the per-backend
 archives PERF_<backend>.json).
 
-The committed evidence file drives the library's kernel
-auto-selection (ops/triangles._load_matching_perf and friends) AND
-the PERF.md renderer (tools/update_perf_md.py). A malformed section —
-a dict where a row list belongs, a parity-true row without a speedup,
-a degradation event missing its tiers — silently disables a selection
-or crashes the unattended renderer at the END of a chip window, which
-is exactly when raw output is lost. This validator is the cheap
+The committed record feeds the PERF.md renderer
+(tools/update_perf_md.py). A malformed section — a dict where a row
+list belongs, a parity-true row without a speedup, a degradation
+event missing its tiers — crashes the unattended renderer at the
+END of a chip window, which is exactly when raw output is lost.
+This validator is the cheap
 tier-1 guard (tests/test_perf_tooling.py) that new profiler sections
 can't break the contract unnoticed.
 
@@ -142,7 +141,6 @@ _COST_PROGRAM_KEYS = ("program", "sig", "flops", "bytes_accessed",
                       "bound", "dispatches")
 
 # A/B sections whose parity-true rows must claim a positive speedup
-# (the adoption gates divide by it; rows_clear_bar rejects otherwise)
 _AB_SECTIONS = ("ingress_ab", "egress_ab", "resident_ab",
                 "tenancy_ab", "pallas_ab", "pump_ab", "gnn_ab")
 

@@ -12,7 +12,7 @@ Sections (argv selects a subset; default: all single-chip):
   fused      — StreamSummaryEngine.process per-window ms (all four
                analytics fused; WindowGraphAggregation.java:54-58)
   dense      — XLA dense matmul vs Pallas fused contraction at
-               V = 1024/2048/4096 (drives the dense-path auto-select)
+               V = 1024/2048/4096
   sharded    — sharded engines on the virtual 8-device CPU mesh
                (run in a subprocess so the backend pin doesn't leak)
 
@@ -94,9 +94,8 @@ def section_intersect(results: dict) -> None:
         # Tile-shape sweep (VERDICT r4 item 6: one real iteration,
         # then decide). Candidates keep the [T, Ck, K] compare tensor
         # + three [T, K] input blocks under ~14MB of VMEM at K=256.
-        # The best parity-true row becomes BOTH the section's headline
-        # pallas_ms (what resolve_intersect_impl gates on) and the
-        # shape intersect_local_pallas ships (_resolve_tile).
+        # The best parity-true row becomes the section's headline
+        # pallas_ms.
         for tile_e, chunk_k in ((32, 64), (32, 128), (64, 64),
                                 (64, 128), (128, 64)):
             try:
@@ -188,14 +187,11 @@ def section_window(results: dict) -> None:
                "h2d_mb_per_chunk": round(num_w * eb * 2 * 4 / 1e6, 1),
                "k_sweep": []}
         # anchor the sweep on the ANALYTIC heuristic, never the tuned
-        # value a committed PERF.json may already inject into the
-        # kernel default — otherwise successive profiling runs ratchet
-        # K downward and can never re-explore larger values
+        # value, so successive profiling runs never ratchet K downward
         default_kb = min(128, 2 * int(np.sqrt(eb)))
         # the sweeps' chunk anchor: deterministic per (backend, eb) —
         # the compile-size-capped default on TPU backends
-        # (ops/triangles._default_chunk), the class default elsewhere. Same ratchet guard as K: committed picks
-        # never set the conditions the sweep measures under.
+        # (ops/triangles._default_chunk), the class default elsewhere.
         from gelly_streaming_tpu.ops.triangles import _default_chunk
 
         anchor_chunk = _default_chunk(eb)
@@ -227,10 +223,8 @@ def section_window(results: dict) -> None:
         # dispatch, cs=64 times two), else the biggest rows silently
         # re-time the same dispatch; reuse the k_sweep's compiled
         # kernel.
-        # same selection the runtime applies (_tuned_kb): the fastest
-        # MEASURED row wins outright — its timing already includes its
-        # own recount cost — so the chunk sweep times the K production
-        # actually runs
+        # the fastest MEASURED row wins outright — its timing already
+        # includes its own recount cost
         best_kb = min(row["k_sweep"],
                       key=lambda s: s["per_window_ms"])["k_bucket"]
         kern = kernels[best_kb]
@@ -593,14 +587,9 @@ def section_trace(results: dict) -> None:
 
 def section_host_stream(results: dict) -> None:
     """Vectorized numpy window tier vs the device (XLA) stream kernel
-    on THIS backend — the committed evidence `_resolve_stream_impl`
-    reads. On a CPU backend both forms run the same single core and
-    the rows drive the process-wide CPU fallback tier. On a chip the
-    rows drive PER-EDGE-BUCKET routing of production
-    count_stream/count_windows traffic (VERDICT r4 item 5: small
-    dispatch-latency-bound windows route to the measured host tier) —
-    so a chip row taken under host load mis-routes real traffic;
-    keep the chip's host quiet during this section."""
+    on THIS backend, per edge bucket. The rows are a record: the
+    streaming counter always runs the device program. Keep the
+    chip's host quiet during this section."""
     import jax
 
     from gelly_streaming_tpu.ops import host_triangles
@@ -733,9 +722,9 @@ def section_pipeline(results: dict) -> None:
 
 def section_host_reduce(results: dict) -> None:
     """Columnar windowed-reduce tiers (ops/windowed_reduce.py): device
-    segment kernels vs the vectorized host kernel, per monoid — the
-    committed evidence `_resolve_reduce_impl` reads (BASELINE config
-    #2's engine). Parity asserted row by row before timing."""
+    segment kernels vs the vectorized host kernel, per monoid
+    (BASELINE config #2's engine). Parity asserted row by row before
+    timing."""
     import numpy as np
 
     from gelly_streaming_tpu.ops.windowed_reduce import WindowedEdgeReduce
@@ -770,10 +759,7 @@ def section_host_reduce(results: dict) -> None:
             "host_vs_device": round(t_dev / t_host, 2),
         }
         if native.windowed_reduce_available():
-            # the C++ fused tier competes under the same committed-
-            # evidence rule (it currently LOSES to the per-window
-            # bincount form on this host — the honest row keeps it
-            # deselected)
+            # the C++ fused form, measured alongside
             nat = eng._native_process_stream(src, dst, val)
             row["native_parity"] = nat is not None and all(
                 (np.array_equal(nc[:nv], hc[:nv]) if name == "sum"
@@ -983,11 +969,10 @@ print(json.dumps(out))
 
 
 def section_ingress_ab(results: dict) -> None:
-    """Stream-chunk wire-format A/B (ops/compact_ingress.py) — the
-    committed evidence `resolve_ingress` reads, via the same probes as
-    the standalone tools/ingress_ab.py. `ingress_ab` carries ONLY the
-    stream A/B rows (the selection gate checks parity+speedup on every
-    row); the latency/bandwidth probes land under `ingress_probes`."""
+    """Stream-chunk wire-format A/B (ops/compact_ingress.py), via the
+    same probes as the standalone tools/ingress_ab.py. `ingress_ab`
+    carries ONLY the stream A/B rows; the latency/bandwidth probes
+    land under `ingress_probes`."""
     import jax
     import jax.numpy as jnp
 
@@ -1005,8 +990,8 @@ def section_ingress_ab(results: dict) -> None:
 
 
 def section_egress_ab(results: dict) -> None:
-    """d2h egress-format A/B (ops/delta_egress.py) — the committed
-    evidence `resolve_egress` reads, via the same probes as the
+    """d2h egress-format A/B (ops/delta_egress.py), via the same
+    probes as the
     standalone tools/egress_ab.py (exact parity asserted, median-of-3
     with dispersion committed). GS_AUTOTUNE is already pinned off for
     this child, so the egress lever is measured in isolation."""
@@ -1022,8 +1007,8 @@ def section_egress_ab(results: dict) -> None:
 
 
 def section_resident_ab(results: dict) -> None:
-    """Resident-tier A/B (ops/resident_engine.py) — the committed
-    evidence `resolve_resident` reads, via the same probes as the
+    """Resident-tier A/B (ops/resident_engine.py), via the same
+    probes as the
     standalone tools/resident_ab.py: the donated super-batch
     megakernel vs chunked scan vs per-window scan dispatch (driver
     and summary engine), exact parity asserted, median-of-3 with
@@ -1041,16 +1026,14 @@ def section_resident_ab(results: dict) -> None:
 
 
 def section_pallas_ab(results: dict) -> None:
-    """Fused-window-megakernel A/B (ops/pallas_window.py) — the
-    committed evidence `resolve_pallas_window` reads, via the same
-    probes as the standalone tools/pallas_ab.py: Pallas megakernel vs
+    """Fused-window-megakernel A/B (ops/pallas_window.py), via the
+    same probes as the standalone tools/pallas_ab.py: Pallas megakernel vs
     XLA scan-of-gathers through the summary engine AND the triangle
     stream kernel, sha256 window parity against the host twins,
     median-of-3 with dispersion. GS_AUTOTUNE is already pinned off
     for this child, so the kernel lever is measured in isolation. On
     a CPU backend the kernel runs interpreted: the parity half of the
-    row is real evidence, the speed half is not (and the
-    backend-matched loader keeps it from driving a chip selection)."""
+    row is real evidence, the speed half is not."""
     import jax
 
     from tools.pallas_ab import engine_pallas, stream_pallas
@@ -1642,9 +1625,8 @@ def section_gnn(results: dict) -> None:
 
 def section_host_snapshot(results: dict) -> None:
     """Batched snapshot-analytics tiers: the driver's device scan vs
-    the C++ carried union-find (native.snapshot_windows) — the
-    committed evidence core.driver.resolve_snapshot_tier reads.
-    Window-by-window parity asserted before timing; rates are whole
+    the C++ carried union-find (native.snapshot_windows), the
+    demotion ladder's native rung. Window-by-window parity asserted before timing; rates are whole
     run_arrays batches (intern + snapshot + materialize), reset
     between reps so carried state restarts identically."""
     import numpy as np
@@ -1686,8 +1668,8 @@ def section_host_snapshot(results: dict) -> None:
 
 PROBE_TIMEOUT_S = int(os.environ.get("GS_PROBE_TIMEOUT", "420"))
 
-# Candidate stream programs for the per-program compile caps
-# (ops/triangles.compile_cap). Triangle candidates try to RAISE the
+# Candidate stream programs for the compile cap
+# (ops/triangles.COMPILE_CAP). Triangle candidates try to RAISE the
 # 2^19 default (the chip chunk sweep was still climbing at the cap);
 # scan candidates BISECT the fused/snapshot wedge (both programs
 # stalled the remote compiler >2400s at sizes the triangle program
@@ -1718,7 +1700,7 @@ PROBE_CANDIDATES = {
 
 def run_compile_probe_child(program: str, eb: int, wb: int) -> None:
     """Compile (and for the scan programs, run once on a trivial
-    stream) ONE candidate shape, overriding the memoized cap so the
+    stream) ONE candidate shape, overriding the compile cap so the
     shape under test is actually built. Prints a single probe row;
     the orchestrating section's subprocess timeout converts a wedged
     remote compile into an ok=false row instead of a lost stage."""
@@ -1729,11 +1711,7 @@ def run_compile_probe_child(program: str, eb: int, wb: int) -> None:
     from gelly_streaming_tpu.ops import triangles as tri
 
     t0 = time.perf_counter()
-    tri._COMPILE_CAPS[program] = 1 << 30
-    if program.startswith("snapshot_scan"):
-        # the driver clamps its scan chunk by the BASE program's cap;
-        # the diagnostic variants must still build the shape under test
-        tri._COMPILE_CAPS["snapshot_scan"] = 1 << 30
+    tri.COMPILE_CAP = 1 << 30
     if program == "triangle_stream":
         k = tri.TriangleWindowKernel(edge_bucket=eb, vertex_bucket=2 * eb)
         k.MAX_STREAM_WINDOWS = wb
@@ -1787,11 +1765,10 @@ def _section_compile_probe(key: str, results: dict) -> None:
         if got.get("ok") and got.get("backend") == backend:
             row.update(ok=True, compile_s=got.get("compile_s"))
         elif "timeout" in err.lower():
-            # a timed-out compile is the evidence compile_cap LOWERS on
+            # a timed-out compile: the shape did not compile in time
             row.update(ok=False, reason=err[:200])
         else:
-            # crash mid-probe: inconclusive — never lower a cap over it
-            # (ok stays non-boolean, compile_cap ignores the row)
+            # crash mid-probe: inconclusive (ok stays non-boolean)
             row.update(ok=None,
                        reason=(err or "backend %s"
                                % got.get("backend"))[:200])
@@ -1803,21 +1780,22 @@ def _section_compile_probe(key: str, results: dict) -> None:
 def section_chunk_deep(results: dict) -> None:
     """Chunk sweep ABOVE the pre-probe compile cap. Runs after the
     compile_probe section in the same window: this child re-reads the
-    just-flushed PERF.json, so a clean probe row at 2^20 raises
-    capped_chunk here and the sweep measures windows-per-dispatch
-    depths the window section's anchor-bounded sweep could not reach
-    (r04: the chip sweep was still climbing — 962K edges/s at 16 —
-    when it hit the 2^19 cap). Rows land under `chunk_deep` and merge
-    into the runtime's chunk selection via
-    ops/triangles._fastest_sweep_row, so the queue's next bench
-    dispatches at the fastest measured depth."""
+    just-flushed PERF.json, and measures windows-per-dispatch depths
+    up to the compile cap that the window section's anchor-bounded
+    sweep did not reach (r04: the chip sweep was still climbing —
+    962K edges/s at 16 — when it hit the 2^19 cap). Rows land under
+    `chunk_deep`."""
     from gelly_streaming_tpu.ops import triangles as tri
 
+    try:
+        with open(os.path.join(REPO, "PERF.json")) as f:
+            perf = json.load(f)
+    except (OSError, ValueError):
+        perf = {}
     out = []
     for eb in (32_768, 8_192):
         vb = 2 * eb
-        cap_c = tri.capped_chunk(eb, "triangle_stream")
-        perf = tri._load_matching_perf() or {}
+        cap_c = tri.capped_chunk(eb)
         measured = [
             int(s["windows_per_dispatch"])
             for key in ("window", "chunk_deep")
@@ -1986,24 +1964,21 @@ def main():
         prior = None
 
     def flush():
-        # PERF.json drives the library's kernel auto-selection
-        # (ops/triangles._load_tpu_perf), so a profiling RUN must never
-        # degrade it:
+        # a profiling RUN must never degrade the committed record:
         #  - no successful section yet -> write PERF.json.partial only;
         #  - same backend as the existing file -> merge this run's
         #    successful sections over it (a subset or interrupted run
         #    keeps the other sections' committed measurements);
         #  - different backend -> replace only when THIS run is the
         #    chip ('tpu'); a CPU-fallback run never overwrites a
-        #    TPU-labeled file (it would silently deselect the measured
-        #    kernels).
+        #    TPU-labeled file.
         backend = results.get("backend")
-        # A failed section NEVER lands under its section key (library
-        # consumers iterate section rows and would crash/mislead on an
-        # {"error": ...} stub; _load_tpu_perf also filters these) —
-        # it is recorded under <name>_error, keeping any prior
-        # measurement. A same-backend prior file seeds the merge; any
-        # other prior is ignored here (the usable check below decides
+        # A failed section NEVER lands under its section key (readers
+        # iterate section rows and would crash/mislead on an
+        # {"error": ...} stub) — it is recorded under <name>_error,
+        # keeping any prior measurement. A same-backend prior file
+        # seeds the merge; any other prior is ignored here (the usable
+        # check below decides
         # whether this run may replace it at all).
         merged = (dict(prior) if prior is not None
                   and prior.get("backend") == backend else {})
@@ -2022,10 +1997,8 @@ def main():
         with open(path, "w") as f:
             json.dump(merged, f, indent=2)
         if ok_sections and backend:
-            # per-backend archive: this backend's selections must keep
-            # their committed rows even after the OTHER backend's
-            # profile run takes over PERF.json
-            # (ops/triangles._load_matching_perf falls back to it).
+            # per-backend archive: this backend's rows survive the
+            # OTHER backend's profile run taking over PERF.json.
             # Seeded from the EXISTING archive so a subset run (e.g.
             # host_stream only) keeps the other archived sections.
             arch_path = os.path.join(REPO, "PERF_%s.json" % backend)

@@ -24,12 +24,10 @@ Timing is median-of-3 with min/max dispersion committed in the row
 load noise, not evidence). GS_AUTOTUNE is pinned OFF inside the
 probes so the residency lever is measured in isolation.
 
-The committed `resident_ab` rows are what ops/resident_engine.
-resolve_resident gates on: parity true AND the resident rate ≥1.05×
-the best committed alternative (scan AND native) on EVERY driver row,
-or the resolved tier stands. `speedup` in the row is resident vs
+The rows are a record: the runtime selects the resident tier only
+when GS_RESIDENT=on pins it. `speedup` in the row is resident vs
 PER-WINDOW dispatch (the wall the megakernel kills);
-`speedup_vs_scan` is the adoption-relevant ratio. Commit policy
+`speedup_vs_scan` is the ratio against the default tier. Commit policy
 identical to tools/egress_ab.py (PERF.json only when backend-matched,
 PERF_<backend>.json always).
 """

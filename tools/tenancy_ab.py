@@ -24,18 +24,13 @@ Four probes, each a JSON row:
   cohort_resident — the resident-cohort tier (GS_COHORT_RESIDENT=on):
               the donated [N, ...] stacked-carry super-batch program
               vs the same N-sequential per-window oracle, one row per
-              N in {1, 3, 8} at the serving shape. These rows are the
-              tier's adoption evidence (resident_engine.
-              resolve_resident_cohort reads them through the
-              rows_clear_bar gate); the N=1 row is committed precisely
-              BECAUSE its speedup is ~1.0 — it keeps auto adoption
-              honest on backends where one tenant gains nothing.
+              N in {1, 3, 8} at the serving shape; the N=1 row shows
+              where one tenant gains nothing.
   cohort_pallas — the tenant-axis Pallas megakernel
               (GS_COHORT_PALLAS=on). Off-TPU this runs in interpret
-              mode and the row carries `interpret: true`;
-              pallas_window.resolve_cohort_pallas ignores interpret
-              rows for adoption, so these rows are PARITY evidence
-              (per-tenant sha256 vs the oracle), not speed evidence.
+              mode and the row carries `interpret: true`: these
+              rows are PARITY evidence (per-tenant sha256 vs the
+              oracle), not speed evidence.
 
 Timing is median-of-3 with min/max dispersion in the row (the ingress
 A/B's flip-flop taught us a single draw is load noise). GS_AUTOTUNE
@@ -153,8 +148,7 @@ def oracle_cached(streams, eb, vb, per_window: bool):
 
 class scoped_env:
     """Pin GS_* knobs for one probe side and restore afterwards,
-    resetting the memoised cohort-tier resolvers so the pin is seen
-    (resolve_* caches the auto decision per process)."""
+    forgetting the Pallas probe verdicts so the pin is re-probed."""
 
     def __init__(self, **pins):
         self.pins = pins
@@ -162,8 +156,6 @@ class scoped_env:
 
     def _reset(self):
         from gelly_streaming_tpu.ops import pallas_window
-        from gelly_streaming_tpu.ops import resident_engine
-        resident_engine._reset_resident_cohort()
         pallas_window._reset_pallas_window()
 
     def __enter__(self):
