@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from concurrent.futures import TimeoutError as _FutureTimeout
 from typing import List, Optional, Sequence
 
@@ -94,7 +95,9 @@ def snapshot_fold_body(vb: int, analytics: tuple, deltas: bool = False,
     single-chip scan (_build_snapshot_scan) and the mesh scan
     (parallel/sharded.make_sharded_snapshot_scan): body(carry, xs)
     folds one window (src, dst, valid) into the carried (degrees, cc
-    labels, double-cover labels) and emits that window's snapshots.
+    labels, double-cover labels) and emits that window's snapshots:
+    degrees and CC labels whole, the cover as the `[vb]` bool odd flag
+    (`odd`, what WindowResult hands out; the labels ride the carry).
     Cover layout: (+) = v, (−) = vb + v. Padding lanes go to each
     table's LAST slot, read from the carry's shapes: the single chip
     carries [vb+1] / [2vb+1] (sentinels vb, 2vb), the mesh engine
@@ -175,10 +178,11 @@ def snapshot_fold_body(vb: int, analytics: tuple, deltas: bool = False,
             (new_cover, outs["cover_rounds"],
              outs["cover_relabel_roots"]) = uf.cc_fold_rooted(
                 cover, s2, d2, seen2)
+            # the consumer-visible value is the odd flag, so the row
+            # read back, the mask and the delta wire all carry IT, not
+            # the raw labels (the chunk's final cover is the carry)
+            new_odd = new_cover[:vb] == new_cover[vb:2 * vb]
             if deltas or delta_out:
-                # the consumer-visible value is the odd flag, so the
-                # mask (and the delta wire) tracks IT, not raw labels
-                new_odd = new_cover[:vb] == new_cover[vb:2 * vb]
                 chg = new_odd != (cover[:vb] == cover[vb:2 * vb])
             if delta_out:
                 (outs["cover_cnt"], outs["cover_idx"],
@@ -187,7 +191,7 @@ def snapshot_fold_body(vb: int, analytics: tuple, deltas: bool = False,
             else:
                 if deltas:
                     outs["cover_chg"] = chg
-                outs["cover"] = new_cover
+                outs["odd"] = new_odd
             cover = new_cover
         return (deg, labels, cover), outs
 
@@ -221,11 +225,13 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
     count exceeding `cap` routes the chunk to the bit-exact host fold.
     The full masks are then NOT emitted (the wire subsumes them).
 
-    With `donate` (the RESIDENT tier, ops/resident_engine), the carry
-    argument is donated where the backend honors donation — the
-    ResidentState slabs update in place across super-batches instead
-    of being re-allocated per dispatch — and under delta egress the
-    final cover row is emitted as an explicit FRESH output
+    The chunk's final cover labels, which the host mirror resyncs
+    from, are the returned carry's: the driver reads them back once a
+    chunk as `cover_final`. With `donate` (the RESIDENT tier,
+    ops/resident_engine), the carry argument is donated where the
+    backend honors donation — the ResidentState slabs update in place
+    across super-batches instead of being re-allocated per dispatch —
+    so the program emits that cover as an explicit FRESH output
     (`cover_final`): the next super-batch donates the carry buffers,
     so the drain must never alias them."""
     import jax
@@ -233,7 +239,6 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
 
     body = snapshot_fold_body(vb, analytics, deltas=deltas, egress=egress,
                               cap=cap)
-    delta_out = egress == "delta"
     want_bip = "bipartite" in analytics
 
     if donate:
@@ -242,7 +247,7 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
         def run_fn(carry, s_w, d_w, valid_w):
             new_carry, outs = jax.lax.scan(body, carry,
                                            (s_w, d_w, valid_w))
-            if delta_out and want_bip:
+            if want_bip:
                 # explicit copy primitive — a folded-away no-op like
                 # `+ 0` would leave this the same HLO value as the
                 # carry's cover, whose donated buffer super-batch N+1
@@ -259,24 +264,48 @@ def _build_snapshot_scan(vb: int, analytics: tuple,
     return run
 
 
-def _readback_counters(outs: dict, windows: int) -> None:
+def _real_rows(outs: dict, windows: int) -> dict:
+    """A chunk's scan outputs cut on the device to its `windows` real
+    windows: the W-bucket's rows past them are sentinel windows that
+    no consumer reads, so they never cross the d2h. `cover_final`, one
+    row a chunk, passes whole. One jitted program per (out tree, real
+    rows), warmed with its W-bucket (_warm_scan_arm)."""
+    rows = {k: v for k, v in outs.items() if k != "cover_final"}
+    cut = _head_rows_fn()(rows, windows)
+    if "cover_final" in outs:
+        cut["cover_final"] = outs["cover_final"]
+    return cut
+
+
+@functools.lru_cache(maxsize=None)
+def _head_rows_fn():
+    import jax
+
+    return jax.jit(lambda rows, n: {k: v[:n] for k, v in rows.items()},
+                   static_argnums=1)
+
+
+def _readback_counters(outs: dict, windows: int,
+                       sentinel_rows: int) -> None:
     """The read-back's counters, from a chunk's materialized scan
-    outputs (no further sync): bytes brought back, and over the
-    chunk's `windows` real windows (rows past them are the W-bucket's
-    sentinel windows) the rounds the CC and double-cover folds took,
-    the moved roots with members they relabelled (`relabel_roots`),
-    and how many of the folds had more than the relabel's list holds
-    and gathered instead (`relabel_gathers`)."""
+    outputs (no further sync), all of whose per-window rows are the
+    chunk's `windows` real windows: bytes brought back, the
+    W-bucket's `sentinel_rows` left on the device (_real_rows), the
+    rounds the CC and double-cover folds took, the moved roots with
+    members they relabelled (`relabel_roots`), and how many of the
+    folds had more than the relabel's list holds and gathered instead
+    (`relabel_gathers`)."""
     telemetry.counter("driver.readback_bytes",
                       sum(v.nbytes for v in outs.values()),
                       windows=windows)
+    telemetry.counter("driver.readback_sentinel_rows", sentinel_rows,
+                      windows=windows)
     for key in ("cc_rounds", "cover_rounds"):
         if key in outs:
-            telemetry.counter("driver." + key,
-                              int(outs[key][:windows].sum()),
+            telemetry.counter("driver." + key, int(outs[key].sum()),
                               windows=windows)
-    moved = [outs[key][:windows] for key in ("cc_relabel_roots",
-                                             "cover_relabel_roots")
+    moved = [outs[key] for key in ("cc_relabel_roots",
+                                   "cover_relabel_roots")
              if key in outs]
     if moved:
         telemetry.counter("driver.relabel_roots",
@@ -927,20 +956,23 @@ class StreamingAnalyticsDriver:
                 self._scan_tuner_key(), {"wb": wbs}, {"wb": cap})
         return self._scan_tuner
 
-    def _warm_scan_arm(self, wb: int) -> None:
+    def _warm_scan_arm(self, take: int) -> None:
         """Compile (and execute once, on an all-padding stack against a
-        throwaway carry) the W-bucket program an arm needs BEFORE its
-        first measured chunk, so exploration never compiles — and the
-        warm run never touches carried state. Keyed like the scan
-        cache; re-warms after bucket growth invalidates it."""
+        throwaway carry) the W-bucket program a chunk of `take` windows
+        needs BEFORE its first measured chunk, with the cut of its
+        outputs to `take` real rows (_real_rows) when the bucket is
+        larger, so exploration never compiles — and the warm run never
+        touches carried state. Keyed like the scan cache; re-warms
+        after bucket growth invalidates it."""
+        import jax
         import jax.numpy as jnp
 
-        wb = seg_ops.bucket_size(wb)
+        wb = seg_ops.bucket_size(take)
         warmed = getattr(self, "_warmed_scan_arms", None)
         key3 = self._scan_key()
         if warmed is None or warmed[0] != key3:
             warmed = self._warmed_scan_arms = (key3, set())
-        if wb in warmed[1]:
+        if (wb, take) in warmed[1]:
             return
         # prime the program cache for THIS bucket (bypassing _scan_wb's
         # bigger-bucket reuse — the arm must compile its own size)
@@ -954,9 +986,11 @@ class StreamingAnalyticsDriver:
                  jnp.arange(2 * vb + 1, dtype=jnp.int32))
         s_w = jnp.full((wb, self.eb), vb, jnp.int32)
         valid = jnp.zeros((wb, self.eb), jnp.bool_)
-        out = fn(carry, s_w, s_w, valid)
-        np.asarray(out[0][0])  # block: the compile must finish here
-        warmed[1].add(wb)
+        _carry, outs = fn(carry, s_w, s_w, valid)
+        if take < wb:
+            outs = _real_rows(outs, take)
+        jax.block_until_ready(outs)  # the compiles must finish here
+        warmed[1].add((wb, take))
 
     def _scan_wb(self, num_w: int) -> int:
         """The W-bucket the snapshot scan will run `num_w` windows at
@@ -1455,16 +1489,9 @@ class StreamingAnalyticsDriver:
                                 np.int32)
                         res.delta_cc = _frozen_delta(
                             idx, res.cc_labels[idx])
-                if "cover" in outs:
-                    if "_odd_rows" in outs:  # native delta path: the
-                        # odd matrix was already computed for the mask
-                        res.bipartite_odd = _snapshot_view(
-                            outs["_odd_rows"][i][:nv], self.vb)
-                    else:
-                        plus = outs["cover"][i][:vb]
-                        minus = outs["cover"][i][vb:2 * vb]
-                        res.bipartite_odd = _snapshot_view(
-                            (plus == minus)[:nv])
+                if "odd" in outs:
+                    res.bipartite_odd = _snapshot_view(
+                        outs["odd"][i][:nv], self.vb)
                     if "cover_chg" in outs:
                         idx = np.nonzero(
                             outs["cover_chg"][i][:nv])[0].astype(
@@ -1482,7 +1509,8 @@ class StreamingAnalyticsDriver:
 
             # ---- chunk boundary: mirrors, cursors, checkpoint move
             # together. Mirror values come from the chunk's LAST
-            # window row (== the carry, no extra d2h).
+            # window row (== the carry, no extra d2h) and the cover's
+            # from the carry itself, read back once a chunk.
             if sharded and run_scan:
                 # engine.state_dict() is a full d2h sync — fetch it
                 # only for keys the scan did NOT produce (all enabled
@@ -1496,8 +1524,8 @@ class StreamingAnalyticsDriver:
                     else:
                         cur = cur or self._engine.state_dict()
                         st[key] = cur[key]
-                if "cover" in outs:
-                    st["bip_labels"] = outs["cover"][last]
+                if "cover_final" in outs:
+                    st["bip_labels"] = outs["cover_final"]
                 else:
                     cur = cur or self._engine.state_dict()
                     if "bip_labels" in cur:
@@ -1516,17 +1544,19 @@ class StreamingAnalyticsDriver:
                     self._deg_state = None  # per-window path: rebuild
                 if "labels" in outs:
                     self._cc = outs["labels"][last][:nv_chunk].copy()
-                if "cover" in outs:
-                    self._bip = outs["cover"][last][:2 * vb].copy()
+                if "cover_final" in outs:
+                    self._bip = outs["cover_final"][:2 * vb].copy()
             _boundary(at, chunk)
 
-        pending = None  # (at, chunk, device outs, superbatch stopwatch)
+        # (at, chunk, device outs, W-bucket sentinel rows, superbatch
+        # stopwatch)
+        pending = None
 
         def finalize_pending():
             nonlocal pending
             if pending is None:
                 return
-            f_at, f_chunk, f_outs, f_sw = pending
+            f_at, f_chunk, f_outs, f_sentinel, f_sw = pending
             pending = None
             with self._step("snapshot_wait",
                             sum(len(s) for _w, s, _d, _n in f_chunk)):
@@ -1540,7 +1570,7 @@ class StreamingAnalyticsDriver:
 
                 f_outs = resilience.call_guarded(
                     "finalize", f_at, _mat, retries=0)
-            _readback_counters(f_outs, len(f_chunk))
+            _readback_counters(f_outs, len(f_chunk), f_sentinel)
             # the dispatch boundary of this chunk's waterfall closes
             # with the materialize (device execute + d2h, observed)
             disp_t[0] = (latency.clock() if latency.enabled()
@@ -1796,18 +1826,24 @@ class StreamingAnalyticsDriver:
                                  else resilience.stage_retries()))
                     if scan_sp is not None:
                         scan_sp.attrs.update(disp_tags)
-                    if "cover_cnt" in outs \
+                    # the read-back carries what WindowResult hands
+                    # out: each window's odd flag, never its cover
+                    # labels, so the cover-label mirror resyncs from
+                    # the chunk's final cover, which IS the carry — one
+                    # [2vb+1] d2h per chunk. (The donated resident
+                    # program already emits a fresh cover_final —
+                    # aliasing the donated carry here would read a
+                    # consumed buffer.)
+                    if "bipartite" in self.analytics \
                             and "cover_final" not in outs:
-                        # delta egress ships odd-flag deltas, which
-                        # cannot resync the cover-label mirror; the
-                        # chunk's final cover IS the carry — one
-                        # [2vb+1] d2h per chunk instead of [W, 2vb].
-                        # (The donated resident program already emits
-                        # a fresh cover_final — aliasing the donated
-                        # carry here would read a consumed buffer.)
                         outs["cover_final"] = carry[2]
+                    # real rows only: cut on the device here, right
+                    # behind the scan, so the cut never waits on the
+                    # next chunk's dispatch
+                    if len(chunk) < wb:
+                        outs = _real_rows(outs, len(chunk))
                 finalize_pending()
-                pending = (at, chunk, outs, sw)
+                pending = (at, chunk, outs, wb - len(chunk), sw)
                 at += take
                 continue
             # only the device-scan branch (which `continue`s above)
@@ -1858,13 +1894,11 @@ class StreamingAnalyticsDriver:
             outs["labels_chg"] = (
                 outs["labels"] != np.concatenate(
                     [pl[None], outs["labels"][:-1]]))
-        if "cover" in outs:
-            odd = (outs["cover"][:, :self.vb]
-                   == outs["cover"][:, self.vb:])
+        if "odd" in outs:
+            odd = outs["odd"]
             podd = (pc[:self.vb] == pc[self.vb:])[None]
             outs["cover_chg"] = odd != np.concatenate(
                 [podd, odd[:-1]])
-            outs["_odd_rows"] = odd  # reused at extraction
 
     # ------------------------------------------------------------------
     # delta-compacted d2h egress (ops/delta_egress): decode + fallback

@@ -130,7 +130,7 @@ def _load() -> Optional[ctypes.CDLL]:
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
         ctypes.POINTER(ctypes.c_int32),
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
-        ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_uint8),
     ]
     _lib = lib
     return _lib
@@ -146,6 +146,10 @@ def _i64ptr(a: np.ndarray):
 
 def _i32ptr(a: np.ndarray):
     return a.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
+
+
+def _u8ptr(a: np.ndarray):
+    return a.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
 
 
 # ----------------------------------------------------------------------
@@ -349,10 +353,12 @@ def snapshot_windows(src: np.ndarray, dst: np.ndarray,
     windows). `deg`/`cc`/`cov` are the caller-owned carried arrays
     (int32 [vb], [vb], [2·vb] — the driver's host mirror layouts, so
     checkpoints stay tier-interchangeable), updated in place; pass
-    None to skip an analytic. Returns {"deg": [W, vb], "labels":
-    [W, vb], "cover": [W, 2·vb]} int32 snapshot stacks for the
-    enabled analytics (the scan tier's `outs` shape contract), or
-    None when the library/symbol is unavailable."""
+    None to skip an analytic. Returns {"deg": [W, vb] int32,
+    "labels": [W, vb] int32, "odd": [W, vb] bool} snapshot stacks for
+    the enabled analytics, the cover as its odd flag, plus the chunk's
+    final cover labels once as a fresh copy ({"cover_final": [2·vb]
+    int32}) — the scan tier's `outs` contract — or None when the
+    library/symbol is unavailable."""
     if not snapshot_available():
         return None
     src = np.ascontiguousarray(src, np.int32)
@@ -377,11 +383,11 @@ def snapshot_windows(src: np.ndarray, dst: np.ndarray,
              | (4 if cov is not None else 0))
     od = np.empty((num_w, vb), np.int32) if deg is not None else None
     oc = np.empty((num_w, vb), np.int32) if cc is not None else None
-    ov = (np.empty((num_w, 2 * vb), np.int32)
-          if cov is not None else None)
+    oo = np.empty((num_w, vb), bool) if cov is not None else None
     w = _lib.gs_snapshot_windows(
         _i32ptr(src), _i32ptr(dst), _i64ptr(offsets), num_w, vb, flags,
-        ptr(deg), ptr(cc), ptr(cov), ptr(od), ptr(oc), ptr(ov))
+        ptr(deg), ptr(cc), ptr(cov), ptr(od), ptr(oc),
+        _u8ptr(oo) if oo is not None else ctypes.POINTER(ctypes.c_uint8)())
     if w != num_w:
         # not an assert: a short write must fail under `python -O` too
         raise RuntimeError("native snapshot_windows wrote %d of %d "
@@ -391,8 +397,9 @@ def snapshot_windows(src: np.ndarray, dst: np.ndarray,
         out["deg"] = od
     if oc is not None:
         out["labels"] = oc
-    if ov is not None:
-        out["cover"] = ov
+    if oo is not None:
+        out["odd"] = oo
+        out["cover_final"] = cov.copy()
     return out
 
 

@@ -456,14 +456,16 @@ static inline void snap_union(int32_t* p, int32_t a, int32_t b) {
 // flags: bit0 degrees, bit1 cc, bit2 bipartite. Buffers for disabled
 // analytics may be null. Windows are [offsets[w], offsets[w+1])
 // slices of the flat COO arrays (varying lengths — the driver's
-// event-time windows). Snapshot rows: out_deg/out_cc [num_w, vb],
-// out_cov [num_w, 2*vb]. Returns the number of windows written.
+// event-time windows). Snapshot rows: out_deg/out_cc [num_w, vb]
+// int32, out_odd [num_w, vb] 0/1 bytes — the cover's odd flag, v and
+// vb + v in one cover component (the labels stay in `cov`). Returns
+// the number of windows written.
 int64_t gs_snapshot_windows(const int32_t* src, const int32_t* dst,
                             const int64_t* offsets, int64_t num_w,
                             int64_t vb, int32_t flags,
                             int32_t* deg, int32_t* cc, int32_t* cov,
                             int32_t* out_deg, int32_t* out_cc,
-                            int32_t* out_cov) {
+                            uint8_t* out_odd) {
     const bool want_deg = flags & 1, want_cc = flags & 2,
                want_cov = flags & 4;
     int64_t w = 0;
@@ -487,8 +489,8 @@ int64_t gs_snapshot_windows(const int32_t* src, const int32_t* dst,
         if (want_cov) {
             for (int64_t v = 0; v < 2 * vb; ++v)
                 cov[v] = snap_find(cov, (int32_t)v);
-            std::memcpy(out_cov + w * 2 * vb, cov,
-                        2 * vb * sizeof(int32_t));
+            uint8_t* odd = out_odd + w * vb;
+            for (int64_t v = 0; v < vb; ++v) odd[v] = cov[v] == cov[v + vb];
         }
     }
     return w;

@@ -2,7 +2,8 @@
 whole snapshot vectors.
 
 The batched snapshot scan (core/driver._build_snapshot_scan) d2h's a
-full [W, vb] int32 stack per analytic per chunk, and the windowed
+full [W, vb] stack per analytic per chunk (int32 degrees and labels,
+the bool odd flag), and the windowed
 reduce's monoid device tier a full [W, vb+1] cells+counts pair — even
 though the delta masks the scan already computes (emit_deltas) know
 how few entries actually changed, and a reduce window touches at most

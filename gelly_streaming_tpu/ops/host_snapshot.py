@@ -6,8 +6,10 @@ Same contract as native.snapshot_windows (which is itself shaped like
 the device scan's `outs`): window w is the [offsets[w], offsets[w+1])
 slice of the flat COO arrays; the caller-owned carried arrays
 (`deg`/`cc`/`cov`, the driver's host-mirror layouts) are updated in
-place; per-window int32 snapshot stacks come back as
-{"deg": [W, vb], "labels": [W, vb], "cover": [W, 2·vb]}.
+place; per-window snapshot stacks come back as {"deg": [W, vb] int32,
+"labels": [W, vb] int32, "odd": [W, vb] bool}, the cover as its odd
+flag (v and vb + v in one cover component), plus the chunk's final
+cover labels once, as a fresh copy, {"cover_final": [2·vb] int32}.
 
 Bit-exactness across tiers is by CONSTRUCTION, not coincidence: the
 carried min-label semantics (ops/unionfind.cc_fixpoint with
@@ -82,8 +84,7 @@ def snapshot_windows(src: np.ndarray, dst: np.ndarray,
     out: Dict[str, np.ndarray] = {}
     od = np.empty((num_w, vb), np.int32) if deg is not None else None
     oc = np.empty((num_w, vb), np.int32) if cc is not None else None
-    ov = (np.empty((num_w, 2 * vb), np.int32)
-          if cov is not None else None)
+    oo = np.empty((num_w, vb), bool) if cov is not None else None
     for w in range(num_w):
         lo, hi = int(offsets[w]), int(offsets[w + 1])
         s, d = src[lo:hi], dst[lo:hi]
@@ -97,11 +98,12 @@ def snapshot_windows(src: np.ndarray, dst: np.ndarray,
         if cov is not None:
             cov[:] = _fixpoint(cov, np.concatenate([s, s + vb]),
                                np.concatenate([d + vb, d]))
-            ov[w] = cov
+            np.equal(cov[:vb], cov[vb:], out=oo[w])
     if od is not None:
         out["deg"] = od
     if oc is not None:
         out["labels"] = oc
-    if ov is not None:
-        out["cover"] = ov
+    if oo is not None:
+        out["odd"] = oo
+        out["cover_final"] = cov.copy()
     return out

@@ -111,9 +111,15 @@ def test_mesh_scan_matches_control_and_single_chip(case):
     one_carry, one = _build_snapshot_scan(VB, ANALYTICS)(one_carry, *xs)
     got, ctl, one = ({k: np.asarray(v) for k, v in o.items()}
                      for o in (got, ctl, one))
-    for key, n in (("deg", VB), ("labels", VB), ("cover", 2 * VB)):
+    # the control reads its whole cover back; the scans, its odd flag
+    ctl["odd"] = ctl["cover"][:, :VB] == ctl["cover"][:, VB:2 * VB]
+    for key, n in (("deg", VB), ("labels", VB), ("odd", VB)):
         np.testing.assert_array_equal(got[key][:, :n], ctl[key][:, :n])
         np.testing.assert_array_equal(got[key][:, :n], one[key][:, :n])
+    assert "cover" not in got and got["odd"].shape == (W, VB)
+    # the final cover, read back from the carry once a chunk
+    np.testing.assert_array_equal(np.asarray(got_carry[2])[:2 * VB],
+                                  np.asarray(one_carry[2])[:2 * VB])
     for key in ("cc_rounds", "cover_rounds"):
         np.testing.assert_array_equal(got[key], one[key])
         assert np.all(got[key] <= ctl[key])

@@ -98,6 +98,14 @@ def test_driver_snapshot_scan_compiles_with_round_counts(one_chip):
     outs = jax.eval_shape(fn, *args)[1]
     for key in ("cc_rounds", "cover_rounds"):
         assert (outs[key].shape, outs[key].dtype) == ((w,), jnp.int32)
+    # the cover reads back as each window's odd flag, never its labels
+    assert (outs["odd"].shape, outs["odd"].dtype) == ((w, vb), jnp.bool_)
+    assert "cover" not in outs
+    # and the cut to a chunk's real rows compiles for the chip too
+    from gelly_streaming_tpu.core.driver import _head_rows_fn
+
+    rows = {k: _sds(v.shape, v.dtype, one_chip) for k, v in outs.items()}
+    _head_rows_fn().lower(rows, w // 2).compile()
 
 
 def test_intersect_pallas_compiles(one_chip, chip_lowering):

@@ -759,10 +759,95 @@ def test_readback_bytes_follow_the_output_shapes(recorder):
         vertex_bucket=vb, edge_bucket=eb, snapshot_tier="scan",
         egress="full")
     drv.run_arrays(src, dst)
-    # degrees and labels [vb+1], cover [2vb+1] int32, two int32 rounds
-    # and two int32 counts of relabelled roots
-    per_window = 4 * (4 * drv.vb + 3) + 16
-    assert _counters("driver.readback_bytes") == [(8 * per_window, 8)]
+    # a window: degrees and labels [vb+1] int32, the odd flag [vb]
+    # bool, two int32 rounds and two int32 counts of relabelled roots;
+    # a chunk: the final cover [2vb+1] int32, once
+    per_window = 4 * 2 * (drv.vb + 1) + drv.vb + 16
+    assert _counters("driver.readback_bytes") == [
+        (8 * per_window + 4 * (2 * drv.vb + 1), 8)]
+    assert _counters("driver.readback_sentinel_rows") == [(0, 8)]
+
+
+@pytest.mark.parametrize("tier,windows", [
+    ("scan", 4), ("scan", 7), ("resident", 4), ("mesh", 4)])
+def test_readback_leaves_sentinel_rows_on_the_device(recorder, tier,
+                                                     windows):
+    """A call of fewer windows than the smallest W-bucket (8 rows)
+    reads back its real rows only, on one chip, the resident tier and
+    the mesh (whose tables carry one more sentinel slot), and counts
+    the rows it left on the device; its windows are the host twin's."""
+    eb = 16
+    rng = np.random.default_rng(windows)
+    src = rng.integers(0, 40, windows * eb)
+    dst = rng.integers(0, 40, windows * eb)
+    kw = ({"mesh": make_mesh(4)} if tier == "mesh"
+          else {"snapshot_tier": tier, "egress": "full"})
+    drv = StreamingAnalyticsDriver(
+        window_ms=0, analytics=("degrees", "cc", "bipartite"),
+        vertex_bucket=64, edge_bucket=eb, **kw)
+    got = drv.run_arrays(src, dst)
+    pad = 2 if tier == "mesh" else 1
+    vb = drv.vb
+    per_window = 4 * 2 * (vb + pad) + vb + 16
+    assert _counters("driver.readback_bytes") == [
+        (windows * per_window + 4 * (2 * vb + pad), windows)]
+    assert _counters("driver.readback_sentinel_rows") == [
+        (8 - windows, windows)]
+    twin = StreamingAnalyticsDriver(
+        window_ms=0, analytics=("degrees", "cc", "bipartite"),
+        vertex_bucket=64, edge_bucket=eb, snapshot_tier="host")
+    want = twin.run_arrays(src, dst)
+    assert len(got) == len(want) == windows
+    for g, w in zip(got, want):
+        for field in ("degrees", "cc_labels", "bipartite_odd"):
+            np.testing.assert_array_equal(getattr(g, field),
+                                          getattr(w, field))
+    np.testing.assert_array_equal(drv._bip, twin._bip)
+
+
+def test_multi_chunk_call_with_padded_tail_matches_host_tier(
+        recorder, monkeypatch):
+    """A call of 8 + 8 + 5 windows, the last chunk padded to its 8-row
+    W-bucket, on the scan tier and on the host twin: every window's
+    degrees, labels and odd flags are equal, with their dtypes and
+    result digests; every array is read-only; and the mirrors after
+    the call (degrees, labels, the cover from the last chunk's final
+    carry) are the twin's."""
+    from gelly_streaming_tpu.utils import provenance
+
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_SCAN_CHUNK", 8)
+    eb, windows = 16, 21
+    rng = np.random.default_rng(29)
+    src = rng.integers(0, 120, windows * eb)
+    dst = rng.integers(0, 120, windows * eb)
+    out = {}
+    drivers = {}
+    for tier in ("scan", "host"):
+        drv = StreamingAnalyticsDriver(
+            window_ms=0, analytics=("degrees", "cc", "bipartite"),
+            vertex_bucket=64, edge_bucket=eb, snapshot_tier=tier,
+            egress="full")
+        out[tier] = drv.run_arrays(src, dst)
+        drivers[tier] = drv
+    assert _counters("driver.readback_sentinel_rows") == [
+        (0, 8), (0, 8), (3, 5)]
+    assert len(out["scan"]) == len(out["host"]) == windows
+    for got, want in zip(out["scan"], out["host"]):
+        for field in ("vertex_ids", "degrees", "cc_labels",
+                      "bipartite_odd"):
+            a, b = getattr(got, field), getattr(want, field)
+            assert a.dtype == b.dtype, field
+            np.testing.assert_array_equal(a, b)
+            assert not a.flags.writeable and not b.flags.writeable
+        assert got.degrees.dtype == np.int64
+        assert got.bipartite_odd.dtype == bool
+        assert provenance.result_digest(got) == \
+            provenance.result_digest(want)
+    scan, host = drivers["scan"], drivers["host"]
+    for mirror in ("_degrees", "_cc", "_bip"):
+        np.testing.assert_array_equal(getattr(scan, mirror),
+                                      getattr(host, mirror))
+        assert getattr(scan, mirror).dtype == getattr(host, mirror).dtype
 
 
 def _np_relabels(table, s, d, seen_slot):
@@ -833,7 +918,9 @@ def test_relabel_counters_match_numpy(recorder, monkeypatch, layout, k_max):
 def test_snapshot_scan_matches_host_snapshot_with_sentinel_windows():
     """The scan over a [W, eb] stack whose rows include all-invalid
     (sentinel) windows and a ragged one gives the host twin's
-    snapshots window for window, from a carry the twin folded."""
+    snapshots window for window, the cover as its odd flag, from a
+    carry the twin folded; its final carry is the twin's final cover
+    (`cover_final`), its sentinel slots untouched."""
     import jax.numpy as jnp
 
     from gelly_streaming_tpu.core.driver import _build_snapshot_scan
@@ -855,17 +942,21 @@ def test_snapshot_scan_matches_host_snapshot_with_sentinel_windows():
     run = _build_snapshot_scan(vb, ("degrees", "cc", "bipartite"))
     carry = (jnp.asarray(np.append(deg, 0)), jnp.asarray(np.append(lab, vb)),
              jnp.asarray(np.append(cov, 2 * vb)))
-    _, outs = run(carry, jnp.asarray(s_w), jnp.asarray(d_w),
-                  jnp.asarray(valid))
+    new_carry, outs = run(carry, jnp.asarray(s_w), jnp.asarray(d_w),
+                          jnp.asarray(valid))
     offs = np.concatenate([[0], np.cumsum(valid.sum(1))])
     want = host_snapshot.snapshot_windows(s_w[valid], d_w[valid], offs, vb,
                                           deg, lab, cov)
-    for key, n in (("deg", vb), ("labels", vb), ("cover", 2 * vb)):
+    for key, n in (("deg", vb), ("labels", vb), ("odd", vb)):
         np.testing.assert_array_equal(np.asarray(outs[key])[:, :n],
                                       want[key])
+    assert "cover" not in outs
+    assert np.asarray(outs["odd"]).shape == (w, vb)
+    assert np.asarray(outs["odd"]).dtype == want["odd"].dtype == bool
     np.testing.assert_array_equal(np.asarray(outs["labels"])[:, vb], vb)
-    np.testing.assert_array_equal(np.asarray(outs["cover"])[:, 2 * vb],
-                                  2 * vb)
+    cover_final = np.asarray(new_carry[2])
+    np.testing.assert_array_equal(cover_final[:2 * vb], want["cover_final"])
+    assert cover_final[2 * vb] == 2 * vb
     for key in ("cc_rounds", "cover_rounds"):
         assert list(np.asarray(outs[key])[[2, 5]]) == [1, 1]
 
