@@ -447,6 +447,9 @@ class StreamingAnalyticsDriver:
         self._engine = None       # sharded: ShardedWindowEngine
         self._sh_tri = None       # sharded: ShardedTriangleWindowKernel
         self._tri_pending = None  # batched-dispatch collector (transient)
+        # handle of the triangle stack dispatched ahead of the last
+        # read-back (_triangles_ahead); None = counted at the flush
+        self._tri_ahead = None
         self.windows_done = 0     # survives checkpoints: resume cursor
         self.edges_done = 0       # count-based window_start offset
         self._closed_partial = False  # count-based misuse guard
@@ -1331,6 +1334,9 @@ class StreamingAnalyticsDriver:
         the call re-enterable after a mid-stream tier demotion."""
         import jax.numpy as jnp
 
+        # a stack a failed entry dispatched ahead covers windows this
+        # entry re-runs: drop it, so each window is counted once
+        self._tri_ahead = None
         if not interned:
             return
         vb = self.vb
@@ -1384,6 +1390,15 @@ class StreamingAnalyticsDriver:
             cov0[:len(self._bip)] = self._bip
             carry = (jnp.asarray(deg0), jnp.asarray(lab0),
                      jnp.asarray(cov0))
+        # the device-scan branch with full egress puts the call's
+        # triangle stack on the device behind its last scan chunk
+        # (_triangles_ahead): the flush's windows from before this
+        # entry lead it. The native and host tiers, and the delta
+        # emit, leave the count to the flush.
+        tri_lead = (len(self._tri_pending)
+                    if self._tri_pending is not None and run_scan
+                    and native_state is None
+                    and self._scan_egress() == "full" else None)
 
         num_w = len(interned)
         scan_chunk = self._chunk_cap()
@@ -1842,6 +1857,8 @@ class StreamingAnalyticsDriver:
                     # next chunk's dispatch
                     if len(chunk) < wb:
                         outs = _real_rows(outs, len(chunk))
+                if tri_lead is not None and at + take >= num_w:
+                    self._triangles_ahead(tri_lead, interned)
                 finalize_pending()
                 pending = (at, chunk, outs, wb - len(chunk), sw)
                 at += take
@@ -2077,14 +2094,18 @@ class StreamingAnalyticsDriver:
     @contextlib.contextmanager
     def _batched_triangles(self):
         """Collect the enclosed windows' triangle work and flush it as
-        one batched count_windows dispatch. Flushes only on clean exit:
-        an exception mid-call leaves the incomplete windows' `triangles`
-        None rather than counts for windows the caller never saw."""
+        one batched count_windows dispatch, or read back the stack
+        _triangles_ahead dispatched. Flushes only on clean exit: an
+        exception mid-call leaves the incomplete windows' `triangles`
+        None rather than counts for windows the caller never saw.
+        Each flush adds the windows the stack dispatched ahead counted
+        to `driver.triangles_ahead`, over the windows it flushed."""
         if "triangles" not in self.analytics \
                 or self._tri_pending is not None:
             yield
             return
         self._tri_pending = []
+        self._tri_ahead = None
         try:
             yield
             pending = self._tri_pending
@@ -2092,25 +2113,61 @@ class StreamingAnalyticsDriver:
                 edges = sum(len(s) for _r, s, _d in pending)
                 windows = [(s, d) for _r, s, d in pending]
                 with self._step("triangles", edges):
-                    counts = self._flush_triangle_windows(windows)
+                    counts, ahead = self._flush_triangle_windows(
+                        windows, self._tri_ahead)
                 for (res, _s, _d), c in zip(pending, counts):
                     res.triangles = c
+                telemetry.counter("driver.triangles_ahead", ahead,
+                                  windows=len(pending))
         finally:
             self._tri_pending = None
+            self._tri_ahead = None
 
-    def _flush_triangle_windows(self, windows) -> list:
+    def _triangles_ahead(self, lead: int, interned) -> None:
+        """Put the call's whole triangle stack on the device behind
+        its last scan chunk, before the host waits on that chunk's
+        read-back: the flush's first `lead` windows (finalized before
+        this entry), then every window of `interned`. The device runs
+        the triangle program while the host waits on the scan's d2h
+        and extracts the windows; the flush reads the counts back. A
+        StageError of the dispatch is kept for the flush, whose ladder
+        takes it as the kernel's failure. The device-scan tiers never
+        run the host twin, so the kernel is always a device one."""
+        windows = ([(s, d) for _r, s, d in self._tri_pending[:lead]]
+                   + [(np.asarray(s, np.int32), np.asarray(d, np.int32))
+                      for _w, s, d, _n in interned])
+        try:
+            self._tri_ahead = self._tri_kern().dispatch_windows(windows)
+        except resilience.StageError as e:
+            self._tri_ahead = e
+
+    def _flush_triangle_windows(self, windows, ahead=None) -> tuple:
         """Count the flush's windows down the SAME demotion ladder as
         the snapshot stage: the sharded kernel while the mesh lives,
         the single-chip device kernel after a mesh demotion, the
         pure-numpy twin once the device itself is gone — each rung's
         typed stage failure demotes and the next rung recounts only
         the windows the failed rung had not finalized (the sharded
-        kernel drains its finalized counts)."""
+        kernel drains its finalized counts). `ahead` is the handle
+        _triangles_ahead left for the leading windows: the first rung,
+        the kernel that dispatched it, reads it back instead of
+        counting them; a StageError in its place is that rung's
+        failure. Returns (counts, the windows read back from
+        `ahead`)."""
         done: list = []
+        early = 0
         while True:
             kern = self._tri_kern()
             try:
-                return done + kern.count_windows(windows[len(done):])
+                if ahead is not None:
+                    handle, ahead = ahead, None
+                    if isinstance(handle, resilience.StageError):
+                        raise handle
+                    done = kern.collect_windows(handle)
+                    early = len(done)
+                rest = windows[len(done):]
+                return done + (kern.count_windows(rest) if rest
+                               else []), early
             except resilience.StageError as e:
                 tier = ("sharded"
                         if kern is self._sh_tri and self._mesh_live()

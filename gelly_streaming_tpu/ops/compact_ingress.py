@@ -1,7 +1,7 @@
 """Compact h2d ingress for batched window dispatches.
 
 The standard stream-chunk format (seg_ops.window_stack →
-TriangleWindowKernel._run_stack) ships 9 bytes per edge-slot to the
+TriangleWindowKernel._run_stack_loop) ships 9 bytes per edge-slot to the
 device: src int32 + dst int32 + valid bool. Two structural facts make
 a 4-bytes/slot form lossless:
 

@@ -36,6 +36,10 @@ Stages, per chunk of windows:
                 overflow recounts), so the d2h round trip of chunk i
                 hides behind chunk i+1's execution — the same depth-2
                 discipline as before, now with a parallel front end.
+                `dispatch_ahead` splits the loop at its read-back
+                instead: every chunk dispatched now, all finalized
+                when the caller asks (the driver's triangle stack,
+                queued behind its scan before the scan's d2h wait).
 
 Per-stage wall time accumulates in a `StageTimers` (prep/h2d/compute
 ms per chunk) that tools/profile_kernels.py commits to PERF.json, so
@@ -460,6 +464,103 @@ def _future_wait(fut, t: Optional[float]) -> bool:
     return True
 
 
+def _chunk_ctx(item):
+    """Chunk span handle (telemetry): stage spans of this chunk —
+    including the pool worker's prep/h2d — parent to it; closed when
+    the chunk's finalize lands. None when disarmed."""
+    return telemetry.chunk_ctx(telemetry.chunk_key(item))
+
+
+def _dispatch_stage(dispatch: Callable, item, dev, tctx, guard: bool):
+    """The dispatch stage of one chunk: its fault site, guard and
+    span. Returns dispatch(dev)'s raw outputs."""
+    # the wrapped program called inside dispatch() binds its
+    # program/signature tags (utils/costmodel) in the TLS of
+    # WHICHEVER thread runs it — under an armed stage watchdog that
+    # is the gs-stage-watchdog helper, not this thread — so the tags
+    # are captured inside the callable and carried back through the
+    # closure for the span record below
+    disp_tags = {}
+
+    def _call():
+        faults.fire("dispatch")
+        out = dispatch(dev)
+        disp_tags.update(telemetry.pop_dispatch_tags())
+        return out
+
+    par, ck = _span_cell({"tctx": tctx}, item)
+    t0 = time.perf_counter()
+    telemetry.pop_dispatch_tags()  # drop any stale pre-dispatch tag
+    # dispatch is retries=0 too: engines fold the chunk into a
+    # device-resident carry inside it, so re-running would
+    # double-fold the chunk
+    with telemetry.trace_scope("ingress.dispatch", chunk=ck):
+        raw = (resilience.call_guarded("dispatch", item, _call,
+                                       retries=0)
+               if guard else _call())
+    telemetry.record_span("ingress.dispatch", t0,
+                          time.perf_counter() - t0, parent=par,
+                          chunk=ck, **disp_tags)
+    return raw
+
+
+def _finalize_stage(finalize: Callable, item, raw, tctx,
+                    timers: Optional[StageTimers], guard: bool) -> None:
+    """The finalize stage of one chunk: its fault site, guard, timer
+    and span; closes the chunk span."""
+    par, ck = _span_cell({"tctx": tctx}, item)
+    t0 = time.perf_counter()
+
+    def _call():
+        faults.fire("finalize")
+        finalize(raw)
+
+    with telemetry.trace_scope("ingress.finalize", chunk=ck):
+        if guard:
+            # deadline only, NEVER retried: finalize mutates consumer
+            # state (appends results, advances carried mirrors), so a
+            # re-run would double-apply; a hang still surfaces as a
+            # typed StageTimeout instead of stalling the stream
+            resilience.call_guarded("finalize", item, _call, retries=0)
+        else:
+            _call()
+    dt = time.perf_counter() - t0
+    if timers is not None:
+        timers.add("compute", dt)
+        timers.chunks += 1
+    telemetry.record_span("ingress.finalize", t0, dt, parent=par,
+                          chunk=ck)
+    telemetry.close_chunk(tctx)
+
+
+def dispatch_ahead(items: Iterable, prep: Callable, h2d: Callable,
+                   dispatch: Callable, finalize: Callable,
+                   timers: Optional[StageTimers] = None) -> Callable:
+    """run_pipeline split at its read-back: prep, h2d and dispatch
+    every item now, finalize them later. For a caller that has other
+    host work to run under the queued programs before it reads them
+    back. Stages run inline on the calling thread, in item order,
+    under run_pipeline's fault sites, guards and spans. Returns the
+    read-back: a call with no arguments that finalizes each item in
+    order; an error stops it at the failing item, those before it
+    finalized."""
+    guard = resilience.guard_active()
+    flights = []
+    for item in items:
+        tctx = _chunk_ctx(item)
+        cell = {"tctx": tctx}
+        dev = (_guarded_prep_h2d(prep, h2d, item, timers, first_cell=cell)
+               if guard else _prep_then_h2d(prep, h2d, item, timers, cell))
+        flights.append(
+            (item, _dispatch_stage(dispatch, item, dev, tctx, guard), tctx))
+
+    def read_back() -> None:
+        for item, raw, tctx in flights:
+            _finalize_stage(finalize, item, raw, tctx, timers, guard)
+
+    return read_back
+
+
 def run_pipeline(items: Iterable, prep: Callable, h2d: Callable,
                  dispatch: Callable, finalize: Callable,
                  timers: Optional[StageTimers] = None,
@@ -504,69 +605,12 @@ def run_pipeline(items: Iterable, prep: Callable, h2d: Callable,
     guard = resilience.guard_active()
     futures = ()
 
-    def _ctx(it):
-        # chunk span handle (telemetry): stage spans of this chunk —
-        # including the pool worker's prep/h2d — parent to it; closed
-        # when the chunk's finalize lands. None when disarmed.
-        return telemetry.chunk_ctx(telemetry.chunk_key(it))
-
     def _finalize(item, raw, tctx=None):
-        par, ck = _span_cell({"tctx": tctx}, item)
-        t0 = time.perf_counter()
-
-        def _call():
-            faults.fire("finalize")
-            finalize(raw)
-
-        with telemetry.trace_scope("ingress.finalize", chunk=ck):
-            if guard:
-                # deadline only, NEVER retried: finalize mutates
-                # consumer state (appends results, advances carried
-                # mirrors), so a re-run would double-apply; a hang
-                # still surfaces as a typed StageTimeout instead of
-                # stalling the stream
-                resilience.call_guarded("finalize", item, _call,
-                                        retries=0)
-            else:
-                _call()
-        dt = time.perf_counter() - t0
-        if timers is not None:
-            timers.add("compute", dt)
-            timers.chunks += 1
-        telemetry.record_span("ingress.finalize", t0, dt, parent=par,
-                              chunk=ck)
-        telemetry.close_chunk(tctx)
+        _finalize_stage(finalize, item, raw, tctx, timers, guard)
 
     def _consume(item, dev, tctx=None):
         nonlocal pending
-
-        # the wrapped program called inside dispatch() binds its
-        # program/signature tags (utils/costmodel) in the TLS of
-        # WHICHEVER thread runs it — under an armed stage watchdog
-        # that is the gs-stage-watchdog helper, not this thread — so
-        # the tags are captured inside the callable and carried back
-        # through the closure for the span record below
-        disp_tags = {}
-
-        def _call():
-            faults.fire("dispatch")
-            out = dispatch(dev)
-            disp_tags.update(telemetry.pop_dispatch_tags())
-            return out
-
-        par, ck = _span_cell({"tctx": tctx}, item)
-        t0 = time.perf_counter()
-        telemetry.pop_dispatch_tags()  # drop any stale pre-dispatch tag
-        # dispatch is retries=0 too: engines fold the chunk into a
-        # device-resident carry inside it, so re-running would
-        # double-fold the chunk
-        with telemetry.trace_scope("ingress.dispatch", chunk=ck):
-            raw = (resilience.call_guarded("dispatch", item, _call,
-                                           retries=0)
-                   if guard else _call())
-        telemetry.record_span("ingress.dispatch", t0,
-                              time.perf_counter() - t0, parent=par,
-                              chunk=ck, **disp_tags)
+        raw = _dispatch_stage(dispatch, item, dev, tctx, guard)
         if pending is not None:
             done_chunk, pending = pending, None
             _finalize(*done_chunk)
@@ -575,7 +619,8 @@ def run_pipeline(items: Iterable, prep: Callable, h2d: Callable,
     def _submit(it):
         # `submitted` anchors the queue-wait deadline: a task no
         # wedged-pool worker ever picks up must still time out
-        cell = {"submitted": time.perf_counter(), "tctx": _ctx(it)}
+        cell = {"submitted": time.perf_counter(),
+                "tctx": _chunk_ctx(it)}
         return (it, cell,
                 pool.submit(_prep_then_h2d, prep, h2d, it, timers,
                             cell))
@@ -583,7 +628,7 @@ def run_pipeline(items: Iterable, prep: Callable, h2d: Callable,
     try:
         if pool is None:
             for item in items:
-                tctx = _ctx(item)
+                tctx = _chunk_ctx(item)
                 cell = {"tctx": tctx}
                 dev = (_guarded_prep_h2d(prep, h2d, item, timers,
                                          first_cell=cell)

@@ -213,7 +213,7 @@ def segmented_reduce(reduce_fn: Callable, segment_ids: np.ndarray,
 
 def warm_stream_buckets(kernel) -> None:
     """AOT-compile every stream-chunk program a window kernel's
-    _run_stack can dispatch at its current configuration — the full
+    _run_stack_loop can dispatch at its current configuration — the full
     MAX_STREAM_WINDOWS chunk and each power-of-two ragged window
     bucket — via the kernel's own `_stream_exec(wb)` executable cache.
     Compile-only: nothing executes, so warming costs compile time, not

@@ -583,6 +583,18 @@ class TriangleWindowKernel:
         forces the single-threaded inline-prep form — same counts.
         """
         counts: list = []
+        ingress_pipeline.run_pipeline(
+            *self._stack_stages(num_w, make_chunk, recount, counts),
+            timers=self.stage_timers)
+        return counts
+
+    def _stack_stages(self, num_w: int, make_chunk, recount,
+                      counts: list) -> tuple:
+        """(items, prep, h2d, dispatch, finalize) of a stack's chunks
+        for the ingress pipeline, whole or split at its read-back
+        (count_windows): finalize extends `counts` by the chunk's
+        counts, each window whose hubs overflowed K recounted exactly
+        by `recount(w)`."""
 
         def prep(at):
             hi = min(at + self.MAX_STREAM_WINDOWS, num_w)
@@ -601,19 +613,17 @@ class TriangleWindowKernel:
         def finalize(raw):
             at, n, c_dev, o_dev = raw
             # np.array (not asarray): device outputs can be read-only
-            c, o = np.array(c_dev)[:n], np.array(o_dev)[:n]  # gslint: disable=host-sync (sanctioned finalize boundary: the chunk's ONE batched [W]-scalar d2h, pipelined one chunk behind dispatch)
+            c, o = np.array(c_dev)[:n], np.array(o_dev)[:n]  # gslint: disable=host-sync (sanctioned finalize boundary: the chunk's ONE batched [W]-scalar d2h, one chunk behind dispatch or after the caller's read-back point)
             _readback_counter(c_dev, o_dev)
             for w in np.nonzero(o)[0]:  # rare hub overflow: exact redo
                 c[w] = recount(at + int(w))
             counts.extend(int(x) for x in c)
 
-        ingress_pipeline.run_pipeline(
-            range(0, num_w, self.MAX_STREAM_WINDOWS),
-            prep, h2d, dispatch, finalize, timers=self.stage_timers)
-        return counts
+        return (range(0, num_w, self.MAX_STREAM_WINDOWS),
+                prep, h2d, dispatch, finalize)
 
-    def _run_stack(self, s, d, valid, get_window) -> list:
-        """Standard-format window stack through _run_stack_loop."""
+    def _std_chunks(self, s, d, valid):
+        """make_chunk(at, hi) over a standard-format [W, eb] stack."""
 
         def make_chunk(at, hi):
             sc, dc, vc, n = seg_ops.pad_window_chunk(
@@ -621,16 +631,11 @@ class TriangleWindowKernel:
                 self.vb)
             return (sc, dc, vc), n
 
-        def recount(w):
-            ws, wd = get_window(w)
-            return self.count(ws, wd, min_k=self.kb)
+        return make_chunk
 
-        return self._run_stack_loop(s.shape[0], make_chunk, recount)
-
-    def _run_stack_compact(self, num_w, s16, d16, nvalid,
-                           recount) -> list:
-        """Compact-format stacks (ops/compact_ingress prep) through
-        the SAME _run_stack_loop."""
+    def _compact_chunks(self, s16, d16, nvalid):
+        """make_chunk(at, hi) over compact-format stacks
+        (ops/compact_ingress prep)."""
         from . import compact_ingress
 
         def make_chunk(at, hi):
@@ -639,7 +644,7 @@ class TriangleWindowKernel:
                 self.eb)
             return (sc, dc, nv), n
 
-        return self._run_stack_loop(num_w, make_chunk, recount)
+        return make_chunk
 
     # ---- online autotuning (ops/autotune.py) -------------------------
 
@@ -684,7 +689,7 @@ class TriangleWindowKernel:
 
     def _run_stack_tuned(self, src: np.ndarray,
                          dst: np.ndarray) -> list:
-        """The autotuned twin of the _run_stack* paths: the stream is
+        """The autotuned twin of _run_stack_loop: the stream is
         folded in measurement ROUNDS of `autotune.round_chunks()`
         dispatch chunks each; the tuner picks each round's
         (windows-per-dispatch, K, ingress) arm, the round runs through
@@ -786,7 +791,7 @@ class TriangleWindowKernel:
             timers=self.stage_timers)
 
     def warm_chunks(self) -> None:
-        """Compile every stream-chunk program _run_stack can dispatch
+        """Compile every stream-chunk program _run_stack_loop can dispatch
         at the current K, so a streaming consumer (the driver) pays
         stream-program COMPILES at (re)build time, never mid-stream:
         the steady-state compile discipline tools/scale_run.py
@@ -833,37 +838,55 @@ class TriangleWindowKernel:
         if self.ingress == "compact":
             from . import compact_ingress
 
-            num_w, s16, d16, nv = compact_ingress.window_stack(
-                src, dst, eb)
-            return self._run_stack_compact(
-                num_w, s16, d16, nv,
-                lambda w: self.count(src[w * eb:(w + 1) * eb],
-                                     dst[w * eb:(w + 1) * eb],
-                                     min_k=self.kb))
-        num_w, s, d, valid = seg_ops.window_stack(src, dst, self.eb,
-                                                  sentinel=self.vb)
-        return self._run_stack(
-            s, d, valid,
-            lambda w: (src[w * eb:(w + 1) * eb], dst[w * eb:(w + 1) * eb]))
+            num_w, *stack = compact_ingress.window_stack(src, dst, eb)
+            make_chunk = self._compact_chunks(*stack)
+        else:
+            num_w, *stack = seg_ops.window_stack(src, dst, eb,
+                                                 sentinel=self.vb)
+            make_chunk = self._std_chunks(*stack)
+        return self._run_stack_loop(
+            num_w, make_chunk,
+            lambda w: self.count(src[w * eb:(w + 1) * eb],
+                                 dst[w * eb:(w + 1) * eb], min_k=self.kb))
+
+    def dispatch_windows(self, windows):
+        """The dispatch half of count_windows: pad the (src, dst)
+        window batches of varying lengths (each ≤ edge_bucket) into
+        one stack, then prep, send and dispatch each chunk of it,
+        reading nothing back. Returns the handle collect_windows
+        reads back."""
+        if self.ingress == "compact":
+            from . import compact_ingress
+
+            make_chunk = self._compact_chunks(
+                *compact_ingress.stack_window_list(windows, self.eb))
+        else:
+            make_chunk = self._std_chunks(*seg_ops.stack_window_list(
+                windows, self.eb, self.vb))
+        counts: list = []
+        read_back = ingress_pipeline.dispatch_ahead(
+            *self._stack_stages(
+                len(windows), make_chunk,
+                lambda w: self.count(*windows[w], min_k=self.kb),
+                counts),
+            timers=self.stage_timers)
+        return read_back, counts
+
+    def collect_windows(self, handle) -> list:
+        """The read-back half of count_windows: each chunk's [W] d2h,
+        in order, with the exact recount of every window whose hubs
+        overflowed K. Returns the counts in window order."""
+        read_back, counts = handle
+        read_back()
+        return counts
 
     def count_windows(self, windows) -> list:
         """Exact counts of a list of (src, dst) window batches of
         varying lengths (each ≤ edge_bucket), padded into one stack and
         dispatched in chunks — the batched form of calling count() per
-        window (used by the driver's event-time windows)."""
-        if not windows:
-            return []
-        if self.ingress == "compact":
-            from . import compact_ingress
-
-            s16, d16, nv = compact_ingress.stack_window_list(
-                windows, self.eb)
-            return self._run_stack_compact(
-                len(windows), s16, d16, nv,
-                lambda w: self.count(*windows[w], min_k=self.kb))
-        s, d, valid = seg_ops.stack_window_list(windows, self.eb,
-                                                self.vb)
-        return self._run_stack(s, d, valid, lambda w: windows[w])
+        window (used by the driver's event-time windows), in two halves
+        a caller may run apart (dispatch_windows, collect_windows)."""
+        return self.collect_windows(self.dispatch_windows(windows))
 
 
 def triangle_count(src: np.ndarray, dst: np.ndarray, num_vertices: int) -> int:

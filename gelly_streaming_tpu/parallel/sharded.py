@@ -741,10 +741,26 @@ class ShardedTriangleWindowKernel:
         `self.drained_counts` — the re-entry cursor a demoting caller
         (core/driver, the host twin hand-off) combines with, instead
         of recomputing delivered windows."""
-        sharding = self._chunk_sharding()
-        num_w = s.shape[0]
         counts: list = []
         self.drained_counts = None
+        try:
+            ingress_pipeline.run_pipeline(
+                *self._stack_stages(s, d, valid, get_window, counts),
+                timers=self.stage_timers)
+        except Exception:
+            self.drained_counts = list(counts)
+            raise
+        return counts
+
+    def _stack_stages(self, s, d, valid, get_window,
+                      counts: list) -> tuple:
+        """(items, prep, h2d, dispatch, finalize) of a [W, eb] stack's
+        chunks for the ingress pipeline, whole (_run_stack) or split at
+        its read-back (count_windows): finalize extends `counts` by the
+        chunk's counts, each overflowed window recounted down the
+        escalation ladder."""
+        sharding = self._chunk_sharding()
+        num_w = s.shape[0]
 
         def prep(at):
             hi = min(at + self.MAX_STREAM_WINDOWS, num_w)
@@ -780,15 +796,8 @@ class ShardedTriangleWindowKernel:
                     failed_cap=self.cap if int(b_ovf[w]) else 0)  # gslint: disable=host-sync (b_ovf is a host numpy array — materialized by the finalize gather above, no device sync)
             counts.extend(int(x) for x in c)
 
-        try:
-            ingress_pipeline.run_pipeline(
-                range(0, num_w, self.MAX_STREAM_WINDOWS),
-                prep, h2d, dispatch, finalize,
-                timers=self.stage_timers)
-        except Exception:
-            self.drained_counts = list(counts)
-            raise
-        return counts
+        return (range(0, num_w, self.MAX_STREAM_WINDOWS),
+                prep, h2d, dispatch, finalize)
 
     def count_stream(self, src: np.ndarray, dst: np.ndarray) -> list:
         """Exact counts of every tumbling `edge_bucket`-sized window,
@@ -819,21 +828,47 @@ class ShardedTriangleWindowKernel:
                             tier="sharded", mesh_shape=[self.n])
         return counts
 
+    def dispatch_windows(self, windows):
+        """The dispatch half of count_windows: pad the (src, dst)
+        window batches (each ≤ edge_bucket) into one stack, then prep,
+        send and dispatch each sharded chunk of it, reading nothing
+        back (nothing drained if it fails). Returns the handle
+        collect_windows reads back."""
+        s, d, valid = seg_ops.stack_window_list(windows, self.eb,
+                                                self.vb)
+        counts: list = []
+        self.drained_counts = None
+        with telemetry.span("sharded.stream", tier="sharded",
+                            engine="triangles", mesh=self.n,
+                            windows=len(windows),
+                            edges=sum(len(w[0]) for w in windows)):
+            read_back = ingress_pipeline.dispatch_ahead(
+                *self._stack_stages(s, d, valid, lambda w: windows[w],
+                                    counts),
+                timers=self.stage_timers)
+        return read_back, counts
+
+    def collect_windows(self, handle) -> list:
+        """The read-back half of count_windows: each chunk's gather,
+        in order, with the exact recount of overflowed windows. On an
+        escaping error the counts of the chunks read back before it
+        are stashed on `drained_counts`, as in _run_stack."""
+        read_back, counts = handle
+        self.drained_counts = None
+        try:
+            read_back()
+        except Exception:
+            self.drained_counts = list(counts)
+            raise
+        return counts
+
     def count_windows(self, windows) -> list:
         """Exact counts of a list of (src, dst) window batches of
         varying lengths (each ≤ edge_bucket) in chunked sharded
         dispatches — the multi-chip form of
         TriangleWindowKernel.count_windows (used by the driver's
-        batched event-time windows)."""
-        if not windows:
-            return []
-        s, d, valid = seg_ops.stack_window_list(windows, self.eb,
-                                                self.vb)
-        with telemetry.span("sharded.stream", tier="sharded",
-                            engine="triangles", mesh=self.n,
-                            windows=len(windows),
-                            edges=sum(len(w[0]) for w in windows)):
-            return self._run_stack(s, d, valid, lambda w: windows[w])
+        batched event-time windows), in the same two halves."""
+        return self.collect_windows(self.dispatch_windows(windows))
 
 
 # ----------------------------------------------------------------------

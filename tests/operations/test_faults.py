@@ -450,3 +450,163 @@ def test_checkpoint_policy_every_seconds_fake_clock(tmp_path):
     e = _driver()
     assert e.try_resume(str(tmp_path / "t.npz"))
     assert e.windows_done > 0
+
+
+# ----------------------------------------------------------------------
+# the call's triangle stack, dispatched ahead of the last read-back
+# ----------------------------------------------------------------------
+def _tri_stream(windows=8, seed=13):
+    rng = np.random.default_rng(seed)
+    return (rng.integers(0, 12, windows * 16),
+            rng.integers(0, 12, windows * 16))
+
+
+def _tri_driver(tier="scan"):
+    return StreamingAnalyticsDriver(window_ms=0, edge_bucket=16,
+                                    vertex_bucket=64, snapshot_tier=tier)
+
+
+def _exact(src, dst):
+    from gelly_streaming_tpu.ops.triangles import triangle_count_sparse
+
+    return [triangle_count_sparse(src[i:i + 16], dst[i:i + 16], 64)
+            for i in range(0, len(src), 16)]
+
+
+def _tri_spy(monkeypatch) -> dict:
+    """Windows the triangle kernels took: the device kernel's dispatch
+    and read-back halves (count_windows runs both), and the host
+    twin's count."""
+    from gelly_streaming_tpu.ops.triangles import TriangleWindowKernel
+    from gelly_streaming_tpu.parallel.host_twin import (
+        HostTriangleWindowKernel)
+
+    seen = {"dispatch": 0, "collect": 0, "host": 0}
+
+    def spy(cls, name, key, size):
+        real = getattr(cls, name)
+
+        def method(self, arg):
+            out = real(self, arg)
+            seen[key] += size(arg, out)
+            return out
+        monkeypatch.setattr(cls, name, method)
+
+    spy(TriangleWindowKernel, "dispatch_windows", "dispatch",
+        lambda windows, _out: len(windows))
+    spy(TriangleWindowKernel, "collect_windows", "collect",
+        lambda _handle, out: len(out))
+    spy(HostTriangleWindowKernel, "count_windows", "host",
+        lambda windows, _out: len(windows))
+    return seen
+
+
+def _ahead_counters():
+    from gelly_streaming_tpu.utils import telemetry
+
+    return [(r["value"], r["a"]["windows"]) for r in telemetry.records()
+            if r["t"] == "counter" and r["name"] == "driver.triangles_ahead"]
+
+
+@pytest.fixture
+def _recorder(monkeypatch):
+    from gelly_streaming_tpu.utils import telemetry
+
+    monkeypatch.setenv("GS_TELEMETRY", "1")
+    monkeypatch.delenv("GS_TRACE_DIR", raising=False)
+    monkeypatch.setenv("GS_AUTOTUNE", "0")
+    telemetry.reset()
+    yield
+    telemetry.reset()
+
+
+@pytest.mark.parametrize("site,on_call", [("finalize", 2),
+                                          ("dispatch", 3)])
+def test_failure_after_the_early_dispatch_leaves_triangles_none(
+        _recorder, monkeypatch, site, on_call):
+    """Two scan chunks a call, no demotion: the last chunk's read-back
+    (`finalize` 2, after the triangle stack went out) or the stack's
+    own dispatch (`dispatch` 3, behind the two scan chunks) fails. The
+    call raises; no window it made has a triangle count, and nothing
+    was read back. The next call counts its own windows ahead."""
+    os.environ["GS_TIER_DEMOTE"] = "0"
+    os.environ["GS_STAGE_TIMEOUT_S"] = "5"
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_SCAN_CHUNK", 4)
+    seen = _tri_spy(monkeypatch)
+    made = []
+    real = StreamingAnalyticsDriver._scan_interned
+
+    def scan_interned(self, interned, results, *args):
+        made.append(results)
+        return real(self, interned, results, *args)
+
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_scan_interned",
+                        scan_interned)
+    src, dst = _tri_stream()
+    drv = _tri_driver()
+    with faults.inject(faults.FaultSpec(site=site, on_call=on_call)):
+        with pytest.raises(resilience.StageFailed) as ei:
+            drv.run_arrays(src, dst)
+    assert ei.value.stage == site
+    (results,) = made
+    assert len(results) == drv.windows_done == (4 if site == "finalize"
+                                                else 8)
+    assert all(r.triangles is None for r in results)
+    assert seen["collect"] == seen["host"] == 0
+    assert seen["dispatch"] == (8 if site == "finalize" else 0)
+    assert _ahead_counters() == []
+    # the stack the failed call left behind is never read back
+    more = _tri_stream(windows=4, seed=14)
+    got = drv.run_arrays(*more)
+    assert [r.triangles for r in got] == _exact(*more)
+    assert _ahead_counters() == [(4, 4)]
+
+
+@pytest.mark.parametrize("tier", ["resident", "scan"])
+def test_demotion_reentry_counts_each_window_once(_recorder, monkeypatch,
+                                                  tier):
+    """The last chunk's read-back fails after the call's stack went
+    out; the call demotes and re-enters for the windows not finalized
+    (resident → scan, which dispatches a stack of its own; scan →
+    native, which counts at the flush). Every window is counted once,
+    exactly: the failed entry's stack is never read back."""
+    monkeypatch.setenv("GS_RESIDENT_SPB", "8")
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_SCAN_CHUNK", 4)
+    windows = 16 if tier == "resident" else 8
+    seen = _tri_spy(monkeypatch)
+    src, dst = _tri_stream(windows)
+    drv = _tri_driver(tier)
+    with faults.inject(faults.FaultSpec(site="finalize", on_call=2)):
+        got = drv.run_arrays(src, dst)
+    assert [(e["from"], e["to"]) for e in drv.demotion_log()] == [
+        (tier, "scan" if tier == "resident" else "native")]
+    assert [r.triangles for r in got] == _exact(src, dst)
+    assert seen["collect"] + seen["host"] == windows
+    if tier == "resident":
+        assert seen["dispatch"] == 2 * windows
+        assert _ahead_counters() == [(windows, windows)]
+    else:
+        assert seen["dispatch"] == windows
+        assert _ahead_counters() == [(0, windows)]
+
+
+@pytest.mark.parametrize("site,on_call", [("dispatch", 3),
+                                          ("finalize", 3)])
+def test_triangle_stack_failure_takes_the_ladder(_recorder, monkeypatch,
+                                                 site, on_call):
+    """With the guard armed, the stack's dispatch (behind the two scan
+    chunks) or its read-back (behind the two chunks' read-backs) fails
+    once: the flush's ladder demotes scan → native, and the host twin
+    counts every window once, exactly."""
+    os.environ["GS_STAGE_TIMEOUT_S"] = "5"
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_SCAN_CHUNK", 4)
+    seen = _tri_spy(monkeypatch)
+    src, dst = _tri_stream()
+    drv = _tri_driver()
+    with faults.inject(faults.FaultSpec(site=site, on_call=on_call)):
+        got = drv.run_arrays(src, dst)
+    assert [(e["from"], e["to"]) for e in drv.demotion_log()] == [
+        ("scan", "native")]
+    assert [r.triangles for r in got] == _exact(src, dst)
+    assert seen["host"] == 8 and seen["collect"] == 0
+    assert _ahead_counters() == [(0, 8)]

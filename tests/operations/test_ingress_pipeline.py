@@ -67,6 +67,36 @@ def test_run_pipeline_orders_and_lags_finalize():
                          "h2d_ms_per_chunk", "compute_ms_per_chunk"}
 
 
+def test_dispatch_ahead_splits_run_pipeline_at_its_read_back():
+    """dispatch_ahead dispatches every chunk, in order, and finalizes
+    none; its read-back then finalizes each in order. The fault sites
+    fire where they belong: `dispatch` while dispatching, `finalize`
+    only in the read-back, which stops at the failing chunk."""
+    from gelly_streaming_tpu.utils import faults
+
+    events = []
+    timers = ip.StageTimers()
+    stages = dict(
+        prep=lambda i: ("prep", i),
+        h2d=lambda p: ("dev", p[1]),
+        dispatch=lambda d: (events.append(("dispatch", d[1]))
+                            or ("raw", d[1])),
+        finalize=lambda r: events.append(("finalize", r[1])))
+    with faults.inject() as plan:
+        read_back = ip.dispatch_ahead(range(4), timers=timers, **stages)
+        assert events == [("dispatch", i) for i in range(4)]
+        assert plan.calls == {"prep": 4, "h2d": 4, "dispatch": 4}
+        read_back()
+    assert events[4:] == [("finalize", i) for i in range(4)]
+    assert plan.calls["finalize"] == 4 and timers.chunks == 4
+    del events[:]
+    read_back = ip.dispatch_ahead(range(4), **stages)
+    with faults.inject(faults.FaultSpec(site="finalize", on_call=3)):
+        with pytest.raises(faults.InjectedFault):
+            read_back()
+    assert events[4:] == [("finalize", 0), ("finalize", 1)]
+
+
 def test_run_pipeline_prep_error_carries_worker_traceback():
     """A prep failure surfaces as PrepError (a RuntimeError) whose
     message contains the WORKER'S formatted traceback — the frames

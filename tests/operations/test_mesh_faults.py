@@ -56,9 +56,9 @@ def _stream(num_w=8, seed=5):
 
 
 def _driver(mesh=None, **kw):
+    kw.setdefault("analytics", ("degrees", "cc", "triangles"))
     return StreamingAnalyticsDriver(
-        window_ms=0, edge_bucket=EB, vertex_bucket=VB,
-        analytics=("degrees", "cc", "triangles"), mesh=mesh, **kw)
+        window_ms=0, edge_bucket=EB, vertex_bucket=VB, mesh=mesh, **kw)
 
 
 def _key(results):
@@ -258,6 +258,55 @@ def test_sharded_triangle_kernel_drains_counts():
     drained = kern.drained_counts
     assert drained is not None and 0 < len(drained) < 8
     assert drained == want[:len(drained)]
+
+
+def test_sharded_collect_failure_hands_drained_counts_down(monkeypatch):
+    """The sharded triangle stack, dispatched whole and read back
+    later, fails at its second chunk's gather: the first chunk's counts
+    are drained to `drained_counts`. In the driver, where the stack went
+    out ahead of the call's last read-back, the flush's ladder demotes
+    to one chip and counts only the windows not drained."""
+    from gelly_streaming_tpu.ops.triangles import TriangleWindowKernel
+
+    _arm(retries="0")
+    monkeypatch.setattr(ShardedTriangleWindowKernel,
+                        "MAX_STREAM_WINDOWS", 2)
+    rng = np.random.default_rng(4)
+    windows = [(rng.integers(0, 40, 128).astype(np.int32),
+                rng.integers(0, 40, 128).astype(np.int32))
+               for _ in range(8)]
+    kern = ShardedTriangleWindowKernel(make_mesh(4), edge_bucket=128,
+                                       vertex_bucket=64)
+    want = kern.count_windows(windows)
+    handle = kern.dispatch_windows(windows)
+    with pytest.raises(resilience.StageError):
+        with faults.inject(faults.FaultSpec(site="shard_gather",
+                                            on_call=2, times=1 << 20)):
+            kern.collect_windows(handle)
+    assert kern.drained_counts == want[:2]
+
+    counted = []
+    real = TriangleWindowKernel.count_windows
+
+    def count_windows(self, ws):
+        counted.append(len(ws))
+        return real(self, ws)
+
+    monkeypatch.setattr(TriangleWindowKernel, "count_windows",
+                        count_windows)
+    src, dst = _stream()
+    full = ("degrees", "cc", "bipartite", "triangles")
+    want = _key(_driver(analytics=full).run_arrays(src, dst))
+    drv = _driver(mesh=make_mesh(4), analytics=full)
+    # gathers: the engine state the scan's carry enters from, then the
+    # stack's chunks
+    with faults.inject(faults.FaultSpec(site="shard_gather", on_call=3,
+                                        times=1 << 20)):
+        got = _key(drv.run_arrays(src, dst))
+    assert got == want
+    assert [(e["from"], e["to"]) for e in drv.demotion_log()] == [
+        ("sharded", "scan")]
+    assert counted[-1] == 6
 
 
 def test_dead_gather_still_demotes_off_the_mirrors():

@@ -235,3 +235,37 @@ def _loop_bodies(lowered):
         if "stablehlo.scatter" in names:
             out.append(" ".join(names).replace("stablehlo.", ""))
     return out
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["full", "ragged"])
+@pytest.mark.parametrize("max_w", [2, 64], ids=["chunks", "one_chunk"])
+@pytest.mark.parametrize("layout", ["one_chip", "mesh"])
+def test_triangle_halves_equal_count_windows(monkeypatch, layout, max_w,
+                                             ragged):
+    """dispatch_windows then collect_windows, with another stack
+    dispatched and read back between them, gives count_windows'
+    counts, count_stream's and the exact host count, in one chunk or
+    several, on one chip and on the mesh; a ragged final window too."""
+    from gelly_streaming_tpu.ops import triangles as tri_ops
+
+    eb, vb = 32, 64
+    kind = (functools.partial(sharded.ShardedTriangleWindowKernel,
+                              make_mesh(4))
+            if layout == "mesh" else tri_ops.TriangleWindowKernel)
+    kern = kind(edge_bucket=eb, vertex_bucket=vb)
+    monkeypatch.setattr(kern, "MAX_STREAM_WINDOWS", max_w)
+    rng = np.random.default_rng(max_w + ragged)
+    src = rng.integers(0, 16, 6 * eb).astype(np.int32)
+    dst = rng.integers(0, 16, 6 * eb).astype(np.int32)
+    windows = [(src[i:i + eb], dst[i:i + eb])
+               for i in range(0, len(src), eb)]
+    if ragged:
+        windows[-1] = (windows[-1][0][:eb // 3], windows[-1][1][:eb // 3])
+    exact = [tri_ops.triangle_count_sparse(s, d, vb) for s, d in windows]
+    handle = kern.dispatch_windows(windows)
+    other = kern.count_windows(windows[::-1])
+    assert kern.collect_windows(handle) == exact == other[::-1]
+    assert kern.count_windows(windows) == exact and sum(exact) > 0
+    if not ragged:
+        assert kern.count_stream(src, dst) == exact
+    assert kern.count_windows([]) == []

@@ -1102,3 +1102,173 @@ def test_fold_loop_has_no_table_sized_ops(program):
         fn = jax.jit(lambda l, s, d: unionfind.cc_fixpoint(l, s, d))
         loops, bad = _loop_ops_past(fn.lower(table, edges, edges), vb)
         assert loops == 1 and bad
+
+
+# ----------------------------------------------------------------------
+# the call's triangle stack goes on the device ahead of the last
+# read-back (_triangles_ahead), and the flush reads it back
+# ----------------------------------------------------------------------
+def _tri_stream(windows, eb, seed, ragged=False):
+    """A count-window stream of `windows` windows over ids < 12, dense
+    enough to close triangles; with `ragged` the last window holds a
+    third of the edges."""
+    rng = np.random.default_rng(seed)
+    n = windows * eb - (eb - eb // 3 if ragged else 0)
+    return rng.integers(0, 12, n), rng.integers(0, 12, n)
+
+
+def _end_flush_only(monkeypatch):
+    """The driver as it was: every window counted at the flush."""
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_triangles_ahead",
+                        lambda self, lead, interned: None)
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["full", "ragged"])
+@pytest.mark.parametrize("chunk", [4, 8], ids=["wb4", "wb8"])
+@pytest.mark.parametrize("layout", ["one_chip", "mesh"])
+def test_triangles_ahead_equal_end_flush_and_count_windows(
+        recorder, monkeypatch, layout, chunk, ragged):
+    """Two calls of 8 windows, in chunks of 4 (two scan launches a
+    call) or 8 (one), the second ending on a ragged window, on one
+    chip and on the mesh: the counts of the stack dispatched ahead
+    equal the end-of-call flush's, count_windows' called directly and
+    the exact host count; every window of each call is counted
+    ahead."""
+    from gelly_streaming_tpu.parallel.sharded import (
+        ShardedTriangleWindowKernel)
+
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_SCAN_CHUNK", chunk)
+    eb, vb = 16, 64
+    src, dst = _tri_stream(16, eb, seed=chunk, ragged=ragged)
+    mesh = make_mesh(4) if layout == "mesh" else None
+
+    def run():
+        drv = StreamingAnalyticsDriver(window_ms=0, vertex_bucket=vb,
+                                       edge_bucket=eb, mesh=mesh)
+        return (drv.run_arrays(src[:8 * eb], dst[:8 * eb])
+                + drv.run_arrays(src[8 * eb:], dst[8 * eb:]))
+
+    ahead = [r.triangles for r in run()]
+    assert _counters("driver.triangles_ahead") == [(8, 8), (8, 8)]
+    with monkeypatch.context() as m:
+        _end_flush_only(m)
+        flushed = [r.triangles for r in run()]
+    assert _counters("driver.triangles_ahead")[2:] == [(0, 8), (0, 8)]
+    windows = [(src[i:i + eb], dst[i:i + eb])
+               for i in range(0, len(src), eb)]
+    kern = (ShardedTriangleWindowKernel(mesh, eb, vb) if mesh is not None
+            else tri_ops.TriangleWindowKernel(eb, vb))
+    exact = [tri_ops.triangle_count_sparse(s, d, vb) for s, d in windows]
+    assert ahead == flushed == kern.count_windows(windows) == exact
+    assert sum(exact) > 0
+    assert len(windows[-1][0]) == (eb // 3 if ragged else eb)
+
+
+@pytest.mark.parametrize("chunk", [4, 8], ids=["wb4", "wb8"])
+@pytest.mark.parametrize("layout", ["one_chip", "mesh"])
+def test_triangle_stack_goes_ahead_of_the_last_read_back(
+        recorder, monkeypatch, layout, chunk):
+    """A spy on the kernel and on the driver's steps: each call
+    dispatches its one triangle stack after its last scan chunk and
+    before the wait on that chunk's read-back, and reads the counts
+    back after the last extraction, in the flush."""
+    from gelly_streaming_tpu.parallel.sharded import (
+        ShardedTriangleWindowKernel)
+
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_SCAN_CHUNK", chunk)
+    kind = (ShardedTriangleWindowKernel if layout == "mesh"
+            else tri_ops.TriangleWindowKernel)
+    log = []
+    real_step = StreamingAnalyticsDriver._step
+    real_dispatch, real_collect = kind.dispatch_windows, kind.collect_windows
+
+    def step(self, name, n):
+        log.append(name)
+        return real_step(self, name, n)
+
+    def dispatch(self, windows):
+        log.append("tri_dispatch:%d" % len(windows))
+        return real_dispatch(self, windows)
+
+    def collect(self, handle):
+        log.append("tri_collect")
+        return real_collect(self, handle)
+
+    monkeypatch.setattr(StreamingAnalyticsDriver, "_step", step)
+    monkeypatch.setattr(kind, "dispatch_windows", dispatch)
+    monkeypatch.setattr(kind, "collect_windows", collect)
+    eb = 16
+    src, dst = _tri_stream(16, eb, seed=3)
+    drv = StreamingAnalyticsDriver(
+        window_ms=0, vertex_bucket=64, edge_bucket=eb,
+        mesh=make_mesh(4) if layout == "mesh" else None)
+    for k in range(2):
+        del log[:]
+        part = slice(8 * k * eb, 8 * (k + 1) * eb)
+        drv.run_arrays(src[part], dst[part])
+        calls = [e for e in log if e.startswith("tri_dispatch")]
+        assert calls == ["tri_dispatch:8"]
+        at = log.index(calls[0])
+        last = lambda name: len(log) - 1 - log[::-1].index(name)
+        assert log.count("snapshot_scan") == 8 // chunk
+        # right behind the last scan chunk: no read-back wait between
+        assert "snapshot_wait" not in log[last("snapshot_scan"):at]
+        assert at < last("snapshot_wait")
+        assert log.count("snapshot_wait") == 8 // chunk
+        assert last("snapshot_extract") < log.index("triangles") \
+            < log.index("tri_collect")
+        assert log.count("tri_collect") == 1
+
+
+def _per_window_calls(drv, src, dst, eb):
+    out = []
+    for i in range(0, len(src), eb):
+        out += drv.run_arrays(src[i:i + eb], dst[i:i + eb])
+    return out
+
+
+@pytest.mark.parametrize("path", [
+    "scan", "resident", "mesh", "per_window", "sliding", "no_triangles",
+    "native", "host", "delta"])
+def test_triangles_ahead_counter_by_path(recorder, monkeypatch, path):
+    """`driver.triangles_ahead` counts every window of a call on the
+    batched device-scan path (scan and resident tiers, the mesh), and
+    0 where the call's windows are counted at the flush: single-window
+    calls, sliding panes, the native and host tiers and the delta
+    emit. With no triangles analytic no flush records it."""
+    from gelly_streaming_tpu.utils import telemetry
+
+    monkeypatch.setenv("GS_RESIDENT_SPB", "8")
+    eb = 16
+    src, dst = _tri_stream(8, eb, seed=5)
+    kw = {"window_ms": 0, "vertex_bucket": 64, "edge_bucket": eb}
+    if path in ("scan", "resident", "native", "host"):
+        kw["snapshot_tier"] = path
+    elif path == "mesh":
+        kw["mesh"] = make_mesh(4)
+    elif path == "sliding":
+        kw["slide"] = eb // 2
+    elif path == "no_triangles":
+        kw["analytics"] = ("degrees", "cc", "bipartite")
+    elif path == "delta":
+        kw.update(snapshot_tier="scan", egress="delta")
+    drv = StreamingAnalyticsDriver(**kw)
+    if path == "per_window":
+        out = _per_window_calls(drv, src, dst, eb)
+    else:
+        out = drv.run_arrays(src, dst)
+    got = _counters("driver.triangles_ahead")
+    if path == "no_triangles":
+        assert got == [] and all(r.triangles is None for r in out)
+        return
+    assert all(r.triangles is not None for r in out)
+    n = len(out)
+    if path in ("scan", "resident", "mesh"):
+        assert got == [(n, n)]
+    elif path == "per_window":
+        assert got == [(0, 1)] * n
+    else:
+        assert got == [(0, n)]
+    assert sum(1 for r in telemetry.records()
+               if r["t"] == "counter"
+               and r["name"] == "driver.triangles_ahead") == len(got)

@@ -3,7 +3,7 @@
 bound by h2d transfer, per-dispatch latency, or device compute — and
 does a compact ingress format fix it?
 
-The standard stream dispatch (TriangleWindowKernel._run_stack) ships
+The standard stream dispatch (TriangleWindowKernel._run_stack_loop) ships
 9 bytes per edge-slot h2d: src int32 + dst int32 + valid bool. But
 (a) vertex ids fit uint16 whenever vertex_bucket <= 65536 (every
 bench scale), and (b) padding is always a per-window SUFFIX
